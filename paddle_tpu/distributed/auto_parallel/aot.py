@@ -59,8 +59,6 @@ def projected_throughput(compiled, global_batch: int, seq: int,
     hardware allows if the latency-hiding scheduler fully overlaps
     collectives, i.e. an upper bound the live run is measured against."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):   # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     traffic = float(cost.get("bytes accessed", 0.0))
     t_compute = flops / peak_flops

@@ -255,7 +255,7 @@ class AsyncCheckpointer:
         fsync + commit run on a background thread unless ``block``.
         Returns the generation path (committed only once the write
         finishes — use :meth:`wait` / ``block=True`` to confirm)."""
-        if not jax.core.trace_state_clean():
+        if not jax.core.trace_ctx.is_top_level():
             raise RuntimeError(
                 "AsyncCheckpointer.save called inside a jax trace — a "
                 "captured step must snapshot from replay OUTPUTS between "

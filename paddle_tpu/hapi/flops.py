@@ -37,8 +37,6 @@ def flops(net, input_size: Optional[Sequence[int]] = None, inputs=None,
             raise ValueError("flops: provide input_size or inputs")
         compiled = jax.jit(fn).lower(*arrays).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0]
         total = int(cost.get("flops", 0))
         if print_detail:
             print(f"Total FLOPs: {total:,} "

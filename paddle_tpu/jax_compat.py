@@ -1,75 +1,35 @@
-"""Cross-version jax API shims.
+"""The three jax entry points paddle_tpu reaches through one place.
 
-The repo targets whatever jax the container bakes in, and the shard_map
-API moved twice upstream: old releases expose
-``jax.experimental.shard_map.shard_map(f, mesh, in_specs, out_specs,
-check_rep=..., auto=...)``; newer ones promote it to ``jax.shard_map``
-with ``check_vma`` (renamed from ``check_rep``) and ``axis_names`` (the
-manual axes; the complement of the old ``auto`` set). Every call site in
-paddle_tpu goes through :func:`shard_map` below so one interpreter runs
-both generations.
+Written for the installed jax (0.9.0): ``jax.shard_map`` with
+``axis_names`` / ``check_vma``, ``jax.distributed.is_initialized`` and
+``pltpu.CompilerParams``. Every call site in paddle_tpu goes through these
+names (graftcheck's ``compat_shim`` rule holds them to it), so the next
+jax upgrade is one file.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # modern jax: promoted to the top-level namespace
-    _new_shard_map = jax.shard_map
-except AttributeError:  # old jax: experimental home, check_rep/auto spelling
-    _new_shard_map = None
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=None):
-    """Version-portable ``jax.shard_map``.
-
-    Accepts the modern keyword surface (``axis_names`` = manual mesh
-    axes, ``check_vma``) and translates for old jax: ``check_vma`` maps
-    to ``check_rep`` and ``axis_names`` to its complement ``auto`` (the
-    axes left under automatic partitioning — partial-manual regions
-    still require a surrounding ``jax.jit`` there).
-    """
-    if _new_shard_map is not None:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
+    """``jax.shard_map``; ``axis_names`` are the manual mesh axes."""
     kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = frozenset(axis_names)
     if check_vma is not None:
-        kwargs["check_rep"] = bool(check_vma)
-    # axis_names is dropped on old jax: its partial-manual mode (`auto`)
-    # hard-aborts XLA's SPMD partitioner on axis_index/ppermute bodies
-    # (Check failed: IsManualSubgroup), so the region runs FULL-manual
-    # over every mesh axis instead. Specs that omit an axis then mean
-    # "replicated over it" — numerically identical, at worst duplicated
-    # compute along the omitted axes inside the region.
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def is_distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` (added upstream after the
-    multi-controller bootstrap API) with a fallback that inspects the
-    global distributed client on older releases."""
-    try:
-        return bool(jax.distributed.is_initialized())
-    except AttributeError:
-        try:
-            from jax._src.distributed import global_state
-            return global_state.client is not None
-        except Exception:
-            return False
+    """``jax.distributed.is_initialized()``."""
+    return bool(jax.distributed.is_initialized())
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new spelling) / ``pltpu.TPUCompilerParams``
-    (old spelling) — same fields either way."""
+    """``pltpu.CompilerParams``."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

@@ -223,6 +223,10 @@ def not_to_static(fn=None):
     return fn
 
 
+def _to_array(t):
+    return t._data if isinstance(t, Tensor) else jnp.asarray(t)
+
+
 class TrainStep:
     """Whole-training-step compilation: loss fwd + grads + optimizer update
     in one donated XLA program.
@@ -347,8 +351,7 @@ class TrainStep:
                  frozen_arrays, key, inputs, labels, lr, stepno):
             # rng/step live ON DEVICE and chain through the donated state:
             # shipping a fresh host scalar per call costs a full host->device
-            # round trip (tens of ms on tunneled devices) and serialises the
-            # step stream
+            # round trip and serialises the step stream
             key, rng = jax.random.split(key)
             stepno = stepno + 1
             (loss, new_buf), grads = grad_fn(param_arrays, frozen_arrays,
@@ -406,6 +409,37 @@ class TrainStep:
             threading.Thread(target=_retire, daemon=True).start()
         return loss
 
+    def _step_args(self, inputs, labels):
+        """The compiled step's arguments for the current state."""
+        opt, params = self.optimizer, self._params
+        lr_val = float(opt.get_lr())
+        if self._lr_cache[0] != lr_val:  # one transfer per lr CHANGE
+            self._lr_cache = (lr_val, jnp.asarray(lr_val, jnp.float32))
+        return (self._accum if self.grad_accum > 1 else (),
+                tuple(p._data for p in params),
+                tuple(opt._masters[i] for i in range(len(params))),
+                tuple(opt._states[i] for i in range(len(params))),
+                tuple(b._data for b in self._buffers),
+                tuple(f._data for f in self._frozen),
+                self._dev_key, inputs, labels,
+                self._lr_cache[1], self._dev_step)
+
+    def lower(self, inputs, labels):
+        """The whole-step program lowered for the current parameters,
+        optimizer state and this batch; nothing runs and nothing is
+        donated. ``lower(...).compile().as_text()`` shows which kernels
+        (``tpu_custom_call``) and collectives the step was built with."""
+        if self._compiled is None:
+            self._build()
+        inputs, labels = (
+            jax.tree.map(_to_array, x,
+                         is_leaf=lambda t: isinstance(t, Tensor))
+            for x in (inputs, labels))
+        if self.grad_accum > 1 and self._accum is None:
+            self._accum = tuple(jnp.zeros(p._data.shape, p._data.dtype)
+                                for p in self._params)
+        return self._compiled.lower(*self._step_args(inputs, labels))
+
     def _call_impl(self, inputs, labels):
         opt = self.optimizer
         if self._compiled is not None and \
@@ -420,10 +454,9 @@ class TrainStep:
             self._step = opt._step_count
             self._dev_step = jnp.asarray(self._step, jnp.int32)
         params, buffers = self._params, self._buffers
-        to_arr = lambda t: t._data if isinstance(t, Tensor) else jnp.asarray(t)
-        inputs = jax.tree.map(to_arr, inputs,
+        inputs = jax.tree.map(_to_array, inputs,
                               is_leaf=lambda x: isinstance(x, Tensor))
-        labels = jax.tree.map(to_arr, labels,
+        labels = jax.tree.map(_to_array, labels,
                               is_leaf=lambda x: isinstance(x, Tensor))
 
         if self.grad_accum > 1 and self._accum is None:
@@ -444,19 +477,8 @@ class TrainStep:
 
         self._step += 1
         opt._step_count = self._step
-        lr_val = float(opt.get_lr())
-        if self._lr_cache[0] != lr_val:  # one transfer per lr CHANGE
-            self._lr_cache = (lr_val, jnp.asarray(lr_val, jnp.float32))
         new_p, new_m, new_s, new_buf, loss, self._dev_key, self._dev_step = \
-            self._compiled(
-                self._accum if self.grad_accum > 1 else (),
-                tuple(p._data for p in params),
-                tuple(opt._masters[i] for i in range(len(params))),
-                tuple(opt._states[i] for i in range(len(params))),
-                tuple(b._data for b in buffers),
-                tuple(f._data for f in self._frozen),
-                self._dev_key, inputs, labels,
-                self._lr_cache[1], self._dev_step)
+            self._compiled(*self._step_args(inputs, labels))
         for i, p in enumerate(params):
             p._set_data(new_p[i])
             opt._masters[i] = new_m[i]

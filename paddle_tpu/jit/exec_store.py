@@ -58,10 +58,7 @@ from ..utils.durability import (COMMIT_FILE, fsync_write,
                                 read_committed_marker,
                                 write_committed_marker)
 
-try:  # AOT executable serialization — absent/refusing backends fail open
-    from jax.experimental import serialize_executable as _se
-except Exception:  # pragma: no cover - older jax  # fail-open: cache off
-    _se = None
+from jax.experimental import serialize_executable as _se
 
 _flags.define_flag(
     "exec_cache_dir", "",
@@ -430,7 +427,7 @@ class PersistentJit:
 
     def _load_or_compile(self, args) -> Callable:
         st = store()
-        if st is None or _se is None:
+        if st is None:
             return self._jfn
         lowered = self._jfn.lower(*args)  # trace errors propagate
         try:
@@ -462,8 +459,15 @@ class PersistentJit:
             with _tracing.span("jit.cache.load",
                                attrs={"kind": self._kind,
                                       "label": self._label}):
-                blob = pickle.loads(payload)
-                fn = _se.deserialize_and_load(*blob)
+                blob, device_ids = pickle.loads(payload)
+                # onto the executable's own devices: left to itself
+                # deserialize_and_load takes every device of the
+                # backend, and a one-device program loaded onto eight
+                # then fails when it is called
+                by_id = {d.id: d for d in jax.devices()}
+                fn = _se.deserialize_and_load(
+                    *blob,
+                    execution_devices=[by_id[i] for i in device_ids])
         except Exception:
             _flight.record_event(
                 "jit.cache.corrupt", (self._kind, self._label,
@@ -478,7 +482,9 @@ class PersistentJit:
 
     def _serialize_put(self, st: ExecStore, parts, compiled) -> None:
         try:
-            payload = pickle.dumps(_se.serialize(compiled))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            payload = pickle.dumps((_se.serialize(compiled), device_ids))
         except Exception:
             # backend refuses serialization (e.g. no PjRt executable
             # serialization support): fail open, keep the compiled fn
@@ -495,7 +501,7 @@ def persistent(jfn: Callable, kind: str, label: str = "",
     time; otherwise return it unchanged (zero overhead off-path).  Cache
     sites keyed on ``flags.version`` re-wrap automatically after a flag
     mutation attaches the store."""
-    if store() is None or _se is None:
+    if store() is None:
         return jfn
     return PersistentJit(jfn, kind, label=label, perf_key=perf_key,
                          extra=extra)

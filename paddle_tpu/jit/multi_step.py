@@ -252,7 +252,7 @@ class MultiStepCapture(CapturedStep):
             return self._run_block_eager(args, kwargs)
         if dispatcher._STEP_TRACE is not None \
                 or dispatcher._STEP_PROBE is not None \
-                or not jax.core.trace_state_clean():
+                or not jax.core.trace_ctx.is_top_level():
             # nested inside another capture/trace: the outer program
             # absorbs the steps one by one
             return self._run_block_eager(args, kwargs)

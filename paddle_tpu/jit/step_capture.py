@@ -857,7 +857,7 @@ class CapturedStep:
             return self._fn(*args, **kwargs)
         if dispatcher._STEP_TRACE is not None \
                 or dispatcher._STEP_PROBE is not None \
-                or not jax.core.trace_state_clean():
+                or not jax.core.trace_ctx.is_top_level():
             # nested inside another capture/trace: run inline, the outer
             # program absorbs this step
             return self._fn(*args, **kwargs)

@@ -44,6 +44,7 @@ lands with the offender's key.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time
@@ -78,14 +79,27 @@ def enabled() -> bool:
     return bool(_F_PERF.value)
 
 
-# Roofline reference peaks (per chip). v5p bf16 dense MXU + HBM3 by
-# default — the same constants the AOT planner projects against
-# (distributed/auto_parallel/aot.py), so achieved-vs-projected joins
-# compare like against like. On other parts (or CPU test runs) the
-# derived MFU is a *reference* ratio, not a physical utilization;
-# override with set_roofline().
-PEAK_FLOPS = 459e12
-HBM_BYTES_PER_S = 2765e9
+# Per-chip peaks by jax ``device_kind``: (bf16 dense FLOP/s, HBM bytes/s).
+# A kind that is not in the table gets no MFU and no roofline figure
+# (None), never another part's numbers.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    # Google Cloud documentation, "TPU v5p": 459 TFLOP/s bf16, 2765 GB/s
+    # — the part the AOT planner projects against
+    # (distributed/auto_parallel/aot.py)
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _peaks():
+    """Peaks of the device this process runs on, resolved at first use;
+    None for a device the table does not know (a CPU, say)."""
+    import jax
+    return DEVICE_PEAKS.get(jax.devices()[0].device_kind)
 
 # Sentinel: fire when achieved throughput of a sampled executable drops
 # more than this far below its own session high-water mark, confirmed
@@ -138,13 +152,6 @@ _STEP_HISTS = {
     "data_wait": _H_DATA_WAIT, "host_dispatch": _H_HOST_DISPATCH,
     "device": _H_DEVICE, "other": _H_OTHER,
 }
-
-
-def set_roofline(peak_flops: float, hbm_bytes_per_s: float) -> None:
-    """Override the reference peaks MFU/bound classification uses."""
-    global PEAK_FLOPS, HBM_BYTES_PER_S
-    PEAK_FLOPS = float(peak_flops)
-    HBM_BYTES_PER_S = float(hbm_bytes_per_s)
 
 
 def _digest(key: Any) -> str:
@@ -245,10 +252,11 @@ class _Entry:
 
     def bound(self) -> str:
         """compute / bandwidth / host / unknown classification."""
-        if not self.flops and not self.bytes_accessed:
+        peaks = _peaks()
+        if peaks is None or (not self.flops and not self.bytes_accessed):
             return "unknown"
-        t_c = (self.flops or 0.0) / PEAK_FLOPS
-        t_m = (self.bytes_accessed or 0.0) / HBM_BYTES_PER_S
+        t_c = (self.flops or 0.0) / peaks[0]
+        t_m = (self.bytes_accessed or 0.0) / peaks[1]
         avg = self.avg_device_s
         if avg is not None and avg > 3.0 * max(t_c, t_m, 1e-12):
             return "host"
@@ -269,8 +277,6 @@ def _resolve_cost(e: _Entry) -> None:
         if compiled is None:
             return
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0))
         traffic = float(cost.get("bytes accessed", 0.0))
         mem = compiled.memory_analysis()
@@ -413,7 +419,8 @@ class ExecutableLedger:
         fps, bps = e.achieved()
         if fps is not None:
             e.g_fps.set(fps)
-            e.g_mfu.set(fps / PEAK_FLOPS)
+            if _peaks() is not None:
+                e.g_mfu.set(fps / _peaks()[0])
         if bps is not None:
             e.g_bps.set(bps)
         if fire is not None:
@@ -480,6 +487,7 @@ class ExecutableLedger:
     def stats(self, resolve_cost: bool = True) -> List[Dict[str, Any]]:
         """Plain-dict rows, sorted by cumulative device time desc."""
         rows = []
+        peaks = _peaks()
         for e in self.entries():
             if not e.calls:
                 continue   # registered but idle (or zeroed by reset())
@@ -501,14 +509,14 @@ class ExecutableLedger:
                 "avg_device_seconds": round(avg, 9) if avg else None,
                 "achieved_flops_per_s": fps,
                 "achieved_bytes_per_s": bps,
-                "mfu": (fps / PEAK_FLOPS) if fps else None,
+                "mfu": (fps / peaks[0]) if (fps and peaks) else None,
                 "bound": e.bound(),
             }
-            if e.flops or e.bytes_accessed:
+            if peaks and (e.flops or e.bytes_accessed):
                 # the same roofline the AOT planner projects: what the
                 # hardware allows vs what sampling measured
-                t_c = (e.flops or 0.0) / PEAK_FLOPS
-                t_m = (e.bytes_accessed or 0.0) / HBM_BYTES_PER_S
+                t_c = (e.flops or 0.0) / peaks[0]
+                t_m = (e.bytes_accessed or 0.0) / peaks[1]
                 proj = max(t_c, t_m)
                 row["roofline"] = {
                     "compute_seconds": t_c, "memory_seconds": t_m,

@@ -149,14 +149,15 @@ def plan_buckets(kind: str, cfg: Dict, specs: Sequence[Tuple]) -> BucketPlan:
 # flat bucket. The formulas mirror optimizer.py's `_update` rules
 # line-for-line (including cast placement) so fused == per-param bitwise.
 
-def _bias_inv(b1, b2, step, barrier: bool):
-    # optimizer._bias_corrections, minus the optimization_barrier inside
-    # a Pallas body (per-tile scalar; the barrier is value-identity)
-    step = step.astype(jnp.float32)
-    pair = (1.0 / (1.0 - b1 ** step), 1.0 / (1.0 - b2 ** step))
-    if barrier:
-        pair = jax.lax.optimization_barrier(pair)
-    return pair
+def _bias_inv(cfg: Dict, sv):
+    """optimizer._bias_corrections as reciprocals. A Pallas body reads
+    them from its scalar vector (``sv["inv_bc"]``, computed here by the
+    caller): Mosaic has no scalar ``powf``."""
+    if "inv_bc" in sv:
+        return sv["inv_bc"]
+    step = sv["step"].astype(jnp.float32)
+    return jax.lax.optimization_barrier(
+        (1.0 / (1.0 - cfg["b1"] ** step), 1.0 / (1.0 - cfg["b2"] ** step)))
 
 
 def _condition_grad(g, pdtype, sv):
@@ -175,7 +176,7 @@ def _keep_old(found, old, new):
 
 
 def _rule_elementwise(kind: str, cfg: Dict, p, g, state, sv,
-                      barrier: bool, condition: bool):
+                      condition: bool):
     """(new_p, new_state) for the purely elementwise rules, sentinel
     select applied. `g` is raw (pre-unscale/clip) in the grad dtype;
     wd rides the scalar vector (``sv["wd"]``). `condition` skips the
@@ -183,10 +184,10 @@ def _rule_elementwise(kind: str, cfg: Dict, p, g, state, sv,
     identity multiplies change FMA contraction downstream."""
     g = _condition_grad(g, p.dtype, sv) if condition \
         else (g.astype(p.dtype) if g.dtype != p.dtype else g)
-    return _rule_core(kind, cfg, sv["wd"], p, g, state, sv, barrier)
+    return _rule_core(kind, cfg, sv["wd"], p, g, state, sv)
 
 
-def _rule_core(kind: str, cfg: Dict, wd32, p, g, state, sv, barrier: bool):
+def _rule_core(kind: str, cfg: Dict, wd32, p, g, state, sv):
     """The rule chain proper; `g` is already conditioned and in the
     compute dtype. `wd32` is an f32 scalar, traced on both routes (the
     per-param path passes wd as a program ARGUMENT, and a baked
@@ -210,7 +211,7 @@ def _rule_core(kind: str, cfg: Dict, wd32, p, g, state, sv, barrier: bool):
             g = g + wd * p
         m = b1 * state["m"] + (1 - b1) * g
         v = b2 * state["v"] + (1 - b2) * jnp.square(g)
-        inv_bc1, inv_bc2 = _bias_inv(b1, b2, sv["step"], barrier)
+        inv_bc1, inv_bc2 = _bias_inv(cfg, sv)
         upd = (m * inv_bc1) / (jnp.sqrt(v * inv_bc2) + eps)
         if cfg["decoupled"]:
             upd = upd + wd * p
@@ -222,8 +223,7 @@ def _rule_core(kind: str, cfg: Dict, wd32, p, g, state, sv, barrier: bool):
     return new_p, new_s
 
 
-def _lamb_moments(cfg: Dict, p, g, state, sv, barrier: bool,
-                  condition: bool):
+def _lamb_moments(cfg: Dict, p, g, state, sv, condition: bool):
     """Lamb phase 1: guarded new moments + RAW trust_ratio_div (its
     per-layer norms are reduced outside, on original-shaped segments)."""
     b1, b2, eps = cfg["b1"], cfg["b2"], cfg["eps"]
@@ -232,7 +232,7 @@ def _lamb_moments(cfg: Dict, p, g, state, sv, barrier: bool,
     wd = sv["wd"].astype(p.dtype)
     m = b1 * state["m"] + (1 - b1) * g
     v = b2 * state["v"] + (1 - b2) * jnp.square(g)
-    inv_bc1, inv_bc2 = _bias_inv(b1, b2, sv["step"], barrier)
+    inv_bc1, inv_bc2 = _bias_inv(cfg, sv)
     tr_div = (m * inv_bc1) / (jnp.sqrt(v * inv_bc2) + eps) + wd * p
     found = sv["found"]
     return (_keep_old(found, state["m"], m),
@@ -249,16 +249,18 @@ def _lamb_apply(p, tr_div, r, sv):
 
 # -- pallas kernels -----------------------------------------------------------
 
-def _pack_scalars(sv) -> jax.Array:
-    # [lr, step, inv, coeff, found, wd] + padding, one SMEM f32 vector
+def _pack_scalars(cfg: Dict, sv) -> jax.Array:
+    # [lr, step, inv, coeff, found, wd, 1/bc1, 1/bc2], one SMEM f32 vector
     z = jnp.float32(0.0)
+    bc1, bc2 = _bias_inv(cfg, sv) if "b1" in cfg else (z, z)
     return jnp.stack([sv["lr"], sv["step"], sv["inv"], sv["coeff"],
-                      sv["found"], sv["wd"], z, z])
+                      sv["found"], sv["wd"], bc1, bc2])
 
 
 def _unpack_scalars(ref) -> Dict[str, jax.Array]:
     return {"lr": ref[0], "step": ref[1], "inv": ref[2],
-            "coeff": ref[3], "found": ref[4], "wd": ref[5]}
+            "coeff": ref[3], "found": ref[4], "wd": ref[5],
+            "inv_bc": (ref[6], ref[7])}
 
 
 def _pad2d(flat, rows, dtype=None):
@@ -306,7 +308,7 @@ def _pallas_elementwise_bucket(plan, bucket, pf, gf, sf, condition):
         state = {k: r[...] for k, r in zip(keys, s_in)}
         new_p, new_s = _rule_elementwise(plan.kind, plan.cfg,
                                          p_ref[...], g_ref[...], state, sv,
-                                         barrier=False, condition=condition)
+                                         condition=condition)
         outs[0][...] = new_p
         for j, k in enumerate(keys):
             outs[1 + j][...] = new_s[k]
@@ -337,7 +339,7 @@ def _pallas_lamb_bucket(plan, bucket, pf, gf, sf, p_orig, condition):
         sv = _unpack_scalars(sv_ref)
         m, v, trd = _lamb_moments(plan.cfg, p_ref[...], g_ref[...],
                                   {"m": m_ref[...], "v": v_ref[...]}, sv,
-                                  barrier=False, condition=condition)
+                                  condition=condition)
         mo[...], vo[...], to[...] = m, v, trd
 
     m2, v2, t2 = _bucket_kernel_call(
@@ -394,7 +396,7 @@ def _lamb_segment(cfg: Dict, wd32, p, g, state, sv):
     wd = wd32.astype(p.dtype)
     m = b1 * state["m"] + (1 - b1) * g
     v = b2 * state["v"] + (1 - b2) * jnp.square(g)
-    inv_bc1, inv_bc2 = _bias_inv(b1, b2, sv["step"], barrier=True)
+    inv_bc1, inv_bc2 = _bias_inv(cfg, sv)
     tr_div = (m * inv_bc1) / (jnp.sqrt(v * inv_bc2) + eps) + wd * p
     tr_div = jax.lax.optimization_barrier(tr_div)
     pn = jnp.sqrt(jnp.sum(jnp.square(p)))
@@ -435,7 +437,7 @@ def _composite_segments(plan, bucket, p_orig, g_orig, s_orig, sv,
             new_pk, new_sk = _lamb_segment(plan.cfg, wd32, p, g, s, sv)
         else:
             new_pk, new_sk = _rule_core(plan.kind, plan.cfg, wd32,
-                                        p, g, s, sv, barrier=True)
+                                        p, g, s, sv)
         new_p.append(new_pk)
         new_s.append(new_sk)
         lows.append(new_pk.astype(jnp.dtype(bucket.low))
@@ -502,7 +504,7 @@ def fused_apply(plan: BucketPlan, p_list, g_list, s_list, lr, step,
                   for key in keys}
         wd32 = f32(wd_list[bi]) if wd_list is not None \
             else jnp.float32(bucket.wd)
-        pf = {"svec": _pack_scalars(dict(sv, wd=wd32)),
+        pf = {"svec": _pack_scalars(plan.cfg, dict(sv, wd=wd32)),
               "p": _pad2d(p_flat, bucket.rows)}
         gf = _pad2d(g_flat, bucket.rows)
         sf = {k: _pad2d(v, bucket.rows) for k, v in s_flat.items()}

@@ -197,6 +197,17 @@ def _cached(key, build):
 
 
 # -- shard_map'd entry points -------------------------------------------------
+# Every region below is manual over ALL axes of the mesh, not only the
+# ones its specs name: Mosaic refuses a kernel in a partial-manual region
+# ("cannot be automatically partitioned"), and the fleet's hybrid mesh
+# always carries its five axes, most of degree 1. An axis the specs leave
+# out means "replicated over it".
+
+def _manual(local, mesh, in_specs, out_specs):
+    return jax.jit(shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=frozenset(mesh.axis_names), check_vma=False))
+
 
 def sharded_flash_attention(query, key, value, mesh, head_axis,
                             batch_axis=None, causal=False, scale=None):
@@ -224,14 +235,11 @@ def sharded_flash_attention(query, key, value, mesh, head_axis,
 
     def build():
         spec = P(ba, None, head_axis, None)
-        axes = frozenset(a for a in (head_axis, ba) if a)
 
         def local(q, k, v):
             return fa.flash_attention(q, k, v, causal=causal, scale=scale)
 
-        return jax.jit(shard_map(
-            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            axis_names=axes, check_vma=False))
+        return _manual(local, mesh, (spec, spec, spec), spec)
 
     fn = _cached(("flash", mesh, head_axis, ba, bool(causal), float(scale)),
                  build)
@@ -264,10 +272,7 @@ def sharded_flash_varlen(q, k, v, cu_q, cu_k, mesh, head_axis,
             return fv._varlen(q_, k_, v_, cq, ck, bool(causal),
                               float(scale), bool(tok_skip))
 
-        return jax.jit(shard_map(
-            local, mesh=mesh, in_specs=(hspec, hspec, hspec, rep, rep),
-            out_specs=hspec, axis_names=frozenset({head_axis}),
-            check_vma=False))
+        return _manual(local, mesh, (hspec, hspec, hspec, rep, rep), hspec)
 
     fn = _cached(("varlen", mesh, head_axis, bool(causal), float(scale),
                   bool(tok_skip)), build)
@@ -301,15 +306,12 @@ def sharded_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
         pspec = P(None, None, head_axis, None)
         tspec = P(ba, None)
         lspec = P(ba)
-        axes = frozenset(a for a in (head_axis, ba) if a)
 
         def local(q_, kp, vp, tbl, lens):
             return pa.paged_attention(q_, kp, vp, tbl, lens, scale=scale)
 
-        return jax.jit(shard_map(
-            local, mesh=mesh,
-            in_specs=(qspec, pspec, pspec, tspec, lspec),
-            out_specs=qspec, axis_names=axes, check_vma=False))
+        return _manual(local, mesh, (qspec, pspec, pspec, tspec, lspec),
+                       qspec)
 
     fn = _cached(("paged", mesh, head_axis, ba, float(scale)), build)
     _M_SHARDED.inc()
@@ -371,10 +373,7 @@ def sharded_ragged_paged_attention(q, k_pool, v_pool, block_tables,
                                                   cu, scale=scale)
             in_specs = (qspec, pspec, pspec, rep2, rep1, rep1)
 
-        return jax.jit(shard_map(
-            local, mesh=mesh, in_specs=in_specs,
-            out_specs=qspec, axis_names=frozenset({head_axis}),
-            check_vma=False))
+        return _manual(local, mesh, in_specs, qspec)
 
     fn = _cached(("ragged", mesh, head_axis, float(scale), quantized),
                  build)

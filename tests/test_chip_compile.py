@@ -1,0 +1,148 @@
+"""Compile-only checks of the main path's Pallas kernels for a described
+TPU v5e (no chip attached): the real XLA:TPU + Mosaic compilers at the
+published Llama-2-7B widths chip_smoke.py runs, ~2 s each.
+
+Interpret mode cannot show what these do: a slice off the tiling, too much
+VMEM, a scalar-memory table Mosaic refuses. Nothing runs, so no result and
+no time comes from here.
+
+The topology is described inside the module-scoped fixture and nowhere
+else: only one process may hold libtpu, every xdist worker imports this
+file, and only the worker that is handed it may load the library. Keep
+these tests in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from paddle_tpu.ops.kernels.pallas import flash_attention as fa
+from paddle_tpu.ops.kernels.pallas import fused_optimizer as fok
+from paddle_tpu.ops.kernels.pallas import ragged_paged_attention as rpa
+
+# Llama-2-7B (LlamaConfig()): 32 heads, 32 kv heads, head_dim 128
+H, KV, D = 32, 32, 128
+HIDDEN, FFN = 4096, 11008
+CUSTOM_CALL = '"tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, no_persistent_cache):
+    """Interpret mode off, as on the chip: the host backend here is the
+    CPU, which the kernels' `_interpret()` would otherwise follow."""
+    monkeypatch.setattr(fa, "_FORCE_INTERPRET", False)
+    monkeypatch.setattr(fok, "_interpret", lambda: False)
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+def test_flash_fwd_bwd(mosaic, one_chip, kv_heads):
+    q = _sds(one_chip, (2, 2048, H, D), jnp.bfloat16)
+    kv = _sds(one_chip, (2, 2048, kv_heads, D), jnp.bfloat16)
+    assert fa.supported(q.shape, kv.shape, True)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count(CUSTOM_CALL) >= 3      # fwd, dq, dk/dv
+
+
+def test_sharded_flash_on_the_hybrid_mesh(mosaic, topo):
+    # fleet.init's mesh always has its five axes, here dp=2 x mp=2 with
+    # three of degree 1. Mosaic takes a kernel only in a region that is
+    # manual over every one of them (found on four chips, PR 22)
+    from paddle_tpu.ops.kernels.pallas import tp_attention as tpa
+    mesh = Mesh(np.array(topo.devices).reshape(2, 1, 1, 1, 2),
+                ("dp", "pp", "sharding", "sep", "mp"))
+    qkv = jax.ShapeDtypeStruct(
+        (4, 2048, H, D), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+    def loss(q, k, v):
+        out = tpa.sharded_flash_attention(q, k, v, mesh, "mp", "dp",
+                                          causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    assert text.count(CUSTOM_CALL) >= 3
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_ragged_paged_attention(mosaic, one_chip, pool_dtype):
+    # the serve phase's step: 512 packed tokens over 8 rows, 64-token
+    # blocks, tables as wide as max_position_embeddings / block_size
+    tokens, rows, blocks, bs, width = 512, 8, 130, 64, 64
+    q = _sds(one_chip, (tokens, H, D), jnp.bfloat16)
+    pool = _sds(one_chip, (blocks, bs, KV, D), pool_dtype)
+    assert rpa.supported(q.shape, pool.shape)
+    scales = {}
+    if pool_dtype == jnp.int8:
+        s = _sds(one_chip, (blocks, bs, KV), jnp.float32)
+        scales = dict(k_scale=s, v_scale=s)
+    text = jax.jit(rpa.ragged_paged_attention).lower(
+        q, pool, pool, _sds(one_chip, (rows, width), jnp.int32),
+        _sds(one_chip, (rows,), jnp.int32),
+        _sds(one_chip, (rows + 1,), jnp.int32), **scales,
+    ).compile().as_text()
+    assert CUSTOM_CALL in text
+
+
+def test_fused_adamw_bucket(mosaic, one_chip):
+    # one decoder layer's matrices and a norm in one bucket: 78.6 M
+    # elements, a row count that is not a multiple of the 512-row block
+    shapes = ((HIDDEN, FFN), (HIDDEN, HIDDEN), (HIDDEN, HIDDEN), (HIDDEN,))
+    plan = fok.plan_buckets(
+        "adam", {"b1": 0.9, "b2": 0.999, "eps": 1e-8, "decoupled": True},
+        tuple((s, "float32", "bfloat16", "bfloat16", 0.01) for s in shapes))
+    (bucket,) = plan.buckets
+    assert bucket.total >= 64 * 2 ** 20
+    p = [_sds(one_chip, s, jnp.float32) for s in shapes]
+    g = [_sds(one_chip, s, jnp.bfloat16) for s in shapes]
+    state = [{"m": x, "v": x} for x in p]
+    scalar = _sds(one_chip, (), jnp.float32)
+
+    def apply(p, g, s, lr, step):
+        return fok.fused_apply(plan, p, g, s, lr, step, 1.0, 1.0, 0.0,
+                               use_pallas=True, condition=False)
+
+    text = jax.jit(apply).lower(p, g, state, scalar,
+                                scalar).compile().as_text()
+    assert CUSTOM_CALL in text

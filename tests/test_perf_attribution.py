@@ -83,7 +83,7 @@ class TestLedgerRegistration:
         fn = lambda v: v  # noqa: E731
         assert perf.ledger().wrap(("k2",), "op", fn) is fn
 
-    def test_dispatcher_registers_per_compile(self, perf_on):
+    def test_dispatcher_registers_per_compile(self, perf_on, monkeypatch):
         """Every exec-cache miss (a jit.compiles tick) of a jitted op
         lands one op-kind ledger row under the same cache identity."""
         c0 = _counter_value("jit.compiles")
@@ -102,12 +102,17 @@ class TestLedgerRegistration:
         assert _counter_value("jit.compiles") >= c0 + len(new_ops)
         (e,) = [x for x in new_ops if "matmul" in x.label]
         assert e.calls == 3
-        row = [r for r in perf.ledger().stats()
-               if r["key"] == e.label][0]
+        def row():
+            return [r for r in perf.ledger().stats()
+                    if r["key"] == e.label][0]
         # cost analysis resolved from the live executable
-        assert row["flops"] and row["flops"] > 0
-        assert row["hbm"]["arg_bytes"] > 0
-        assert row["roofline"]["projected_step_seconds"] > 0
+        assert row()["flops"] and row()["flops"] > 0
+        assert row()["hbm"]["arg_bytes"] > 0
+        # the CPU is not in the peaks table: no MFU, no roofline figure
+        assert row()["mfu"] is None and "roofline" not in row()
+        monkeypatch.setattr(perf, "_peaks",
+                            lambda: perf.DEVICE_PEAKS["TPU v5 lite"])
+        assert row()["roofline"]["projected_step_seconds"] > 0
 
     def test_step_capture_and_optimizer_register(self, perf_on):
         sc = paddle.get_flags(["FLAGS_step_capture"])
