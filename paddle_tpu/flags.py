@@ -229,8 +229,9 @@ define_flag("tracing", True,
             "bounded per-process ring, exported as Chrome-trace JSON via "
             "observability.dump_trace(); False short-circuits every span "
             "to a single flag read")
-define_flag("tracing_ring_size", 4096,
-            "tracing ring capacity (completed spans + instant events)")
+define_flag("tracing_ring_size", 16384,
+            "tracing ring capacity (completed spans + instant events); "
+            "a serving step writes about ten, so this holds some minutes")
 define_flag("tracing_path", "",
             "crash-dump destination for the span trace (Chrome-trace "
             "JSON, written next to the flight recorder dump on uncaught "

@@ -83,6 +83,9 @@ class QueueFull(RuntimeError):
 _M = _metrics_mod.registry()
 _M_STEPS = _M.counter(
     "serving.steps", "ragged scheduler steps executed")
+# ops/dispatcher.py's count of eager dispatches: its difference across a
+# step's model call is the number of programs the step launched
+_M_LAUNCHES = _M.counter("dispatch.count")
 _M_STEP_TOKENS = _M.counter(
     "serving.step_tokens", "packed tokens processed (prefill + decode)")
 _M_GEN_TOKENS = _M.counter(
@@ -739,233 +742,262 @@ class ContinuousBatchingEngine:
         requests that finished during this step."""
         from ..autograd.engine import no_grad
 
-        self._admit()
-        if self.pending and self.preempt_after is not None \
-                and not self.admission_paused:
-            self._head_waited += 1
-            if self._head_waited > self.preempt_after:
-                self._preempt_lifo()
-                self._head_waited = 0
-                self._admit()
-        _M_QUEUE.set(len(self.pending))
-        _M_ACTIVE.set(self.num_active)
-        _M_BACKLOG.set(sum(r.target - r.ctx for r in self.slots
-                           if r is not None and r.ctx < r.target))
-        _M_FREE.set(self._free_effective())
+        # the host's phases of this step, each a live span on the thread's
+        # timeline (untraced: one ragged step serves many requests) and,
+        # under a jax.profiler trace, an annotation on the device's clock
+        n_step = self.steps + 1
+        with _tracing.start_span("serving.step.admit",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}):
+            self._admit()
+            if self.pending and self.preempt_after is not None \
+                    and not self.admission_paused:
+                self._head_waited += 1
+                if self._head_waited > self.preempt_after:
+                    self._preempt_lifo()
+                    self._head_waited = 0
+                    self._admit()
+            _M_QUEUE.set(len(self.pending))
+            _M_ACTIVE.set(self.num_active)
+            _M_BACKLOG.set(sum(r.target - r.ctx for r in self.slots
+                               if r is not None and r.ctx < r.target))
+            _M_FREE.set(self._free_effective())
         if self.num_active == 0:
             return []
 
-        B, R, bs = self.token_budget, self.max_batch, self.block_size
-        # fixed-size prefill chunks, round-robin by admission order, into
-        # the budget left after every decoding row's token
-        decode_rows = [i for i, r in enumerate(self.slots)
-                       if r is not None and r.ctx >= r.target]
-        prefill_rows = sorted(
-            (i for i, r in enumerate(self.slots)
-             if r is not None and r.ctx < r.target),
-            key=lambda i: self.slots[i].admit_order)
-        grants = dict.fromkeys(prefill_rows, 0)
-        left = B - len(decode_rows)
-        while left > 0:
-            gave = False
-            for i in prefill_rows:
-                req = self.slots[i]
-                g = min(self.prefill_chunk, req.target - req.ctx - grants[i],
-                        left)
-                if g > 0:
-                    grants[i] += g
-                    left -= g
-                    gave = True
-                if left <= 0:
-                    break
-            if not gave:
-                break
-
-        # speculative drafts out of the LEFTOVER budget: each decode row
-        # may carry up to spec_k draft tokens, turning its q_len=1 row
-        # into a q_len=1+K' verify row (a prefill-chunk shape the step
-        # executable already compiles for). The emission cap keeps
-        # write positions inside the admission-time worst case, so the
-        # block reservation math is untouched by speculation.
-        drafts: Dict[int, np.ndarray] = {}
-        if self.spec_k and left > 0:
-            for i in decode_rows:
-                req = self.slots[i]
-                cap = min(self.spec_k,
-                          req.max_new_tokens - len(req.out_tokens) - 1,
-                          left)
-                if cap <= 0:
-                    continue
-                # proposal depends ONLY on this request's committed
-                # tokens — never batch composition — so speculative
-                # output stays schedule-independent
-                hist = np.concatenate(
-                    [req.prompt, np.asarray(req.out_tokens, np.int32)])
-                d = self.proposer.propose(hist, cap)
-                if len(d):
-                    drafts[i] = np.asarray(d, np.int32)
-                    left -= len(d)
-                if left <= 0:
-                    break
-
-        # L sample lanes per row: lane j of a verify row samples stream
-        # position len(out)+j from the logits of packed token t+j. With
-        # spec off L=1 and the arrays are exactly the legacy geometry.
-        L = self.spec_k + 1
-        ids = np.zeros((B,), np.int32)
-        pos = np.zeros((B,), np.int32)
-        slot_vec = np.full((B,), self._trash_slot, np.int64)
-        qlen = np.zeros((R,), np.int32)
-        lens = np.zeros((R,), np.int32)
-        sample_idx = np.zeros((R * L,), np.int32)
-        stream_pos = np.zeros((R * L,), np.int32)
-        keys = np.zeros((R * L, self._key_w), np.uint32)
-        post = []                      # (row, is_decode, n) commit plan
-        t = 0
-        for i in range(R):
-            req = self.slots[i]
-            if req is None:
-                continue
-            if req.ctx >= req.target:           # decode / verify row
-                d = drafts.get(i)
-                n = 1 + (0 if d is None else len(d))
-                ids[t] = self.tok[i]
-                if n > 1:
-                    ids[t + 1:t + n] = d
-                pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
-                slot_vec[t:t + n] = self._write_slots(i, req.ctx, n)
-                qlen[i] = n
-                lens[i] = req.ctx + n
-                sample_idx[i * L:(i + 1) * L] = t   # spare lanes: dup t
-                sample_idx[i * L:i * L + n] = np.arange(t, t + n)
-                stream_pos[i * L:i * L + n] = (len(req.out_tokens)
-                                               + np.arange(n))
-                keys[i * L:(i + 1) * L] = req.key_data
-                post.append((i, True, n))
-                t += n
-            else:                                           # prefill chunk
-                n = grants.get(i, 0)
-                lens[i] = req.ctx + n
-                if n == 0:
-                    continue
-                ids[t:t + n] = req.full_seq[req.ctx:req.ctx + n]
-                pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
-                slot_vec[t:t + n] = self._write_slots(i, req.ctx, n)
-                qlen[i] = n
-                if req.ctx + n == req.target and not req.out_tokens:
-                    sample_idx[i * L] = t + n - 1  # first tok: last logits
-                    stream_pos[i * L] = 0
-                    keys[i * L] = req.key_data
-                post.append((i, False, n))
-                t += n
-        cu = np.zeros((R + 1,), np.int32)
-        np.cumsum(qlen, out=cu[1:])
-
-        _t0_ns = _tracing.now_ns()
-        # synthetic ledger row for the whole ragged step: it has no
-        # single jax.jit of its own (the model dispatches through the
-        # per-op exec cache, whose entries carry the FLOPs/HBM), but the
-        # step IS the serving unit of device work — and its host sync
-        # below makes the device-time measurement free
-        _pe = _p_sample = None
-        if _perf_mod.enabled():
-            _led = _perf_mod.ledger()
-            _pe = _led.register(
-                ("serving", self.max_batch, self.token_budget,
-                 self.spec_k, self.cache.kv_dtype),
-                "serving", name="serving_step")
-            _p_sample = _led.tick(_pe)
-        view = _RaggedView(
-            self.cache,
-            Tensor(jnp.asarray(slot_vec, jnp.int32)),
-            Tensor(jnp.asarray(self.cache.block_tables, jnp.int32)),
-            Tensor(jnp.asarray(lens, jnp.int32)),
-            Tensor(jnp.asarray(cu, jnp.int32)))
-        with no_grad():
-            logits = self.model(
-                Tensor(jnp.asarray(ids[None])), cache=view,
-                start_pos=Tensor(jnp.asarray(pos[None], jnp.int32)))
-            lrows = call_op("gather", logits.reshape([B, -1]),
-                            Tensor(jnp.asarray(sample_idx, jnp.int32)))
-            nxt = call_op("sample_logits_keyed", lrows,
-                          Tensor(jnp.asarray(keys)),
-                          Tensor(jnp.asarray(stream_pos, jnp.int32)),
-                          **self.sampling)
-        _td_ns = _tracing.now_ns()       # async dispatch returned
-        self.steps += 1
-        _M_STEPS.inc()
-        _M_STEP_TOKENS.inc(t)
-        sampled = np.asarray(nxt._data).reshape(-1)
-        if _pe is not None:
-            _perf_mod.ledger().commit(
-                _pe, (_td_ns - _t0_ns) / 1e9,
-                ((_tracing.now_ns() - _t0_ns) / 1e9
-                 if _p_sample else None))
-        # retroactive, on the thread timeline (untraced: one ragged step
-        # serves many requests): model call through the host sync above
-        _tracing.record_span(
-            "serving.step", _t0_ns, _tracing.now_ns(),
-            attrs={"tokens": t, "decode_rows": len(decode_rows),
-                   "prefill_rows": len(prefill_rows)})
-        if self.cache.quantized:
-            # every attended block is dequantized in-tile each step:
-            # bandwidth accounting for the int8 pool (per layer, per row)
-            _M_KV_DEQ.inc(sum((int(lens[i]) + bs - 1) // bs
-                              for i, _, _ in post)
-                          * self.cache.num_layers)
-        now = time.time()
-        finished: List[Request] = []
-        for i, is_decode, n in post:
-            req = self.slots[i]
-            if is_decode:
-                # exact-match verify: draft j is accepted iff it equals
-                # the keyed sample at its stream position — so spec-on
-                # output is byte-identical to spec-off at ANY temperature
-                # (the samples themselves are the ground truth). Accepted
-                # drafts validate the NEXT lane's logits; the first
-                # mismatch invalidates everything after it.
-                d = drafts.get(i)
-                nd = n - 1
-                base = i * L
-                a = 0
-                while a < nd and int(sampled[base + a]) == int(d[a]):
-                    a += 1
-                if nd:
-                    _M_SPEC_PROP.inc(nd)
-                    _M_SPEC_ACC.inc(a)
-                    _M_SPEC_REJ.inc(nd - a)
-                    _M_SPEC_ROWS.inc()
-                # rejected-draft KV rows (positions ctx+1+a..ctx+n-1) are
-                # garbage: context_lens hides them and the next step
-                # overwrites those slots in place
-                req.ctx += 1 + a
-                self.cache.context_lens[i] = req.ctx
-                for j in range(a + 1):
-                    self._append_token(req, i, int(sampled[base + j]),
-                                       now, finished)
-                    if req.done:
+        with _tracing.start_span("serving.step.schedule",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}) as sp:
+            B, R, bs = self.token_budget, self.max_batch, self.block_size
+            # fixed-size prefill chunks, round-robin by admission order, into
+            # the budget left after every decoding row's token
+            decode_rows = [i for i, r in enumerate(self.slots)
+                           if r is not None and r.ctx >= r.target]
+            prefill_rows = sorted(
+                (i for i, r in enumerate(self.slots)
+                 if r is not None and r.ctx < r.target),
+                key=lambda i: self.slots[i].admit_order)
+            grants = dict.fromkeys(prefill_rows, 0)
+            left = B - len(decode_rows)
+            while left > 0:
+                gave = False
+                for i in prefill_rows:
+                    req = self.slots[i]
+                    g = min(self.prefill_chunk,
+                            req.target - req.ctx - grants[i], left)
+                    if g > 0:
+                        grants[i] += g
+                        left -= g
+                        gave = True
+                    if left <= 0:
                         break
-            else:
-                req.ctx += n
-                self.cache.context_lens[i] = req.ctx
-                _M_PREFILL_TOKENS.inc(n)
-                _tracing.instant(
-                    "serving.prefill_chunk", trace=_req_trace(req),
-                    attrs={"rid": req.rid, "tokens": n, "ctx": req.ctx})
-                self._register_blocks(req, i, req.ctx)
-                if req.ctx == req.target:
-                    if req.out_tokens:  # resumed: next input pre-sampled
-                        self.tok[i] = req.out_tokens[-1]
-                    else:
-                        self._append_token(req, i, int(sampled[i * L]),
+                if not gave:
+                    break
+
+            # speculative drafts out of the LEFTOVER budget: each decode row
+            # may carry up to spec_k draft tokens, turning its q_len=1 row
+            # into a q_len=1+K' verify row (a prefill-chunk shape the step
+            # executable already compiles for). The emission cap keeps
+            # write positions inside the admission-time worst case, so the
+            # block reservation math is untouched by speculation.
+            drafts: Dict[int, np.ndarray] = {}
+            if self.spec_k and left > 0:
+                for i in decode_rows:
+                    req = self.slots[i]
+                    cap = min(self.spec_k,
+                              req.max_new_tokens - len(req.out_tokens) - 1,
+                              left)
+                    if cap <= 0:
+                        continue
+                    # proposal depends ONLY on this request's committed
+                    # tokens — never batch composition — so speculative
+                    # output stays schedule-independent
+                    hist = np.concatenate(
+                        [req.prompt, np.asarray(req.out_tokens, np.int32)])
+                    d = self.proposer.propose(hist, cap)
+                    if len(d):
+                        drafts[i] = np.asarray(d, np.int32)
+                        left -= len(d)
+                    if left <= 0:
+                        break
+            sp.set(decode_rows=len(decode_rows),
+                   prefill_rows=len(prefill_rows),
+                   granted=sum(grants.values()))
+
+        with _tracing.start_span("serving.step.pack",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}):
+            # L sample lanes per row: lane j of a verify row samples stream
+            # position len(out)+j from the logits of packed token t+j. With
+            # spec off L=1 and the arrays are exactly the legacy geometry.
+            L = self.spec_k + 1
+            ids = np.zeros((B,), np.int32)
+            pos = np.zeros((B,), np.int32)
+            slot_vec = np.full((B,), self._trash_slot, np.int64)
+            qlen = np.zeros((R,), np.int32)
+            lens = np.zeros((R,), np.int32)
+            sample_idx = np.zeros((R * L,), np.int32)
+            stream_pos = np.zeros((R * L,), np.int32)
+            keys = np.zeros((R * L, self._key_w), np.uint32)
+            post = []                      # (row, is_decode, n) commit plan
+            t = 0
+            for i in range(R):
+                req = self.slots[i]
+                if req is None:
+                    continue
+                if req.ctx >= req.target:           # decode / verify row
+                    d = drafts.get(i)
+                    n = 1 + (0 if d is None else len(d))
+                    ids[t] = self.tok[i]
+                    if n > 1:
+                        ids[t + 1:t + n] = d
+                    pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
+                    slot_vec[t:t + n] = self._write_slots(i, req.ctx, n)
+                    qlen[i] = n
+                    lens[i] = req.ctx + n
+                    sample_idx[i * L:(i + 1) * L] = t   # spare lanes: dup t
+                    sample_idx[i * L:i * L + n] = np.arange(t, t + n)
+                    stream_pos[i * L:i * L + n] = (len(req.out_tokens)
+                                                   + np.arange(n))
+                    keys[i * L:(i + 1) * L] = req.key_data
+                    post.append((i, True, n))
+                    t += n
+                else:                                           # prefill chunk
+                    n = grants.get(i, 0)
+                    lens[i] = req.ctx + n
+                    if n == 0:
+                        continue
+                    ids[t:t + n] = req.full_seq[req.ctx:req.ctx + n]
+                    pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
+                    slot_vec[t:t + n] = self._write_slots(i, req.ctx, n)
+                    qlen[i] = n
+                    if req.ctx + n == req.target and not req.out_tokens:
+                        sample_idx[i * L] = t + n - 1  # first tok: last logits
+                        stream_pos[i * L] = 0
+                        keys[i * L] = req.key_data
+                    post.append((i, False, n))
+                    t += n
+            cu = np.zeros((R + 1,), np.int32)
+            np.cumsum(qlen, out=cu[1:])
+
+        with _tracing.start_span("serving.step.dispatch",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}) as sp_dispatch:
+            launches = _M_LAUNCHES.value
+            # synthetic ledger row for the whole ragged step: it has no
+            # single jax.jit of its own (the model dispatches through the
+            # per-op exec cache, whose entries carry the FLOPs/HBM), but the
+            # step IS the serving unit of device work — and its host sync
+            # below makes the device-time measurement free
+            _pe = _p_sample = None
+            if _perf_mod.enabled():
+                _led = _perf_mod.ledger()
+                _pe = _led.register(
+                    ("serving", self.max_batch, self.token_budget,
+                     self.spec_k, self.cache.kv_dtype),
+                    "serving", name="serving_step")
+                _p_sample = _led.tick(_pe)
+            view = _RaggedView(
+                self.cache,
+                Tensor(jnp.asarray(slot_vec, jnp.int32)),
+                Tensor(jnp.asarray(self.cache.block_tables, jnp.int32)),
+                Tensor(jnp.asarray(lens, jnp.int32)),
+                Tensor(jnp.asarray(cu, jnp.int32)))
+            with no_grad():
+                logits = self.model(
+                    Tensor(jnp.asarray(ids[None])), cache=view,
+                    start_pos=Tensor(jnp.asarray(pos[None], jnp.int32)))
+                lrows = call_op("gather", logits.reshape([B, -1]),
+                                Tensor(jnp.asarray(sample_idx, jnp.int32)))
+                nxt = call_op("sample_logits_keyed", lrows,
+                              Tensor(jnp.asarray(keys)),
+                              Tensor(jnp.asarray(stream_pos, jnp.int32)),
+                              **self.sampling)
+            launches = _M_LAUNCHES.value - launches
+            self.steps += 1
+            _M_STEPS.inc()
+            _M_STEP_TOKENS.inc(t)
+        with _tracing.start_span("serving.step.sync",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}) as sp_sync:
+            sampled = np.asarray(nxt._data).reshape(-1)
+
+        with _tracing.start_span("serving.step.commit",
+                                 trace=_tracing.UNTRACED,
+                                 attrs={"step": n_step}):
+            # the phases' own edges: dispatch began, its last async launch
+            # returned, the tokens were on the host (none with FLAGS_tracing
+            # off: the ledger row then counts its calls only)
+            t0_ns, td_ns = sp_dispatch.t0_ns, sp_dispatch.t1_ns
+            t1_ns = sp_sync.t1_ns
+            if td_ns is not None and t1_ns is not None:
+                if _pe is not None:
+                    _perf_mod.ledger().commit(
+                        _pe, (td_ns - t0_ns) / 1e9,
+                        (t1_ns - t0_ns) / 1e9 if _p_sample else None)
+                # retroactive, on the thread timeline: the model call
+                # through the host sync (dispatch + sync above)
+                _tracing.record_span(
+                    "serving.step", t0_ns, t1_ns,
+                    attrs={"tokens": t, "decode_rows": len(decode_rows),
+                           "prefill_rows": len(prefill_rows),
+                           "launches": launches})
+            if self.cache.quantized:
+                # every attended block is dequantized in-tile each step:
+                # bandwidth accounting for the int8 pool (per layer, per row)
+                _M_KV_DEQ.inc(sum((int(lens[i]) + bs - 1) // bs
+                                  for i, _, _ in post)
+                              * self.cache.num_layers)
+            now = time.time()
+            finished: List[Request] = []
+            for i, is_decode, n in post:
+                req = self.slots[i]
+                if is_decode:
+                    # exact-match verify: draft j is accepted iff it equals
+                    # the keyed sample at its stream position — so spec-on
+                    # output is byte-identical to spec-off at ANY temperature
+                    # (the samples themselves are the ground truth). Accepted
+                    # drafts validate the NEXT lane's logits; the first
+                    # mismatch invalidates everything after it.
+                    d = drafts.get(i)
+                    nd = n - 1
+                    base = i * L
+                    a = 0
+                    while a < nd and int(sampled[base + a]) == int(d[a]):
+                        a += 1
+                    if nd:
+                        _M_SPEC_PROP.inc(nd)
+                        _M_SPEC_ACC.inc(a)
+                        _M_SPEC_REJ.inc(nd - a)
+                        _M_SPEC_ROWS.inc()
+                    # rejected-draft KV rows (positions ctx+1+a..ctx+n-1) are
+                    # garbage: context_lens hides them and the next step
+                    # overwrites those slots in place
+                    req.ctx += 1 + a
+                    self.cache.context_lens[i] = req.ctx
+                    for j in range(a + 1):
+                        self._append_token(req, i, int(sampled[base + j]),
                                            now, finished)
-        if self.on_finish is not None:
-            for req in finished:
-                self.results.pop(req.rid, None)
-                self.on_finish(req)
-        if finished:
-            with self.finish_cv:
-                self.finish_cv.notify_all()
+                        if req.done:
+                            break
+                else:
+                    req.ctx += n
+                    self.cache.context_lens[i] = req.ctx
+                    _M_PREFILL_TOKENS.inc(n)
+                    self._register_blocks(req, i, req.ctx)
+                    if req.ctx == req.target:
+                        if req.out_tokens:  # resumed: next input pre-sampled
+                            self.tok[i] = req.out_tokens[-1]
+                        else:
+                            self._append_token(req, i, int(sampled[i * L]),
+                                               now, finished)
+            if self.on_finish is not None:
+                for req in finished:
+                    self.results.pop(req.rid, None)
+                    self.on_finish(req)
+            if finished:
+                with self.finish_cv:
+                    self.finish_cv.notify_all()
         return finished
 
     def pop_result(self, rid: int,

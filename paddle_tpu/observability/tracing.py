@@ -24,7 +24,13 @@ always-on, near-zero-cost third leg:
   ``chrome://tracing`` / Perfetto), merged into the profiler's chrome
   trace when a window is open (:func:`set_span_sink`) and dumped next
   to the flight recorder on uncaught exception (:func:`_crash_dump`,
-  chained by ``flight_recorder.install_excepthook``).
+  chained by ``flight_recorder.install_excepthook``);
+* a **live** span (:func:`span`, :func:`start_span`) also holds a
+  ``jax.profiler.TraceAnnotation`` of its name open for its duration, so
+  whenever a ``jax.profiler`` trace is running the program's spans are
+  in the xplane on the host thread's line, on the device trace's clock
+  (a no-op in C++ otherwise). Retroactive spans and instants stay
+  ring-only; :func:`finished_spans` reads the ring back.
 
 The span-name taxonomy is FROZEN (:data:`SPAN_NAMES`) exactly like
 ``metrics.METRIC_NAMES``: a typo'd name would silently fork the
@@ -39,6 +45,8 @@ Span phases for one served request (TTFT = queue + compile + kernel)::
                    serving.prefill    admission -> first token
                    serving.decode     first token -> finish
     serving.step                      one ragged engine step (kernel time)
+    serving.step.admit .schedule .pack .dispatch .sync .commit
+                                      the host's phases of one step()
     jit.compile                       XLA compiles, parented if ambient
 """
 
@@ -51,7 +59,9 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, IO, List, Optional, Tuple
+from typing import Any, Dict, IO, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import flags as _flags
 from . import metrics as _metrics
@@ -60,7 +70,8 @@ __all__ = [
     "SPAN_NAMES", "Span", "span", "start_span", "record_span", "instant",
     "event", "activate", "deactivate", "current", "current_trace_id",
     "inject", "extract", "enabled", "now_ns", "dump_trace", "to_chrome",
-    "set_span_sink", "clear", "active_spans",
+    "set_span_sink", "clear", "active_spans", "finished_spans",
+    "FinishedSpan", "UNTRACED",
 ]
 
 # one-attribute-read disabled path, same discipline as _F_METRICS
@@ -97,10 +108,17 @@ SPAN_NAMES = frozenset({
     "serving.step_hang",       # event: watchdog fired on a wedged step
     # models/serving.py — the ragged engine's per-request phases
     "serving.step",            # span: ONE ragged mixed prefill+decode step
+    #                            (retro: dispatch + sync, attrs launches)
+    "serving.step.admit",      # span: _admit, preemption check, gauges
+    "serving.step.schedule",   # span: decode/prefill rows, grants, drafts
+    "serving.step.pack",       # span: the step's numpy arrays and cu
+    "serving.step.dispatch",   # span: uploads, model, gather, sample:
+    #                            until the last async launch returns
+    "serving.step.sync",       # span: the sampled tokens' transfer
+    "serving.step.commit",     # span: the post loop, on_finish, notify
     "serving.queue",           # span (retro): arrival -> row-slot admission
     "serving.prefill",         # span (retro): slot admission -> first token
     "serving.decode",          # span (retro): first token -> finish
-    "serving.prefill_chunk",   # event: one prefill chunk committed
     "serving.first_token",     # event: the TTFT edge
     "serving.finish",          # event: request finished
     "serving.preempt",         # event: LIFO preemption victim
@@ -131,6 +149,9 @@ SPAN_NAMES = frozenset({
 })
 
 _EVENTS_MAX = 256             # per-span event cap (rings bound everything else)
+
+# the explicit carrier of a span that belongs to no request's trace
+UNTRACED = (0, 0)
 
 now_ns = time.perf_counter_ns
 
@@ -181,7 +202,7 @@ class Span:
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0_ns",
                  "t1_ns", "tid", "attrs", "events", "kind", "_token",
-                 "_ended")
+                 "_ended", "_ann")
 
     def __init__(self, name: str, trace_id: int, parent_id: int,
                  attrs: Optional[Dict[str, Any]] = None,
@@ -199,6 +220,7 @@ class Span:
         self.kind = kind
         self._token = None
         self._ended = False
+        self._ann = None           # the profiler annotation of a live span
 
     # -- context --------------------------------------------------------------
     @property
@@ -229,6 +251,9 @@ class Span:
         if self._ended:
             return
         self._ended = True
+        if self._ann is not None:      # left first: it lies inside [t0, t1]
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self.t1_ns = now_ns()
         _ACTIVE.pop(self.span_id, None)
         if self._token is not None:
@@ -261,6 +286,7 @@ class _NoopSpan:
     span_id = 0
     parent_id = 0
     context = (0, 0)
+    t0_ns = t1_ns = None       # the disabled path reads no clock
 
     def set(self, **attrs):
         return self
@@ -356,6 +382,28 @@ def active_spans() -> List[Span]:
     return sorted(_ACTIVE.values(), key=lambda s: s.t0_ns)
 
 
+class FinishedSpan(NamedTuple):
+    """What a reader of the ring gets: stamps are ``perf_counter_ns``."""
+    name: str
+    t0_ns: int
+    t1_ns: int
+    attrs: Dict[str, Any]
+
+
+def finished_spans(prefix: str = "", since_ns: Optional[int] = None
+                   ) -> List[FinishedSpan]:
+    """The ring's finished spans (no instants) by start time, optionally
+    only names starting with ``prefix`` and spans that start at or after
+    ``since_ns``. The ring is bounded (``FLAGS_tracing_ring_size``): the
+    oldest are gone first."""
+    if _RING is None:
+        return []
+    return [FinishedSpan(sp.name, sp.t0_ns, sp.t1_ns, sp.attrs or {})
+            for sp in _RING.entries()
+            if sp.kind == "span" and sp.name.startswith(prefix)
+            and (since_ns is None or sp.t0_ns >= since_ns)]
+
+
 # -- span creation ------------------------------------------------------------
 
 def _parent(trace) -> Tuple[int, int]:
@@ -368,6 +416,19 @@ def _parent(trace) -> Tuple[int, int]:
     return (_new_trace_id(), 0)
 
 
+def _open(name: str, trace, attrs) -> Span:
+    """A live span: registered for the crash dump and, while a
+    ``jax.profiler`` trace runs, an annotation of the same name on the
+    host thread's line of the xplane, entered last so that it lies inside
+    the ring's [t0, t1]."""
+    tid, parent = _parent(trace)
+    sp = Span(name, tid, parent, attrs)
+    _ACTIVE[sp.span_id] = sp
+    sp._ann = _TraceAnnotation(name)
+    sp._ann.__enter__()
+    return sp
+
+
 def span(name: str, *, trace=None, attrs=None):
     """Open an ACTIVATED span: it becomes the ambient context (children
     opened inside — same thread, or via an awaited contextvars copy —
@@ -376,22 +437,19 @@ def span(name: str, *, trace=None, attrs=None):
     with an explicit ``(trace_id, span_id)`` carrier."""
     if not _F_TRACING.value:
         return _NOOP
-    tid, parent = _parent(trace)
-    sp = Span(name, tid, parent, attrs)
-    _ACTIVE[sp.span_id] = sp
-    sp._token = _CTX.set((tid, sp.span_id))
+    sp = _open(name, trace, attrs)
+    sp._token = _CTX.set(sp.context)
     return sp
 
 
 def start_span(name: str, *, trace=None, attrs=None):
     """Open a NON-activating span (no contextvar mutation): for phases a
-    caller holds across steps/threads and ends explicitly."""
+    caller holds across steps/threads and ends explicitly, and, as a
+    context manager with ``trace=UNTRACED``, for the phases of a step
+    that serves many requests."""
     if not _F_TRACING.value:
         return _NOOP
-    tid, parent = _parent(trace)
-    sp = Span(name, tid, parent, attrs)
-    _ACTIVE[sp.span_id] = sp
-    return sp
+    return _open(name, trace, attrs)
 
 
 def record_span(name: str, t0_ns: int, t1_ns: int, *, trace=None,

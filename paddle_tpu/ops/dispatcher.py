@@ -206,6 +206,10 @@ def _get_exec(op_name: str, attrs_key: Tuple, present_mask: Tuple[bool, ...],
             return tuple(res)
         return (res,)
 
+    # the name jax.jit gives the XLA module (jit_op_<name>) and the
+    # profiler's host launch event: a trace then says which op each of a
+    # step's launches is
+    fwd_flat.__name__ = fwd_flat.__qualname__ = f"op_{op_name}"
     fwd = jax.jit(fwd_flat) if use_jit else fwd_flat
     perf_key = ("op", op_name, attrs_key, present_mask, fver)
     if use_jit:
@@ -239,6 +243,7 @@ def _get_exec(op_name: str, attrs_key: Tuple, present_mask: Tuple[bool, ...],
         _, vjp = jax.vjp(f_float, *(p for p, d in zip(frozen, dmask) if d))
         return vjp(tuple(cts_float))
 
+    vjp_run.__name__ = vjp_run.__qualname__ = f"op_{op_name}_vjp"
     vjp_j = jax.jit(vjp_run) if use_jit else vjp_run
     if use_jit:
         from ..jit import exec_store as _exec_store

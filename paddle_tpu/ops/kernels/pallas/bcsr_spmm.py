@@ -94,6 +94,7 @@ def bcsr_spmm(crows, cols, values, x, bn: int = 512):
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mb * bm, Np), x.dtype),
+        name="bcsr_spmm",
         interpret=_interpret(),
     )(jnp.asarray(row_of), jnp.asarray(first), jnp.asarray(last),
       jnp.asarray(cols_np), values, xp)

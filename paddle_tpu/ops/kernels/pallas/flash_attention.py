@@ -123,6 +123,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jax.lax.transpose(m_scr[:, :1] + jnp.log(l), (1, 0))
 
 
+# The scope around each pallas_call's own name= takes the decoration of a
+# transform (jvp(...), transpose(jvp(...))) in the kernel's place: the
+# custom call is then flash_attention_fwd / _bwd_dq / _bwd_dkv in the
+# compiled program and in a device trace under jax.grad too, not
+# jvp_flash_attention_fwd_ (tests/test_chip_compile.py).
+@jax.named_scope("flash_attention")
 def _fwd(q, k, v, causal, scale):
     """q: [bh, sq, d]; k/v: [bh_kv, sk, d] -> (out [bh, sq, d], lse [bh, sq])."""
     bh, sq, d = q.shape
@@ -154,6 +160,7 @@ def _fwd(q, k, v, causal, scale):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
@@ -244,6 +251,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@jax.named_scope("flash_attention")
 def _bwd(causal, scale, res, dout, dlse=None):
     q, k, v, out, lse = res
     bh, sq, d = q.shape
@@ -276,6 +284,7 @@ def _bwd(causal, scale, res, dout, dlse=None):
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, dout, lse, delta)
 
@@ -308,6 +317,7 @@ def _bwd(causal, scale, res, dout, dlse=None):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv

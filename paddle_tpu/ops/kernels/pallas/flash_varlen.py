@@ -221,6 +221,9 @@ def _varlen(q, k, v, cu_q, cu_k, causal, scale, tok_skip):
     return out
 
 
+# outer scope: keeps the kernels' name= plain under jax.grad (see
+# flash_attention.py)
+@jax.named_scope("flash_varlen")
 def _varlen_fwd_impl(q, k, v, cu_q, cu_k, causal, scale, tok_skip):
     Tq, h, d = q.shape
     Tk, hk, _ = k.shape
@@ -272,6 +275,7 @@ def _varlen_fwd_impl(q, k, v, cu_q, cu_k, causal, scale, tok_skip):
             jax.ShapeDtypeStruct((h, Tqp, d), q.dtype),
             jax.ShapeDtypeStruct((h, 1, Tqp), jnp.float32),
         ],
+        name="flash_varlen_fwd",
         interpret=_interpret(),
     )(ranges, qf, kf, vf, sq2, pq2, sk2, pk2)
     return jnp.swapaxes(out[:, :Tq], 0, 1), (qf, kf, vf, out, lse, ranges,
@@ -284,6 +288,7 @@ def _varlen_fwd(q, k, v, cu_q, cu_k, causal, scale, tok_skip):
     return out, (res, q.shape, k.shape)
 
 
+@jax.named_scope("flash_varlen")
 def _varlen_bwd(causal, scale, tok_skip, carry, dout):
     res, q_shape, k_shape = carry
     qf, kf, vf, outf, lse, ranges, sq2, pq2, sk2, pk2 = res
@@ -324,6 +329,7 @@ def _varlen_bwd(causal, scale, tok_skip, carry, dout):
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((h, Tqp, d), qf.dtype),
+        name="flash_varlen_bwd_dq",
         interpret=_interpret(),
     )(ranges, qf, kf, vf, dof, lse, delta, sq2, pq2, sk2, pk2)
 
@@ -368,6 +374,7 @@ def _varlen_bwd(causal, scale, tok_skip, carry, dout):
             jax.ShapeDtypeStruct((hk, Tkp, d), kf.dtype),
             jax.ShapeDtypeStruct((hk, Tkp, d), vf.dtype),
         ],
+        name="flash_varlen_bwd_dkv",
         interpret=_interpret(),
     )(ranges, qf, kf, vf, dof, lse, delta, sq2, pq2, sk2, pk2)
 

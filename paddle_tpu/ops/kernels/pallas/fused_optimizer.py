@@ -291,6 +291,7 @@ def _bucket_kernel_call(body, bucket, inputs, out_dtypes):
     out_shape = [jax.ShapeDtypeStruct((rows, _LANES), d) for d in out_dtypes]
     return pl.pallas_call(
         body, grid_spec=grid_spec, out_shape=out_shape,
+        name="fused_optimizer",
         interpret=_interpret())(*inputs)
 
 

@@ -106,6 +106,9 @@ def _gmm_wide_kernel(counts_ref, x_ref, w_ref, o_ref, *, bc, bn):
 _WIDE_N_W_BYTES = 8 * 1024 * 1024
 
 
+# outer scope: keeps the kernels' name= plain under jax.grad (see
+# flash_attention.py)
+@jax.named_scope("grouped_gemm")
 def _gmm_impl(x, w, counts, gpe: int):
     G, C, K = x.shape
     E, _, N = w.shape
@@ -137,6 +140,7 @@ def _gmm_impl(x, w, counts, gpe: int):
             compiler_params=tpu_compiler_params(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=110 * 1024 * 1024),
+            name="grouped_gemm_wide",
             interpret=_interpret(),
         )(counts.astype(jnp.int32), x, w)
         return y[:, :C, :N]
@@ -171,6 +175,7 @@ def _gmm_impl(x, w, counts, gpe: int):
         functools.partial(_gmm_kernel, bc=bc, bn=bn, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, Cp, Np), out_dtype),
+        name="grouped_gemm",
         interpret=_interpret(),
     )(counts.astype(jnp.int32), x, w)
     return y[:, :C, :N]

@@ -111,6 +111,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                           scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, D), q.dtype),
+        name="paged_attention",
         interpret=_interpret(),
     )(jnp.clip(block_tables.astype(jnp.int32), 0, NB - 1),
       context_lens.astype(jnp.int32), qr, k_pool, v_pool)

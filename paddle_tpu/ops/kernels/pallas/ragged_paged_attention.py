@@ -190,6 +190,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                           scale=float(scale), quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NT, KV, TG, D), out_dtype),
+        name="ragged_paged_attention",
         interpret=_interpret(),
     )(row_of, qpos0, qcount,
       jnp.clip(block_tables.astype(jnp.int32), 0, NB - 1),

@@ -127,6 +127,7 @@ def _pallas_int4_matmul(x, qweight, scales, bn: int = 512,
         out_specs=pl.BlockSpec((mp, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((mp, bn), jnp.float32)],
+        name="weight_only_gemm",
         interpret=jax.default_backend() != "tpu",
     )(xe, xo, qweight)
     out = acc * scales.reshape(1, n).astype(jnp.float32)

@@ -11,6 +11,8 @@ else: only one process may hold libtpu, every xdist worker imports this
 file, and only the worker that is handed it may load the library. Keep
 these tests in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,8 +70,18 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
-def test_flash_fwd_bwd(mosaic, one_chip, kv_heads):
+_TEXTS = {}
+
+
+def _compiled(case, build):
+    """The compiled text of ``case``, compiled once a process: the cases
+    that read the kernels' names share it with the cases that compile."""
+    if case not in _TEXTS:
+        _TEXTS[case] = build()
+    return _TEXTS[case]
+
+
+def _flash_text(one_chip, kv_heads):
     q = _sds(one_chip, (2, 2048, H, D), jnp.bfloat16)
     kv = _sds(one_chip, (2, 2048, kv_heads, D), jnp.bfloat16)
     assert fa.supported(q.shape, kv.shape, True)
@@ -78,8 +90,14 @@ def test_flash_fwd_bwd(mosaic, one_chip, kv_heads):
         out = fa.flash_attention(q, k, v, causal=True)
         return jnp.sum(out.astype(jnp.float32))
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text()
+    return _compiled(("flash", kv_heads), lambda: jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text())
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+def test_flash_fwd_bwd(mosaic, one_chip, kv_heads):
+    text = _flash_text(one_chip, kv_heads)
     assert text.count(CUSTOM_CALL) >= 3      # fwd, dq, dk/dv
 
 
@@ -104,9 +122,7 @@ def test_sharded_flash_on_the_hybrid_mesh(mosaic, topo):
     assert text.count(CUSTOM_CALL) >= 3
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-def test_ragged_paged_attention(mosaic, one_chip, pool_dtype):
+def _ragged_text(one_chip, pool_dtype):
     # the serve phase's step: 512 packed tokens over 8 rows, 64-token
     # blocks, tables as wide as max_position_embeddings / block_size
     tokens, rows, blocks, bs, width = 512, 8, 130, 64, 64
@@ -117,15 +133,21 @@ def test_ragged_paged_attention(mosaic, one_chip, pool_dtype):
     if pool_dtype == jnp.int8:
         s = _sds(one_chip, (blocks, bs, KV), jnp.float32)
         scales = dict(k_scale=s, v_scale=s)
-    text = jax.jit(rpa.ragged_paged_attention).lower(
-        q, pool, pool, _sds(one_chip, (rows, width), jnp.int32),
-        _sds(one_chip, (rows,), jnp.int32),
-        _sds(one_chip, (rows + 1,), jnp.int32), **scales,
-    ).compile().as_text()
-    assert CUSTOM_CALL in text
+    return _compiled(("ragged", pool_dtype), lambda: jax.jit(
+        rpa.ragged_paged_attention).lower(
+            q, pool, pool, _sds(one_chip, (rows, width), jnp.int32),
+            _sds(one_chip, (rows,), jnp.int32),
+            _sds(one_chip, (rows + 1,), jnp.int32), **scales,
+        ).compile().as_text())
 
 
-def test_fused_adamw_bucket(mosaic, one_chip):
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_ragged_paged_attention(mosaic, one_chip, pool_dtype):
+    assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype)
+
+
+def _fused_adamw_text(one_chip):
     # one decoder layer's matrices and a norm in one bucket: 78.6 M
     # elements, a row count that is not a multiple of the 512-row block
     shapes = ((HIDDEN, FFN), (HIDDEN, HIDDEN), (HIDDEN, HIDDEN), (HIDDEN,))
@@ -143,6 +165,40 @@ def test_fused_adamw_bucket(mosaic, one_chip):
         return fok.fused_apply(plan, p, g, s, lr, step, 1.0, 1.0, 0.0,
                                use_pallas=True, condition=False)
 
-    text = jax.jit(apply).lower(p, g, state, scalar,
-                                scalar).compile().as_text()
-    assert CUSTOM_CALL in text
+    return _compiled("fused_adamw", lambda: jax.jit(apply).lower(
+        p, g, state, scalar, scalar).compile().as_text())
+
+
+def test_fused_adamw_bucket(mosaic, one_chip):
+    assert CUSTOM_CALL in _fused_adamw_text(one_chip)
+
+
+def _custom_call_names(text):
+    """The instruction names of the compiled text's Pallas calls, without
+    XLA's counter: what a device trace's ``XLA Ops`` line shows."""
+    names = set()
+    for line in text.splitlines():
+        if CUSTOM_CALL in line and "custom-call(" in line:
+            m = re.match(r"\s*(?:ROOT\s+)?%?([^\s=]+)\s*=", line)
+            names.add(re.sub(r"\.\d+$", "", m.group(1)))
+    return names
+
+
+@pytest.mark.parametrize("text_of, kernel, others", [
+    (lambda c: _ragged_text(c, jnp.bfloat16), "ragged_paged_attention", ()),
+    (lambda c: _ragged_text(c, jnp.int8), "ragged_paged_attention", ()),
+    (lambda c: _flash_text(c, 8), "flash_attention_fwd",
+     ("flash_attention_bwd_dq", "flash_attention_bwd_dkv")),
+    (lambda c: _flash_text(c, 8), "flash_attention_bwd_dq",
+     ("flash_attention_fwd", "flash_attention_bwd_dkv")),
+    (lambda c: _flash_text(c, 8), "flash_attention_bwd_dkv",
+     ("flash_attention_fwd", "flash_attention_bwd_dq")),
+    (_fused_adamw_text, "fused_optimizer", ()),
+], ids=["ragged-bf16", "ragged-int8", "flash-fwd", "flash-bwd-dq",
+        "flash-bwd-dkv", "fused-adamw"])
+def test_custom_call_carries_its_kernels_name(mosaic, one_chip, text_of,
+                                              kernel, others):
+    # the name= of the pl.pallas_call, plain: under jax.grad too, where a
+    # bare name would read jvp_<name>_ and transpose_jvp_<name>__; readers
+    # of a device trace look kernels up by these names (ISSUE 26)
+    assert _custom_call_names(text_of(one_chip)) == {kernel, *others}

@@ -562,3 +562,81 @@ class TestSpeculativeDecode:
         finally:
             paddle.set_flags(saved)
         assert _metric("serving.spec.fallback") == fb0 + 1
+
+
+PHASES = ("admit", "schedule", "pack", "dispatch", "sync", "commit")
+
+
+class TestStepPhases:
+    """ISSUE 26: the host's phases of one ``step()`` as spans."""
+
+    def _run(self, model):
+        from paddle_tpu.observability import tracing
+        tracing.clear()
+        rng = np.random.RandomState(5)
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                       block_size=16, temperature=0.0,
+                                       token_budget=16, prefill_chunk=8)
+        rids = [eng.add_request(rng.randint(0, 128, n).tolist(),
+                                max_new_tokens=5) for n in (21, 6, 11)]
+        launches = []
+        while eng.pending or eng.num_active:
+            before = _metric("dispatch.count")
+            eng.step()
+            launches.append(_metric("dispatch.count") - before)
+        return eng, [eng.results[r].out_tokens for r in rids], launches
+
+    def test_every_step_records_its_six_phases_in_order(self, model):
+        from paddle_tpu.observability import tracing
+        eng, _, _ = self._run(model)
+        assert eng.steps > 5
+        spans = tracing.finished_spans("serving.step.")
+        by_step = {}
+        for sp in spans:
+            by_step.setdefault(sp.attrs["step"], []).append(sp)
+        assert sorted(by_step) == list(range(1, eng.steps + 1))
+        for n, got in by_step.items():
+            assert [s.name for s in got] == [
+                "serving.step." + p for p in PHASES], n
+            for a, b in zip(got, got[1:]):
+                assert a.t0_ns <= a.t1_ns <= b.t0_ns      # no overlap
+        sched = [s for s in spans if s.name == "serving.step.schedule"]
+        assert all({"decode_rows", "prefill_rows", "granted"} <= set(s.attrs)
+                   for s in sched)
+        # 38 prompt tokens, none shared: every one was granted once
+        assert sum(s.attrs["granted"] for s in sched) == 21 + 6 + 11
+
+    def test_step_span_is_dispatch_plus_sync_and_counts_launches(self, model):
+        from paddle_tpu.observability import tracing
+        eng, _, launches = self._run(model)
+        steps = [s for s in tracing.finished_spans("serving.step")
+                 if s.name == "serving.step"]
+        assert len(steps) == eng.steps == len(launches)
+        phases = tracing.finished_spans("serving.step.")
+        for k, (sp, n) in enumerate(zip(steps, launches), start=1):
+            disp, sync = [p for p in phases if p.attrs["step"] == k
+                          and p.name in ("serving.step.dispatch",
+                                         "serving.step.sync")]
+            assert sp.t0_ns == disp.t0_ns and sp.t1_ns == sync.t1_ns
+            assert sp.attrs["launches"] == n > 0
+            assert {"tokens", "decode_rows", "prefill_rows"} <= set(sp.attrs)
+
+    def test_idle_step_records_admit_only(self, model):
+        from paddle_tpu.observability import tracing
+        tracing.clear()
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                       block_size=16, temperature=0.0)
+        assert eng.step() == []
+        assert [s.name for s in tracing.finished_spans("serving.")] == [
+            "serving.step.admit"]
+
+    def test_tokens_are_the_same_with_tracing_off(self, model):
+        from paddle_tpu.observability import tracing
+        _, on, _ = self._run(model)
+        paddle.set_flags({"FLAGS_tracing": False})
+        try:
+            _, off, _ = self._run(model)
+            assert tracing.finished_spans() == []
+        finally:
+            paddle.set_flags({"FLAGS_tracing": True})
+        assert on == off and all(len(t) == 5 for t in on)

@@ -348,6 +348,27 @@ class TestExecCacheFlagVersion:
             paddle.set_flags(prev)
 
 
+class TestExecNames:
+    @pytest.mark.parametrize("op, ref, n_in", [("multiply", np.multiply, 2),
+                                               ("tanh", np.tanh, 1)])
+    def test_jitted_function_and_module_carry_the_ops_name(self, op, ref,
+                                                           n_in):
+        """A profile's XLA Modules line and its PjitFunction(...) host
+        events say which op each launch of an eager step is (ISSUE 26:
+        every one used to read fwd_flat)."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops import dispatcher as D
+
+        fwd, vjp = D._get_exec(op, (), (1,) * n_in, (True,) * n_in, 0, True)
+        assert fwd.__name__ == f"op_{op}"
+        assert vjp.__name__ == f"op_{op}_vjp"
+        x = np.full((4, 4), 0.5, np.float32)
+        args = [jnp.asarray(x)] * n_in
+        assert f"module @jit_op_{op} " in fwd.lower(*args).as_text()
+        np.testing.assert_allclose(fwd(*args)[0], ref(*[x] * n_in),
+                                   rtol=1e-6)
+
+
 class TestEagerLoopSteering:
     def test_warns_once_at_threshold(self):
         # VERDICT r4 Weak#5: sustained eager dispatch is launch-bound;

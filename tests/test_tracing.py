@@ -235,6 +235,87 @@ class TestRingBounds:
         assert tracing._ring().total == 0
 
 
+# ------------------------------------------ ring reader + xplane (fast)
+
+def _xplane_host_events(log_dir, prefix):
+    """(name, start_ns, duration_ns) of the host planes' events whose name
+    starts with ``prefix``, read back from the one trace under log_dir."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+class TestProfilerClock:
+    """A live span is one record in the ring and, while a jax.profiler
+    trace runs, one host event of the same name in the xplane."""
+
+    @pytest.mark.parametrize("opener", ["span", "start_span"])
+    def test_live_span_is_in_the_ring_and_the_xplane(self, tmp_path, opener):
+        import jax
+        tracing.clear()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with getattr(tracing, opener)("serving.step.pack",
+                                          trace=tracing.UNTRACED,
+                                          attrs={"step": 7}):
+                time.sleep(0.002)
+            tracing.record_span("serving.queue", 1, 2)     # ring only
+            tracing.instant("serving.finish")              # ring only
+        finally:
+            jax.profiler.stop_trace()
+        (got,) = tracing.finished_spans("serving.step.")
+        assert got.name == "serving.step.pack" and got.attrs == {"step": 7}
+        assert got.t1_ns - got.t0_ns >= 2_000_000
+        events = _xplane_host_events(str(tmp_path), "serving.")
+        assert [e[0] for e in events] == ["serving.step.pack"]
+        # the annotation lies inside the ring's stamps, to a few microseconds
+        assert 0 <= (got.t1_ns - got.t0_ns) - events[0][2] < 1_000_000
+
+    def test_tracing_off_reaches_neither(self, tmp_path):
+        import jax
+        tracing.clear()
+        paddle.set_flags({"FLAGS_tracing": False})
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracing.start_span("serving.step.pack") as sp:
+                assert sp.t0_ns is None and sp.t1_ns is None
+            with tracing.span("serving.step.sync"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+            paddle.set_flags({"FLAGS_tracing": True})
+        assert tracing.finished_spans() == []
+        assert _xplane_host_events(str(tmp_path), "serving.") == []
+
+    def test_finished_spans_filters_by_prefix_and_start(self):
+        tracing.clear()
+        tracing.record_span("serving.queue", 10, 20, attrs={"rid": 1})
+        tracing.record_span("serving.step", 30, 40)
+        tracing.record_span("serving.step.sync", 35, 40)
+        tracing.instant("serving.finish")
+        names = lambda spans: [s.name for s in spans]
+        assert names(tracing.finished_spans()) == [
+            "serving.queue", "serving.step", "serving.step.sync"]
+        assert names(tracing.finished_spans("serving.step")) == [
+            "serving.step", "serving.step.sync"]
+        assert names(tracing.finished_spans("serving.", since_ns=30)) == [
+            "serving.step", "serving.step.sync"]
+        assert tracing.finished_spans("serving.queue")[0] == (
+            "serving.queue", 10, 20, {"rid": 1})
+        assert tracing.finished_spans("fleet.") == []
+
+    def test_default_ring_holds_a_benchmark_window(self):
+        # 50 s at 8.45 steps/s write some 4,000 entries (ISSUE 26)
+        assert paddle.get_flags(["FLAGS_tracing_ring_size"])[
+            "FLAGS_tracing_ring_size"] >= 16384
+
+
 # ------------------------------------------------------- chrome export (fast)
 
 class TestChromeExport:
