@@ -59,6 +59,7 @@ from ..observability import metrics as _metrics_mod
 from ..observability import perf as _perf_mod
 from ..observability import tracing as _tracing
 from ..ops.dispatcher import call_op
+from ..ops.kernels.pallas import ragged_paged_attention as _rpa
 from .generation import PagedKVCache, kv_pool_blocks
 
 __all__ = ["Request", "ContinuousBatchingEngine", "GangScheduledEngine",
@@ -879,6 +880,11 @@ class ContinuousBatchingEngine:
                     t += n
             cu = np.zeros((R + 1,), np.int32)
             np.cumsum(qlen, out=cu[1:])
+            # what one layer's attention call walks: its tiles' live kv
+            # blocks, beside the tile x table-column pairs of the whole grid
+            kv_tile_blocks = _rpa.live_tile_blocks(qlen, lens, bs)
+            kv_table_blocks = (_rpa.num_tiles(R, B)
+                               * self.cache.block_tables.shape[1])
 
         with _tracing.start_span("serving.step.dispatch",
                                  trace=_tracing.UNTRACED,
@@ -941,7 +947,9 @@ class ContinuousBatchingEngine:
                     "serving.step", t0_ns, t1_ns,
                     attrs={"tokens": t, "decode_rows": len(decode_rows),
                            "prefill_rows": len(prefill_rows),
-                           "launches": launches})
+                           "launches": launches,
+                           "kv_tile_blocks": kv_tile_blocks,
+                           "kv_table_blocks": kv_table_blocks})
             if self.cache.quantized:
                 # every attended block is dequantized in-tile each step:
                 # bandwidth accounting for the int8 pool (per layer, per row)

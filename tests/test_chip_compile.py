@@ -122,29 +122,73 @@ def test_sharded_flash_on_the_hybrid_mesh(mosaic, topo):
     assert text.count(CUSTOM_CALL) >= 3
 
 
-def _ragged_text(one_chip, pool_dtype):
-    # the serve phase's step: 512 packed tokens over 8 rows, 64-token
-    # blocks, tables as wide as max_position_embeddings / block_size
-    tokens, rows, blocks, bs, width = 512, 8, 130, 64, 64
-    q = _sds(one_chip, (tokens, H, D), jnp.bfloat16)
-    pool = _sds(one_chip, (blocks, bs, KV, D), pool_dtype)
+# the ragged step's shapes: (q heads, kv heads, packed tokens, rows, pool
+# blocks, table width), 64-token blocks, head_dim 128
+RAGGED_SHAPES = {
+    # chip_smoke's serve phase: Llama-2-7B, 8 rows, tables as wide as
+    # max_position_embeddings / block_size
+    "llama2-7b": (H, KV, 512, 8, 130, 64),
+    # chipbench's serve-chat-steady: Mistral-7B-v0.3, 64 rows, the 8.6 GB
+    # pool, 32768 / 64 = 512 table columns in scalar memory
+    "mistral-7b-cell": (32, 8, 512, 64, 4096, 512),
+}
+
+
+def _ragged_args(shapes, pool_dtype, sharding):
+    heads, kv_heads, tokens, rows, blocks, width = RAGGED_SHAPES[shapes]
+    bs = 64
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding(spec))
+
+    q = sds((tokens, heads, D), jnp.bfloat16, P(None, "mp", None))
+    pool = sds((blocks, bs, kv_heads, D), pool_dtype,
+               P(None, None, "mp", None))
     assert rpa.supported(q.shape, pool.shape)
     scales = {}
     if pool_dtype == jnp.int8:
-        s = _sds(one_chip, (blocks, bs, KV), jnp.float32)
+        s = sds((blocks, bs, kv_heads), jnp.float32, P(None, None, "mp"))
         scales = dict(k_scale=s, v_scale=s)
-    return _compiled(("ragged", pool_dtype), lambda: jax.jit(
-        rpa.ragged_paged_attention).lower(
-            q, pool, pool, _sds(one_chip, (rows, width), jnp.int32),
-            _sds(one_chip, (rows,), jnp.int32),
-            _sds(one_chip, (rows + 1,), jnp.int32), **scales,
-        ).compile().as_text())
+    return (q, pool, pool, sds((rows, width), jnp.int32),
+            sds((rows,), jnp.int32), sds((rows + 1,), jnp.int32)), scales
+
+
+def _ragged_text(one_chip, pool_dtype, shapes="llama2-7b"):
+    args, scales = _ragged_args(shapes, pool_dtype, lambda spec: one_chip)
+    return _compiled(("ragged", shapes, pool_dtype), lambda: jax.jit(
+        rpa.ragged_paged_attention).lower(*args, **scales)
+        .compile().as_text())
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-def test_ragged_paged_attention(mosaic, one_chip, pool_dtype):
-    assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype)
+@pytest.mark.parametrize("shapes", sorted(RAGGED_SHAPES))
+def test_ragged_paged_attention(mosaic, one_chip, shapes, pool_dtype):
+    # the kv loop's dynamic trip count, the pool left in HBM and the block
+    # DMAs out of it, the table in scalar memory: interpret mode takes all
+    # of them, Mosaic has to (ISSUE 27)
+    assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype, shapes)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):
+    # heads and the pool's kv heads over mp, rows replicated over dp: the
+    # kernel sees 16 heads over 4 kv heads and DMAs from its pool shard
+    from paddle_tpu.ops.kernels.pallas import tp_attention as tpa
+    mesh = Mesh(np.array(topo.devices).reshape(2, 1, 1, 1, 2),
+                ("dp", "pp", "sharding", "sep", "mp"))
+    args, scales = _ragged_args("mistral-7b-cell", pool_dtype,
+                                lambda spec: NamedSharding(mesh, spec))
+
+    def attend(q, kp, vp, tbl, lens, cu, **scales):
+        out = tpa.sharded_ragged_paged_attention(
+            q, kp, vp, tbl, lens, cu, mesh, "mp", **scales)
+        assert out is not None
+        return out
+
+    text = jax.jit(attend).lower(*args, **scales).compile().as_text()
+    assert _custom_call_names(text) == {"ragged_paged_attention"}
 
 
 def _fused_adamw_text(one_chip):

@@ -621,6 +621,36 @@ class TestStepPhases:
             assert sp.attrs["launches"] == n > 0
             assert {"tokens", "decode_rows", "prefill_rows"} <= set(sp.attrs)
 
+    def test_step_span_counts_live_kv_blocks_beside_the_table(self, model):
+        # ISSUE 27: three row slots, block_size 4, q tiles of 8 tokens, a
+        # budget of 25 tokens a step (one decode token + one 24-token chunk).
+        # A tile walks the blocks up to its last token, so by hand:
+        #   step 1  A prefills 6 tokens: one tile ending at 6 -> 2 blocks
+        #   step 2  A decodes at context 7 -> 2; B's chunk of 24 is three
+        #           tiles ending at 8, 16, 24 -> 2 + 4 + 6; slot 3 idle
+        #   step 3  A decodes at 8 -> 2; B's last 16 tokens, context 40,
+        #           two tiles ending at 32, 40 -> 8 + 10; slot 3 idle
+        from paddle_tpu.observability import tracing
+        tracing.clear()
+        rng = np.random.RandomState(6)
+        eng = ContinuousBatchingEngine(model, max_batch=3, num_blocks=64,
+                                       block_size=4, temperature=0.0,
+                                       token_budget=25, prefill_chunk=24)
+        eng.add_request(rng.randint(0, 128, 6).tolist(), max_new_tokens=8)
+        eng.step()
+        eng.add_request(rng.randint(0, 128, 40).tolist(), max_new_tokens=8)
+        eng.step()
+        eng.step()
+        steps = [s for s in tracing.finished_spans("serving.step")
+                 if s.name == "serving.step"]
+        assert [(s.attrs["decode_rows"], s.attrs["prefill_rows"])
+                for s in steps] == [(0, 1), (1, 1), (1, 1)]
+        assert [s.attrs["kv_tile_blocks"] for s in steps] == [
+            2, 2 + 2 + 4 + 6, 2 + 8 + 10]
+        # 3 rows + ceil(25 / 8) tiles, each against every table column
+        table = (3 + 4) * eng.cache.block_tables.shape[1]
+        assert [s.attrs["kv_table_blocks"] for s in steps] == [table] * 3
+
     def test_idle_step_records_admit_only(self, model):
         from paddle_tpu.observability import tracing
         tracing.clear()

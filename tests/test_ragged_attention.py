@@ -221,6 +221,70 @@ class TestRaggedKernel:
                                    atol=2e-5, rtol=2e-5)
 
 
+# (q_lens, context_lens) at block_size 16: what the in-kernel loop over a
+# tile's live blocks must get right at its edges
+_LOOP_CASES = {
+    # contexts of exactly k x BS and k x BS + 1, decode rows and chunks
+    "block_multiples": ([1, 1, 16, 17, 1], [32, 33, 16, 17, 16]),
+    # one token of context: the row's own first token, one block, one lane
+    "one_token_of_context": ([1, 1, 1], [1, 40, 1]),
+    # empty rows between live ones, and a row with context but no query
+    # (a prefill row the budget gave nothing this step)
+    "empty_rows_between": ([1, 0, 0, 9, 0, 1], [20, 0, 7, 9, 0, 33]),
+    # speculative verify rows: q_len = K + 1 = 3 inside one tile
+    "verify_row": ([3, 1, 3, 3], [35, 16, 3, 48]),
+}
+
+
+class TestLiveBlockLoop:
+    """ISSUE 27: the kv axis is a loop over each tile's live blocks; the
+    table's width and its dead columns are never read."""
+
+    @pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("case", sorted(_LOOP_CASES))
+    def test_matches_reference_whatever_the_tables_width(self, case, pool):
+        qlens, ctxs = _LOOP_CASES[case]
+        rng = np.random.RandomState(sorted(_LOOP_CASES).index(case))
+        dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+        q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, 48, dtype=dtype)
+        ref = _reference(q, kp, vp, tbl, ctx, cu, bs=16)
+        scales = {}
+        if pool == "int8":
+            kp, vp, ks, vs = _quantize_pools(kp, vp)
+            scales = dict(k_scale=ks, v_scale=vs)
+        got = rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, cu, **scales)
+        assert got.dtype == q.dtype
+        tol = 2e-5 if pool == "f32" else 5e-2
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[:cu[-1]], ref[:cu[-1]],
+            atol=tol, rtol=tol)
+        # a table 32 times wider, its dead columns out-of-range garbage
+        R, mb = tbl.shape
+        live = np.arange(mb)[None, :] < -(-np.asarray(ctx)[:, None] // 16)
+        junk = rng.randint(-2 ** 30, 2 ** 30, (R, 32 * mb)).astype(np.int32)
+        wide = junk.copy()
+        wide[:, :mb] = np.where(live, np.asarray(tbl), junk[:, :mb])
+        got_wide = rpa.ragged_paged_attention(
+            q, kp, vp, jnp.asarray(wide), ctx, cu, **scales)
+        np.testing.assert_array_equal(np.asarray(got_wide, np.float32),
+                                      np.asarray(got, np.float32))
+
+    @pytest.mark.parametrize("case", sorted(_LOOP_CASES))
+    def test_host_count_is_the_kernels_trip_count(self, case):
+        # the engine's span attribute (numpy) against the scalar-prefetched
+        # per-tile trip counts the kernel loops over
+        qlens, ctxs = _LOOP_CASES[case]
+        cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
+        nt = rpa.num_tiles(len(qlens), 48)
+        for bs in (4, 16, 64):
+            *_, qcount, _, nblk = rpa._tile_metadata(
+                jnp.asarray(cu), jnp.asarray(ctxs, jnp.int32), nt, bs, 512)
+            assert int((np.asarray(qcount) > 0).sum()) == sum(
+                -(-n // rpa.TQ) for n in qlens)
+            assert rpa.live_tile_blocks(qlens, ctxs, bs) == int(
+                np.asarray(nblk).sum())
+
+
 @pytest.mark.skipif(jax.device_count() < 8,
                     reason="needs the forced 8-device CPU mesh")
 class TestShardedRagged:
