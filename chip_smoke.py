@@ -407,21 +407,11 @@ def _requests(sz: Sizes, seed: int, vocab: int):
     return reqs
 
 
-def _ragged_step_text(eng, cfg) -> str:
-    """The engine has no single compiled step: its attention runs as the
-    `ragged_paged_attention` op through the per-op executable cache. This
-    compiles that op's dispatcher at the engine's own shapes, so a
-    dispatcher that gives way to the composite shows in the text."""
-    from paddle_tpu.ops.dispatcher import KERNELS
-    d = cfg.hidden_size // cfg.num_attention_heads
-    q = jax.ShapeDtypeStruct((eng.token_budget, cfg.num_attention_heads, d),
-                             eng.cache.k[0]._data.dtype)
-    rows = eng.max_batch
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    return jax.jit(KERNELS["ragged_paged_attention"]).lower(
-        q, eng.cache.k[0]._data, eng.cache.v[0]._data,
-        i32(*eng.cache.block_tables.shape), i32(rows), i32(rows + 1),
-    ).compile().as_text()
+def _ragged_step_text(eng) -> str:
+    """The engine's step program, compiled for the shapes it ran with: a
+    ragged attention dispatcher that gave way to the composite shows in
+    the text as a program without its Mosaic call."""
+    return eng._program.compiled().as_text()
 
 
 def serve_phase(sz: Sizes, seed: int) -> Dict:
@@ -460,8 +450,8 @@ def serve_phase(sz: Sizes, seed: int) -> Dict:
                "token id outside the vocabulary")
     _check(hit_blocks >= 1, "the prefix cache reported no hit")
     _check(first == second, "a second greedy run gave other tokens")
-    kernels = _mosaic_calls(_ragged_step_text(eng, cfg),
-                            "the ragged attention op")
+    kernels = _mosaic_calls(_ragged_step_text(eng),
+                            "the engine's step program")
     return {
         "phase": "serve", "reduced": _reduced(sz, sz.serve_layers),
         "requests": len(reqs), "max_batch": sz.max_batch,

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -15,17 +16,24 @@ from ..core.tensor import Tensor
 from ..nn.layer_base import Layer
 
 
+# Held while tensors are swapped: a model shared by threads (replicas of one
+# fleet) is traced by one of them at a time, and a thread that gathers the
+# same tensors' buffers as arguments takes it too, so it never reads tracers
+_SWAP_LOCK = threading.RLock()
+
+
 @contextlib.contextmanager
 def _swap_state(tensors: List[Tensor], arrays: List[jax.Array]):
     """Temporarily rebind tensor buffers (to tracers during tracing)."""
-    saved = [t._data for t in tensors]
-    for t, a in zip(tensors, arrays):
-        t._data = a
-    try:
-        yield
-    finally:
-        for t, s in zip(tensors, saved):
-            t._data = s
+    with _SWAP_LOCK:
+        saved = [t._data for t in tensors]
+        for t, a in zip(tensors, arrays):
+            t._data = a
+        try:
+            yield
+        finally:
+            for t, s in zip(tensors, saved):
+                t._data = s
 
 
 @contextlib.contextmanager

@@ -74,7 +74,13 @@ class PagedKVCache:
     Pool: [num_blocks, block_size, KV_heads, head_dim] per layer. The host
     allocator hands free blocks to sequences as they grow; `release` returns
     them — the serving memory model of the reference's block_multi_head
-    path."""
+    path.
+
+    The pool arrays belong to the cache: ``k[l]._data`` and ``v[l]._data``
+    (and the int8 scales) are whatever the last write returned. A writer
+    that donates them (the ragged engine's step program) hands the old
+    arrays to XLA and rebinds the new ones through `set_pools`, so nobody
+    keeps a pool array across a write: read it from the cache each time."""
 
     def __init__(self, num_layers: int, batch: int, num_blocks: int,
                  block_size: int, num_kv_heads: int, head_dim: int,
@@ -112,6 +118,8 @@ class PagedKVCache:
                 for _ in range(num_layers)]
         else:
             self.k_scale = self.v_scale = None
+        self.pool_names = (("k", "v", "k_scale", "v_scale") if self.quantized
+                           else ("k", "v"))
         self._free = list(range(num_blocks - 1, -1, -1))
         self.block_tables = np.zeros((batch, max_blocks_per_seq), np.int32)
         self.context_lens = np.zeros((batch,), np.int32)
@@ -128,11 +136,46 @@ class PagedKVCache:
     def set_decode_override(self, slots: Optional[Tensor]):
         self._decode_override = slots
 
+    # -- the pools as one flat group of arrays --------------------------------
+    def pool_lists(self) -> List[List[Tensor]]:
+        """The per-layer lists that hold the pools: K, V and, for an int8
+        pool, their scales."""
+        return [getattr(self, name) for name in self.pool_names]
+
+    def pools(self) -> Tuple:
+        """Every pool array, list by list and layer by layer: what a
+        program that owns the pools takes and gives back."""
+        return tuple(t._data for ts in self.pool_lists() for t in ts)
+
+    def set_pools(self, arrays) -> None:
+        """Rebinds every pool to ``arrays`` (the order of `pools`)."""
+        for t, a in zip((t for ts in self.pool_lists() for t in ts), arrays):
+            t._data = a
+
+    @classmethod
+    def over(cls, pool_names: Tuple[str, ...], arrays) -> "PagedKVCache":
+        """A cache that is nothing but pools: ``arrays`` (tracers, while a
+        program that owns the pools is traced) in the order of `pools`
+        for a cache whose `pool_names` these are. It can `write`, hand
+        out `scale_kwargs` and give its `pools` back; it has no allocator
+        and belongs to no engine."""
+        c = object.__new__(cls)
+        c.pool_names = tuple(pool_names)
+        c.quantized = "k_scale" in c.pool_names
+        c.num_layers = n = len(arrays) // len(c.pool_names)
+        for j, name in enumerate(c.pool_names):
+            setattr(c, name, [Tensor(a) for a in arrays[j * n:(j + 1) * n]])
+        return c
+
     def write(self, layer: int, k_new: Tensor, v_new: Tensor,
               slots: Tensor):
         """THE pool write: every append path (prefill bulk, decode
         override, ragged step, slot view) funnels here so the int8
-        quantize-on-append and the plain write stay one implementation."""
+        quantize-on-append and the plain write stay one implementation.
+        Pure: the pools it returns replace the ones it read. Called per
+        op it copies a whole pool to write a few slots; the ragged step
+        calls it inside the one program that owns the pools, where the
+        same scatter is in place."""
         if self.quantized:
             self.k[layer], self.k_scale[layer] = call_op(
                 "paged_cache_write_q", self.k[layer], self.k_scale[layer],

@@ -316,6 +316,11 @@ class LlamaForCausalLM(Layer, GenerationMixin):
 
     def forward(self, input_ids, attn_mask=None, position_ids=None,
                 cache=None, start_pos=None):
+        program = getattr(cache, "program", None)
+        if program is not None:
+            # a ragged serving step (models/serving.py): this forward, traced
+            # once over the view, is the engine's one XLA program
+            return program(self, input_ids, start_pos, cache)
         hidden = self.llama(input_ids, attn_mask, position_ids,
                             cache=cache, start_pos=start_pos)
         if self.lm_head is None:  # tied: logits = h @ E^T

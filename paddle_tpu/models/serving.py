@@ -46,6 +46,7 @@ from __future__ import annotations
 import hashlib
 import threading
 import time
+import weakref
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -55,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor
+from ..jit import exec_store as _exec_store
+from ..jit.api import _SWAP_LOCK, _collect_state, _swap_state
 from ..observability import metrics as _metrics_mod
 from ..observability import perf as _perf_mod
 from ..observability import tracing as _tracing
@@ -84,9 +87,12 @@ class QueueFull(RuntimeError):
 _M = _metrics_mod.registry()
 _M_STEPS = _M.counter(
     "serving.steps", "ragged scheduler steps executed")
-# ops/dispatcher.py's count of eager dispatches: its difference across a
-# step's model call is the number of programs the step launched
+# ops/dispatcher.py's count of dispatches, in which the step program counts
+# each of its launches (`_StepProgram.launches` reads it for the step)
 _M_LAUNCHES = _M.counter("dispatch.count")
+_M_TRACES = _M.counter(
+    "serving.step.traces",
+    "times a ragged step program was traced (1 per engine when healthy)")
 _M_STEP_TOKENS = _M.counter(
     "serving.step_tokens", "packed tokens processed (prefill + decode)")
 _M_GEN_TOKENS = _M.counter(
@@ -303,15 +309,23 @@ class _RaggedView:
     """Cache facade for ONE ragged step: per-token write slots were
     precomputed by the scheduler (bulk block allocation, COW-guarded),
     and attention is the single ragged_paged_attention invocation over
-    the pool — decode rows and prefill chunks in the same call."""
+    the pool — decode rows and prefill chunks in the same call.
+
+    The view the engine hands to the model names the engine's
+    `_StepProgram`: the model's forward runs as that one XLA program,
+    which owns the pools for the call. ``update`` and ``attend`` run only
+    inside the program's trace, on a view over tracers (``program`` None);
+    a ragged step has no per-op path."""
 
     def __init__(self, cache: PagedKVCache, slots: Tensor, tables: Tensor,
-                 lens: Tensor, cu: Tensor):
+                 lens: Tensor, cu: Tensor,
+                 program: Optional["_StepProgram"] = None):
         self._c = cache
         self._slots = slots
         self._tables = tables
         self._lens = lens
         self._cu = cu
+        self.program = program
 
     def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
         return self._c.write(layer, k_new, v_new, self._slots)
@@ -325,6 +339,114 @@ class _RaggedView:
         return out.reshape([b, s, h, d])
 
 
+# model -> {(flags.version, pool names): (jitted serving_step, its state)}:
+# engines over one model (the replicas of a fleet, a relaunch, a warm-up
+# engine) share the traced program; jax.jit keeps one executable for each
+# geometry it is called with. Keyed on flags.version like the dispatcher's
+# cache, so a store attached later wraps anew
+_STEP_PROGRAMS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _step_program(model, pool_names: Tuple[str, ...]):
+    """The ragged step's model call as ONE XLA program:
+
+        (parameters and buffers, the pools, ids, pos, slots, tables, lens,
+         cu)  ->  (logits [1, B, V], the same pools)
+
+    Built by tracing the model's own forward (its ops run inline on
+    tracers through the dispatcher) over a `_RaggedView` of tracers, with
+    every pool array donated and returned: the pool writes scatter in
+    place, and one launch replaces the forward's per-op launches. Returns
+    (the jitted function, the tensors whose buffers are its first
+    argument)."""
+    from .. import flags
+    from ..autograd.engine import no_grad
+    programs = _STEP_PROGRAMS.setdefault(model, {})
+    key = (flags.version, pool_names)
+    if key in programs:
+        return programs[key]
+    params, buffers = _collect_state(model)
+    state = params + buffers
+    model_ref = weakref.ref(model)
+
+    def serving_step(state_arrays, pools, ids, pos, slots, tables, lens, cu):
+        _M_TRACES.inc()
+        before = _M_LAUNCHES.value
+        over = PagedKVCache.over(pool_names, pools)
+        view = _RaggedView(over, Tensor(slots), Tensor(tables),
+                           Tensor(lens), Tensor(cu))
+        with _swap_state(state, list(state_arrays)), no_grad():
+            logits = model_ref()(Tensor(ids), cache=view,
+                                 start_pos=Tensor(pos))
+        _StepProgram.traced_ops += _M_LAUNCHES.value - before
+        return logits._data, over.pools()
+
+    # with an exec store attached (a relaunching replica) the compiled
+    # program is loaded from disk; the trace still runs once
+    jit = _exec_store.persistent(
+        jax.jit(serving_step, donate_argnums=(1,)), "serving",
+        label="serving_step")
+    programs[key] = (jit, state)
+    return programs[key]
+
+
+class _StepProgram:
+    """One engine's use of its model's step program (`_step_program`):
+    the program owns the engine's pools across a call. The arrays it was
+    given are gone when it returns (where the backend donates), and the
+    cache is rebound to the ones it gave back before anyone else can read
+    them. Shapes are the engine's static ones, so one trace and one
+    executable serve every step."""
+
+    # dispatches counted while a program was traced: they launched nothing
+    traced_ops = 0
+
+    def __init__(self, cache: PagedKVCache):
+        self._cache = cache
+        self._model = None
+        self._avals = None
+
+    @classmethod
+    def launches(cls) -> int:
+        """``dispatch.count`` less the dispatches that ran on tracers while
+        a program was traced: a clock of launches to take differences of."""
+        return _M_LAUNCHES.value - cls.traced_ops
+
+    def _args(self, state: List[Tensor], ids: Tensor, pos: Tensor,
+              view: _RaggedView):
+        with _SWAP_LOCK:    # another replica's thread may be tracing the model
+            state = tuple(t._data for t in state)
+        return (state, self._cache.pools(),
+                ids._data, pos._data, view._slots._data, view._tables._data,
+                view._lens._data, view._cu._data)
+
+    def __call__(self, model, ids: Tensor, pos: Tensor,
+                 view: _RaggedView) -> Tensor:
+        jit, state = _step_program(model, self._cache.pool_names)
+        args = self._args(state, ids, pos, view)
+        if self._avals is None:
+            self._model = weakref.ref(model)
+            self._avals = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        _M_LAUNCHES.inc()
+        logits, pools = jit(*args)
+        self._cache.set_pools(pools)
+        return Tensor(logits)
+
+    def lower(self, model, args):
+        """The program lowered for ``args`` (arrays or their shapes, as
+        `_args` orders them); nothing runs and nothing is donated.
+        ``lower(...).compile()`` has its text, cost and memory analysis."""
+        return _step_program(model, self._cache.pool_names)[0].lower(*args)
+
+    def compiled(self):
+        """The program compiled for the shapes of this engine's first
+        call; None before it."""
+        if self._avals is None:
+            return None
+        return self.lower(self._model(), self._avals).compile()
+
+
 class ContinuousBatchingEngine:
     """Ragged continuous batching: chunked prefill + decode in one
     compiled step over the paged pool, with prefix-cache block sharing.
@@ -333,7 +455,13 @@ class ContinuousBatchingEngine:
     shapes -> one executable); it must cover at least one token per row
     (``max_batch``). ``prefill_chunk`` is the fixed chunk size long
     prompts are sliced into, so a long admission never stalls decode
-    for more than one chunk's worth of compute."""
+    for more than one chunk's worth of compute.
+
+    The pools are ``self.cache``'s. During a step's model call they
+    belong to the engine's `_StepProgram`, which donates them and rebinds
+    the cache to what it returns; between steps every reader
+    (copy-on-write, preemption, the warm-cache snapshot) takes
+    ``cache.k[l]._data`` anew and keeps no pool array across a step."""
 
     def __init__(self, model, max_batch: int,
                  num_blocks: Optional[int] = None,
@@ -379,6 +507,7 @@ class ContinuousBatchingEngine:
             max_blocks_per_seq=mb, dtype=getattr(cfg, "dtype", "float32"),
             kv_dtype=kv_dtype)
         _M_KV_BPT.set(self.cache.kv_bytes_per_token())
+        self._program = _StepProgram(self.cache)
         # speculative decoding: K draft tokens per decode row, verified
         # as one q_len=K+1 ragged row out of the leftover token budget.
         # Acceptance is EXACT-MATCH against the row's keyed sample at
@@ -557,15 +686,13 @@ class ContinuousBatchingEngine:
         # reused for every COW), not an eager full-pool .at[].set
         bs = self.cache.block_size
         slots = Tensor(jnp.asarray(fresh * bs + np.arange(bs), jnp.int32))
-        pools = [self.cache.k, self.cache.v]
-        if self.cache.quantized:
-            # int8 pool: the per-token-slot scale rows move with their
-            # block (paged_cache_write is shape-generic over the
-            # trailing dims, so the [NB,BS,KV] scale pools ride the
-            # same one-block scatter executable)
-            pools += [self.cache.k_scale, self.cache.v_scale]
-        for layer in range(self.cache.num_layers):
-            for pool in pools:
+        # int8 pool: the per-token-slot scale rows move with their block
+        # (paged_cache_write is shape-generic over the trailing dims, so
+        # the [NB,BS,KV] scale pools ride the same one-block scatter
+        # executable). Each pool is read here, after the last step
+        # rebound it, and replaced by the write's result
+        for pool in self.cache.pool_lists():
+            for layer in range(self.cache.num_layers):
                 rows = Tensor(pool[layer]._data[blk][None])  # [1,BS,...]
                 pool[layer] = call_op("paged_cache_write", pool[layer],
                                       rows, slots)
@@ -889,26 +1016,27 @@ class ContinuousBatchingEngine:
         with _tracing.start_span("serving.step.dispatch",
                                  trace=_tracing.UNTRACED,
                                  attrs={"step": n_step}) as sp_dispatch:
-            launches = _M_LAUNCHES.value
-            # synthetic ledger row for the whole ragged step: it has no
-            # single jax.jit of its own (the model dispatches through the
-            # per-op exec cache, whose entries carry the FLOPs/HBM), but the
-            # step IS the serving unit of device work — and its host sync
-            # below makes the device-time measurement free
+            launches = self._program.launches()
+            # ledger row of the ragged step: the model call is one jax.jit
+            # (`_StepProgram`), whose cost analysis gives the row its FLOPs
+            # and HBM bytes; gather and sampling are two small ops beside
+            # it. The host sync below makes the device-time sample free
             _pe = _p_sample = None
             if _perf_mod.enabled():
                 _led = _perf_mod.ledger()
                 _pe = _led.register(
                     ("serving", self.max_batch, self.token_budget,
                      self.spec_k, self.cache.kv_dtype),
-                    "serving", name="serving_step")
+                    "serving", name="serving_step",
+                    lower=self._program.compiled)
                 _p_sample = _led.tick(_pe)
             view = _RaggedView(
                 self.cache,
                 Tensor(jnp.asarray(slot_vec, jnp.int32)),
                 Tensor(jnp.asarray(self.cache.block_tables, jnp.int32)),
                 Tensor(jnp.asarray(lens, jnp.int32)),
-                Tensor(jnp.asarray(cu, jnp.int32)))
+                Tensor(jnp.asarray(cu, jnp.int32)),
+                program=self._program)
             with no_grad():
                 logits = self.model(
                     Tensor(jnp.asarray(ids[None])), cache=view,
@@ -919,7 +1047,7 @@ class ContinuousBatchingEngine:
                               Tensor(jnp.asarray(keys)),
                               Tensor(jnp.asarray(stream_pos, jnp.int32)),
                               **self.sampling)
-            launches = _M_LAUNCHES.value - launches
+            launches = self._program.launches() - launches
             self.steps += 1
             _M_STEPS.inc()
             _M_STEP_TOKENS.inc(t)

@@ -179,6 +179,11 @@ class ThreadReplicaHandle(ReplicaHandle):
             if not stepped:
                 self._wake.wait(timeout=self._idle_wait_s)
                 self._wake.clear()
+            else:
+                # give the GIL away between steps: a submitter waiting for
+                # the lock otherwise loses it to this loop again and again
+                # (a step that is one launch holds it nearly all the time)
+                time.sleep(0)
 
     def start(self) -> None:
         self._stop.clear()

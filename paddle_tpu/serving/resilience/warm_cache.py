@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from ...core.tensor import Tensor
+from ...jit.api import _SWAP_LOCK
 from ...observability import flight_recorder as _flight
 from ...observability import metrics as _metrics
 from ...ops.dispatcher import call_op
@@ -83,8 +84,12 @@ def _model_fingerprint(model) -> str:
         picks = sorted({0, len(params) - 1,
                         *range(0, len(params),
                                max(1, len(params) // 8))})
-        for idx in picks:
-            flat = params[idx]._data.reshape(-1)
+        # replicas in threads share their model: while one of them traces
+        # its step program the parameters hold tracers
+        with _SWAP_LOCK:
+            arrays = [params[idx]._data for idx in picks]
+        for a in arrays:
+            flat = a.reshape(-1)
             stride = max(1, int(flat.shape[0]) // 64)
             probe = np.asarray(jax.device_get(flat[::stride][:64]))
             h.update(probe.tobytes())

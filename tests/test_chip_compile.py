@@ -191,6 +191,70 @@ def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):
     assert _custom_call_names(text) == {"ragged_paged_attention"}
 
 
+def _serving_step_text(one_chip):
+    """The engine's step program at chipbench's `mistral-7b-v0.3-serve`:
+    Mistral-7B-v0.3 widths, 8 layers, 64 rows, 512 packed tokens and 16
+    bf16 pools of 4096 blocks, lowered from shapes. The parameters stay the
+    zeros `LazyGuard` puts in host memory (4 GB), never initialized."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.api import _collect_state
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.generation import PagedKVCache
+    from paddle_tpu.models.serving import _StepProgram
+
+    def build():
+        layers, rows, tokens, blocks, width = 8, 64, 512, 4096, 512
+        cfg = LlamaConfig(
+            vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+            num_hidden_layers=layers, num_attention_heads=32,
+            num_key_value_heads=8, max_position_embeddings=32768,
+            rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16")
+        with paddle.LazyGuard():
+            model = LlamaForCausalLM(cfg)
+        model.eval()
+        for _, sub, _ in model._walk(""):
+            sub.__dict__.pop("_has_lazy", None)
+            for p in sub._parameters.values():
+                if p is not None and hasattr(p, "_lazy_spec"):
+                    del p._lazy_spec
+        # the cache gives the program its geometry; the pools it is
+        # lowered for are the cell's, as shapes
+        cache = PagedKVCache(layers, rows, num_blocks=2, block_size=64,
+                             num_kv_heads=8, head_dim=D,
+                             max_blocks_per_seq=width, dtype="bfloat16")
+        params, buffers = _collect_state(model)
+        state = tuple(_sds(one_chip, t._data.shape, t._data.dtype)
+                      for t in params + buffers)
+        pools = tuple(_sds(one_chip, (blocks,) + a.shape[1:], a.dtype)
+                      for a in cache.pools())
+
+        def i32(*shape):
+            return _sds(one_chip, shape, jnp.int32)
+
+        args = (state, pools, i32(1, tokens), i32(1, tokens), i32(tokens),
+                i32(rows, width), i32(rows), i32(rows + 1))
+        text = _StepProgram(cache).lower(model, args).compile().as_text()
+        return text, len(state), len(pools)
+
+    return _compiled("serving_step", build)
+
+
+def test_serving_step_owns_its_pools(mosaic, one_chip):
+    # ISSUE 30: one program for the ragged step's model call. Every pool is
+    # an argument aliased to an output, so the 512 slots are written in
+    # place; the per-op path copied a 537 MB pool for each of 16 writes
+    text, n_state, n_pools = _serving_step_text(one_chip)
+    assert n_pools == 16
+    head = text[:text.index("\n")]
+    alias = head[head.index("input_output_alias={"):
+                 head.index("entry_computation_layout")]
+    aliased = {int(m) for m in re.findall(r"\((\d+), \{\}", alias)}
+    assert aliased == set(range(n_state, n_state + n_pools)), alias
+    assert not re.search(r"= bf16\[4096,64,8,128\]\S* copy\(", text)
+    assert _custom_call_names(text) == {"ragged_paged_attention"}
+    assert text.count(CUSTOM_CALL) >= 8          # one call a layer
+
+
 def _fused_adamw_text(one_chip):
     # one decoder layer's matrices and a norm in one bucket: 78.6 M
     # elements, a row count that is not a multiple of the 512-row block

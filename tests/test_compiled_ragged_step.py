@@ -1,0 +1,234 @@
+"""The ragged step's model call as one XLA program that owns its pools
+(ISSUE 30).
+
+`ContinuousBatchingEngine.step` hands the model a `_RaggedView` that names
+the engine's `_StepProgram`; the forward runs as that one donated program,
+traced once, whatever stands at ``eng.model``. These cases hold the program
+to the tokens the per-op path gave (the uncached ``generate`` for a float32
+pool, schedule independence and exact speculation for every pool), to its
+launch count, to one trace an engine, and to the pools' hand-over.
+"""
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.serving import ContinuousBatchingEngine
+from paddle_tpu.observability import metrics as obs_metrics
+from paddle_tpu.observability import tracing
+
+VOCAB = 128
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(0)
+    cfg = LlamaConfig(vocab_size=VOCAB, hidden_size=64,
+                      intermediate_size=160, num_hidden_layers=2,
+                      num_attention_heads=4, num_key_value_heads=2,
+                      max_position_embeddings=128)
+    m = LlamaForCausalLM(cfg)
+    m.eval()
+    return m
+
+
+def _metric(name):
+    m = obs_metrics.registry().get(name)
+    return 0 if m is None else (m.value or 0)
+
+
+def _generate(model, prompt, n_new):
+    """Greedy tokens of the plain decode loop over the contiguous cache:
+    no pool, no packing, no compiled step."""
+    ids = Tensor(jnp.asarray(np.asarray(prompt, np.int32)[None]))
+    out = model.generate(ids, max_new_tokens=n_new, temperature=0.0)
+    return list(np.asarray(out._data)[0, len(prompt):])
+
+
+def _prompts(seed, lengths):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, VOCAB, n).tolist() for n in lengths]
+
+
+def _step_launches():
+    """The ``launches`` attribute of every `serving.step` span on record."""
+    return [s.attrs["launches"] for s in tracing.finished_spans("serving.step")
+            if s.name == "serving.step"]
+
+
+def _serve(model, prompts, n_new, **engine):
+    eng = ContinuousBatchingEngine(model, num_blocks=64, block_size=16,
+                                   temperature=0.0, **engine)
+    rids = [eng.add_request(p, max_new_tokens=n_new) for p in prompts]
+    res = eng.run()
+    return eng, [res[r] for r in rids]
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["spec0", "spec2"])
+@pytest.mark.parametrize("kv_dtype", ["auto", "bf16", "int8"],
+                         ids=["float32", "bf16-pool", "int8-pool"])
+def test_tokens_and_launches(model, kv_dtype, spec_k):
+    # 21- and 37-token prompts in chunks of 8 beside decode rows: every
+    # step is the same program, and launches it, the logits' reshape,
+    # gather and sampling
+    prompts = _prompts(31, (5, 21, 9, 37))
+    tracing.clear()
+    eng, got = _serve(model, prompts, 7, max_batch=3, token_budget=16,
+                      prefill_chunk=8, kv_dtype=kv_dtype,
+                      speculative_k=spec_k)
+    launches = _step_launches()
+    assert len(launches) == eng.steps > 8
+    assert set(launches) == {launches[0]} and launches[0] <= 4, launches
+    # another schedule, speculation off: the same tokens, whatever the pool
+    # rounds to (per-token quantization and exact-match verification)
+    _, other = _serve(model, prompts, 7, max_batch=2, token_budget=24,
+                      prefill_chunk=16, kv_dtype=kv_dtype, speculative_k=0)
+    assert got == other
+    if kv_dtype == "auto":
+        assert got == [_generate(model, p, 7) for p in prompts]
+
+
+def test_one_trace_through_prefill_decode_preemption_and_cow(model):
+    want_a = _generate(model, [3, 4, 5], 24)
+    want_b = _generate(model, [9, 8, 7], 24)
+    want_c = _generate(model, [7, 8, 9], 6)
+    traces0, cow0 = _metric("serving.step.traces"), _metric("serving.cow_copies")
+    # a pool of 3 usable blocks for two requests of 2 blocks each: the
+    # starved head preempts the running row, which later resumes by
+    # prefilling prompt + what it had generated in chunks of 16, beside
+    # the other row's decode tokens
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=4,
+                                   block_size=16, temperature=0.0,
+                                   preempt_after=4)
+    a = eng.add_request([3, 4, 5], max_new_tokens=24)
+    b = eng.add_request([9, 8, 7], max_new_tokens=24)
+    res = eng.run()
+    assert eng.preempt_count >= 1
+    assert res[a] == want_a and res[b] == want_b
+    # then a row whose partial block another holder has cached: its next
+    # write copies the block first
+    c = eng.add_request([7, 8, 9], max_new_tokens=6)
+    eng.step()
+    req = eng.results[c]
+    blk = int(eng.cache.block_tables[req.slot, req.ctx // 16])
+    eng._pc.register(b"held-elsewhere", blk)
+    eng._pc.acquire(blk)
+    assert eng.run()[c] == want_c
+    assert _metric("serving.cow_copies") > cow0
+    assert _metric("serving.step.traces") - traces0 == 1
+
+
+class _Tap:
+    """Stands where the engine holds its model, as chipbench's check does,
+    and keeps what each call returned."""
+
+    def __init__(self, model):
+        self._model = model
+        self.config = model.config
+        self.logits = []
+
+    def __call__(self, *args, **kwargs):
+        out = self._model(*args, **kwargs)
+        self.logits.append(out._data)
+        return out
+
+
+def test_a_tap_at_eng_model_sees_concrete_logits_of_the_same_program(model):
+    prompts = _prompts(32, (19, 6))
+    tracing.clear()
+    plain, want = _serve(model, prompts, 5, max_batch=2, token_budget=12,
+                         prefill_chunk=8)
+    plain_launches = _step_launches()
+    tracing.clear()
+    traces0 = _metric("serving.step.traces")
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=12, prefill_chunk=8)
+    tap = _Tap(model)
+    eng.model = tap
+    rids = [eng.add_request(p, max_new_tokens=5) for p in prompts]
+    res = eng.run()
+    assert [res[r] for r in rids] == want
+    assert len(tap.logits) == eng.steps == plain.steps
+    for logits in tap.logits:
+        assert isinstance(logits, jax.Array)
+        assert not isinstance(logits, jax.core.Tracer)
+        assert logits.shape == (1, 12, VOCAB)
+        assert np.isfinite(np.asarray(logits, np.float32)).all()
+    assert _step_launches() == plain_launches
+    # engines over one model share its program: under the tap the second
+    # engine ran the very executable the first had traced
+    assert _metric("serving.step.traces") == traces0
+
+
+@pytest.mark.parametrize("kv_dtype", ["auto", "int8"],
+                         ids=["float32", "int8-pool"])
+def test_pools_are_handed_over_and_a_block_still_copies(model, kv_dtype):
+    eng = ContinuousBatchingEngine(model, max_batch=1, num_blocks=16,
+                                   block_size=16, temperature=0.0,
+                                   kv_dtype=kv_dtype)
+    rid = eng.add_request([7, 8, 9, 10, 11], max_new_tokens=6)
+    n_pools = (4 if kv_dtype == "int8" else 2) * eng.cache.num_layers
+    held = eng.cache.pools()
+    assert len(held) == n_pools
+    eng.step()
+    # the step program took the arrays it was given and the cache holds
+    # the ones it gave back
+    if held[0].is_deleted():         # this backend donates
+        assert all(a.is_deleted() for a in held)
+    now = eng.cache.pools()
+    assert not any(a.is_deleted() for a in now)
+    assert all(a is not b for a, b in zip(held, now))
+    # copy-on-write reads the pools of after the step
+    req = eng.results[rid]
+    blk = int(eng.cache.block_tables[req.slot, 0])
+    before = [np.asarray(a[blk]) for a in now]
+    assert any(np.abs(b.astype(np.float32)).sum() > 0 for b in before)
+    eng._pc.register(b"held-elsewhere", blk)
+    eng._pc.acquire(blk)
+    eng._ensure_writable(req.slot, 0)
+    fresh = int(eng.cache.block_tables[req.slot, 0])
+    assert fresh != blk
+    for a, want in zip(eng.cache.pools(), before):
+        np.testing.assert_array_equal(np.asarray(a[fresh]), want)
+        np.testing.assert_array_equal(np.asarray(a[blk]), want)
+    # and the next step donates what the copy left in the cache
+    held = eng.cache.pools()
+    eng.step()
+    assert not any(a.is_deleted() for a in eng.cache.pools())
+    if held[0].is_deleted():
+        assert all(a.is_deleted() for a in held)
+    # the copy changed where the row's tokens live, not what they are
+    _, (want,) = _serve(model, [[7, 8, 9, 10, 11]], 6, max_batch=1,
+                        kv_dtype=kv_dtype)
+    assert eng.run()[rid] == want
+
+
+def test_two_threads_trace_one_shared_model(model):
+    # replicas of a fleet share their model: while one thread traces its
+    # program the parameters hold tracers, which the other must never read
+    prompts = _prompts(33, (11, 4))
+    want = [_generate(model, p, 6) for p in prompts]
+    got, errors = {}, []
+    gate = threading.Barrier(2)
+
+    def replica(k):
+        try:
+            gate.wait(timeout=60)
+            got[k] = _serve(model, prompts, 6, max_batch=2,
+                            token_budget=8 + 4 * k, prefill_chunk=4)[1]
+        except Exception as e:          # a leaked tracer raises here
+            errors.append(e)
+
+    threads = [threading.Thread(target=replica, args=(k,)) for k in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    assert not errors, errors
+    assert got == {0: want, 1: want}

@@ -618,7 +618,11 @@ class TestStepPhases:
                           and p.name in ("serving.step.dispatch",
                                          "serving.step.sync")]
             assert sp.t0_ns == disp.t0_ns and sp.t1_ns == sync.t1_ns
-            assert sp.attrs["launches"] == n > 0
+            # ISSUE 30: the step program, the logits' reshape, gather and
+            # sampling. The first step may also trace the program, and the
+            # ops that ran on tracers count as dispatches, not launches
+            assert sp.attrs["launches"] == 4
+            assert n == 4 if k > 1 else n >= 4
             assert {"tokens", "decode_rows", "prefill_rows"} <= set(sp.attrs)
 
     def test_step_span_counts_live_kv_blocks_beside_the_table(self, model):

@@ -47,6 +47,8 @@ def _fresh_process_sim():
     dsp._get_exec.cache_clear()
     for schema in dsp.OPS.values():
         schema.__dict__.pop("_fast_ex", None)
+    from paddle_tpu.models import serving
+    serving._STEP_PROGRAMS.clear()
     jax.clear_caches()
 
 
@@ -370,7 +372,9 @@ class TestServingWarmStart:
         # gather, threefry...) that any fresh process pays in ~ms each
         assert es.store().hits > 0 and es.store().misses == 0, (
             es.store().state())
-        assert cold_compiles - warm_compiles >= 15
+        # since ISSUE 30 a step is four executables: the step program
+        # (the whole forward), the logits' reshape, gather and sampling
+        assert cold_compiles - warm_compiles >= 4
         assert cold_s > warm_s * 2, (
             f"warm relaunch not compile-bound-free: cold {cold_s:.3f}s "
             f"vs warm {warm_s:.3f}s")

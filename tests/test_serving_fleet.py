@@ -565,12 +565,14 @@ class TestFleetRouter:
         router, _ = _mk_fleet(model, tmp_path, max_queue=1,
                               eng=dict(max_batch=1, num_blocks=32))
         try:
+            # 200 tokens a request: no row frees within a 20 ms deadline,
+            # however fast a step of the tiny model is on this host
             prompts = _prompts(8, rng_seed=5)
             admitted, shed = [], []
             for p in prompts:
                 try:
                     admitted.append(router.submit(
-                        p, max_new_tokens=24, deadline_s=0.02))
+                        p, max_new_tokens=200, deadline_s=0.02))
                 except FleetShed as e:
                     assert e.retry_after_s is not None
                     assert e.retry_after_s > 0.0
@@ -580,7 +582,7 @@ class TestFleetRouter:
             router.drain_all(timeout_s=120.0)
             for p in shed:                 # the retry path
                 admitted.append(router.submit(
-                    p, max_new_tokens=24, deadline_s=30.0))
+                    p, max_new_tokens=200, deadline_s=30.0))
             router.drain_all(timeout_s=120.0)
             assert router.sheds == len(shed)
             assert router.dropped_requests == 0
