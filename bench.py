@@ -545,49 +545,16 @@ def bench_moe(on_tpu: bool):
 
 
 # --------------------------------------------------------------------------
-# kernel micro-benches: paged attention + grouped GEMM, Pallas vs composite
+# kernel micro-benches: ring-attention block + grouped GEMM, Pallas vs composite
 # --------------------------------------------------------------------------
 
 def bench_micro(on_tpu: bool):
     import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.ops.kernels.serving import paged_attention_kernel
     from paddle_tpu.ops.kernels.pallas.grouped_gemm import grouped_matmul
     from benchmarks.device_time import device_time_us
 
     out = []
     rng = np.random.RandomState(0)
-
-    # paged attention: serving decode shapes
-    if on_tpu:
-        B, H, KV, D, NB, BS, MB = 64, 32, 8, 128, 1024, 64, 32
-    else:
-        B, H, KV, D, NB, BS, MB = 4, 8, 4, 64, 16, 16, 4
-    q = jnp.asarray(rng.randn(B, 1, H, D), jnp.bfloat16)
-    kp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.bfloat16)
-    vp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.bfloat16)
-    tbl = jnp.asarray(rng.randint(0, NB, (B, MB)), jnp.int32)
-    lens = jnp.asarray(rng.randint(BS, MB * BS, B), jnp.int32)
-
-    def paged_fn(use_pallas):
-        def f(*a):
-            paddle.set_flags({"FLAGS_use_pallas_kernels": use_pallas})
-            return paged_attention_kernel(*a)
-        return jax.jit(f)
-
-    t_pal = device_time_us(paged_fn(True), (q, kp, vp, tbl, lens))
-    t_xla = device_time_us(paged_fn(False), (q, kp, vp, tbl, lens))
-    paddle.set_flags({"FLAGS_use_pallas_kernels": True})
-    out.append({
-        "metric": "paged_attention_us",
-        "value": round(t_pal, 1),
-        "unit": "us/call",
-        "vs_baseline": round(t_xla / t_pal, 4),
-        "detail": {"shape": f"B{B} H{H} KV{KV} D{D} blocks{NB}x{BS}",
-                   "xla_composite_us": round(t_xla, 1),
-                   "baseline": "XLA gather+SDPA composite "
-                               "(device-clock ratio)"},
-    })
 
     # ring-attention block: flash_block vs the XLA composite block at SEP
     # shard shapes — fwd+bwd, measuring the (s/P)^2 HBM round-trip the
@@ -794,355 +761,6 @@ def bench_tp_attention(on_tpu: bool):
                         + ("" if on_tpu else
                            " (CPU smoke: Pallas runs interpreted — "
                            "code-path check, not a perf claim)"),
-        },
-    }
-
-
-# --------------------------------------------------------------------------
-# serving: paged-KV decode throughput, Pallas vs composite attention
-# --------------------------------------------------------------------------
-
-def bench_serving(on_tpu: bool):
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.models.generation import PagedKVCache
-    from paddle_tpu.ops.dispatcher import call_op
-
-    if on_tpu:
-        cfg = LlamaConfig(
-            vocab_size=32000, hidden_size=3072, intermediate_size=8448,
-            num_hidden_layers=6, num_attention_heads=24,
-            num_key_value_heads=12, max_position_embeddings=2048,
-            dtype="bfloat16")
-        batch, prompt, steps = 32, 1024, 10
-        paddle.set_default_dtype("bfloat16")
-    else:
-        cfg = LlamaConfig.tiny()
-        batch, prompt, steps = 2, 16, 2
-
-    try:
-        paddle.seed(0)
-        model = LlamaForCausalLM(cfg)
-    finally:
-        if on_tpu:
-            paddle.set_default_dtype("float32")
-
-    hd = cfg.hidden_size // cfg.num_attention_heads
-    total = prompt + steps * 4 + 8
-    bs = 64 if on_tpu else 4
-    mb = -(-total // bs)
-    ids = Tensor(jnp.asarray(
-        ((jnp.arange(batch * prompt, dtype=jnp.uint32) * 1103515245
-          + 12345) % cfg.vocab_size).astype(jnp.int32)
-        .reshape(batch, prompt)))
-
-    def decode_rate(use_pallas: bool):
-        from paddle_tpu.autograd.engine import no_grad
-        paddle.set_flags({"FLAGS_use_pallas_kernels": use_pallas})
-        cache = PagedKVCache(
-            cfg.num_hidden_layers, batch, num_blocks=batch * mb,
-            block_size=bs, num_kv_heads=cfg.num_key_value_heads,
-            head_dim=hd, max_blocks_per_seq=mb,
-            dtype=getattr(cfg, "dtype", "float32"))
-        state = {"pos": prompt,
-                 "tok": Tensor(jnp.asarray(
-                     np.full((batch, 1), 7, np.int32)))}
-        with no_grad():
-            model(ids, cache=cache,
-                  start_pos=Tensor(jnp.asarray(0, jnp.int32)))
-
-            def step():
-                pos = Tensor(jnp.asarray(state["pos"], jnp.int32))
-                logits = model(state["tok"], cache=cache, start_pos=pos)
-                nxt = call_op("sample_logits", logits[:, -1, :],
-                              temperature=1.0, top_k=0, top_p=1.0)
-                state["tok"] = nxt.reshape([batch, 1])
-                state["pos"] += 1
-                return logits._data
-
-            sec = _time_steps(step, steps)
-        return batch / sec
-
-    prev_flag = paddle.get_flags(["FLAGS_use_pallas_kernels"])[
-        "FLAGS_use_pallas_kernels"]
-    try:
-        pallas_rate = decode_rate(True)
-        composite_rate = decode_rate(False)
-    finally:
-        paddle.set_flags({"FLAGS_use_pallas_kernels": prev_flag})
-    return {
-        "metric": "llama_paged_decode_tok_per_sec",
-        "value": round(pallas_rate, 1),
-        "unit": "tokens/sec",
-        "vs_baseline": round(pallas_rate / composite_rate, 4),
-        "detail": {"batch": batch, "prompt": prompt,
-                   "hidden": cfg.hidden_size,
-                   "layers": cfg.num_hidden_layers,
-                   "composite_tok_per_sec": round(composite_rate, 1),
-                   "baseline": "same paged-KV decode loop with the XLA "
-                               "gather+SDPA attention (device-clock "
-                               "ratio; reference serving flow: "
-                               "block_multi_head_attention)"},
-    }
-
-
-# --------------------------------------------------------------------------
-# continuous batching: insert/evict scheduling vs gang-scheduled batches
-# --------------------------------------------------------------------------
-
-def bench_cbatch(on_tpu: bool):
-    """Tokens/s under mixed output lengths: the (now-baseline)
-    gang-scheduled continuous engine refills slots as sequences finish;
-    the static baseline gang-schedules batches that run until their
-    LONGEST member finishes (VERDICT r4 Next#10). The ragged engine's
-    win over THIS engine is measured by serving_ragged. Cost model uses
-    the device clock for the shared compiled decode step and the two
-    prefill widths; scheduling quality (step counts) comes from actually
-    running the engine."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.models.serving import GangScheduledEngine
-    from paddle_tpu.ops.dispatcher import call_op
-
-    if on_tpu:
-        cfg = LlamaConfig(
-            vocab_size=32000, hidden_size=2048, intermediate_size=5632,
-            num_hidden_layers=4, num_attention_heads=16,
-            num_key_value_heads=8, max_position_embeddings=1024,
-            dtype="bfloat16")
-        max_batch, prompt, n_req = 8, 128, 12
-        lens = list(np.random.RandomState(0).randint(8, 49, n_req))
-        paddle.set_default_dtype("bfloat16")
-    else:
-        cfg = LlamaConfig.tiny()
-        max_batch, prompt, n_req = 2, 8, 4
-        lens = [2, 6, 3, 5]
-
-    try:
-        paddle.seed(0)
-        model = LlamaForCausalLM(cfg)
-        model.eval()
-    finally:
-        if on_tpu:
-            paddle.set_default_dtype("float32")
-
-    rng = np.random.RandomState(1)
-    prompts = [rng.randint(0, cfg.vocab_size, prompt).tolist()
-               for _ in range(n_req)]
-
-    bs = 64 if on_tpu else 4
-    eng = GangScheduledEngine(
-        model, max_batch=max_batch,
-        num_blocks=max_batch * (-(-(prompt + int(max(lens)) + bs) // bs))
-        + n_req, block_size=bs, temperature=0.0)
-    for p, n in zip(prompts, lens):
-        eng.add_request(p, max_new_tokens=int(n))
-    eng.run()
-    cont_steps = eng.steps
-
-    # gang-scheduled static baseline: arrival-order batches of max_batch,
-    # each runs its longest member's step count
-    batches = [lens[i:i + max_batch]
-               for i in range(0, len(lens), max_batch)]
-    static_steps = sum(int(max(b)) - 1 for b in batches)
-    cont_prefills, static_prefills = n_req, len(batches)
-
-    # device-clock costs of the shared compiled programs
-    def decode_step():
-        ids = Tensor(jnp.asarray(
-            np.zeros((max_batch, 1), np.int32)))
-        from paddle_tpu.models.generation import PagedKVCache
-        cache = PagedKVCache(
-            cfg.num_hidden_layers, max_batch,
-            num_blocks=max_batch * 4, block_size=bs,
-            num_kv_heads=cfg.num_key_value_heads,
-            head_dim=cfg.hidden_size // cfg.num_attention_heads,
-            max_blocks_per_seq=4, dtype=getattr(cfg, "dtype", "float32"))
-        from paddle_tpu.autograd.engine import no_grad
-        with no_grad():
-            model(Tensor(jnp.asarray(
-                np.ones((max_batch, prompt), np.int32))), cache=cache,
-                start_pos=Tensor(jnp.asarray(0, jnp.int32)))
-
-            def one():
-                # uniform scalar pos: same compiled step cost as the
-                # engine's vector-pos step (identical program shape)
-                logits = model(ids, cache=cache,
-                               start_pos=Tensor(jnp.asarray(
-                                   prompt, np.int32)))
-                return logits._data
-
-            t_step = _time_steps(one, 8 if on_tpu else 2)
-
-            def pre1():
-                from paddle_tpu.models.serving import _SlotView
-                view = _SlotView(cache, 0)
-                return model(Tensor(jnp.asarray(
-                    np.ones((1, prompt), np.int32))), cache=view,
-                    start_pos=Tensor(jnp.asarray(0, jnp.int32)))._data
-
-            t_p1 = _time_steps(pre1, 4 if on_tpu else 1)
-
-            def preb():
-                return model(Tensor(jnp.asarray(
-                    np.ones((max_batch, prompt), np.int32))), cache=cache,
-                    start_pos=Tensor(jnp.asarray(0, jnp.int32)))._data
-
-            t_pb = _time_steps(preb, 4 if on_tpu else 1)
-        return t_step, t_p1, t_pb
-
-    t_step, t_p1, t_pb = decode_step()
-    tokens = float(sum(lens))
-    cont_time = cont_steps * t_step + cont_prefills * t_p1
-    static_time = static_steps * t_step + static_prefills * t_pb
-    return {
-        "metric": "serving_continuous_batching_tok_per_sec",
-        "value": round(tokens / cont_time, 1),
-        "unit": "tokens/sec",
-        "vs_baseline": round((tokens / cont_time)
-                             / (tokens / static_time), 4),
-        "detail": {
-            "requests": n_req, "max_batch": max_batch, "prompt": prompt,
-            "out_lens": [int(x) for x in lens],
-            "continuous_decode_steps": cont_steps,
-            "static_decode_steps": static_steps,
-            "decode_step_ms": round(t_step * 1e3, 3),
-            "prefill1_ms": round(t_p1 * 1e3, 3),
-            "prefill_batch_ms": round(t_pb * 1e3, 3),
-            "baseline": "gang-scheduled batches of max_batch (each runs "
-                        "its longest member); same compiled decode step, "
-                        "device-clock costs",
-        },
-    }
-
-
-# --------------------------------------------------------------------------
-# ragged serving: one-kernel chunked prefill + decode vs the gang engine
-# --------------------------------------------------------------------------
-
-def bench_serving_ragged(on_tpu: bool, quick: bool = False):
-    """ISSUE 8 acceptance micro: tokens/s at mixed prompt/output lengths,
-    ragged engine (chunked prefill + decode in ONE compiled step over the
-    paged pool, prefix-cache sharing) vs the preserved gang-scheduled
-    engine (batch-1 prefill + gang decode) on IDENTICAL request streams.
-    Both engines run end to end twice — the first full run absorbs every
-    compile, the second is timed wall-clock — so the ratio measures the
-    execution model, not XLA. TTFT/TPOT p50/p99 come from the ragged
-    engine's per-request records of the timed run (arrival = enqueue
-    before the run starts, so TTFT includes queue wait under load)."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.models.serving import (ContinuousBatchingEngine,
-                                           GangScheduledEngine)
-    from paddle_tpu.observability import metrics as obs_metrics
-
-    if on_tpu:
-        cfg = LlamaConfig(
-            vocab_size=32000, hidden_size=2048, intermediate_size=5632,
-            num_hidden_layers=4, num_attention_heads=16,
-            num_key_value_heads=8, max_position_embeddings=2048,
-            dtype="bfloat16")
-        max_batch, n_req, bs = 8, 24, 64
-        budget, chunk = 512, 256
-        head_len, plens, olens = 256, (128, 384, 768), (16, 48, 96)
-        paddle.set_default_dtype("bfloat16")
-    else:
-        # request-heavy chat-turn mix: the regime where the gang engine's
-        # per-admission batch-1 prefill stall dominates. `quick` halves
-        # the stream for the tier-1 smoke (same shapes, same code paths)
-        cfg = LlamaConfig.tiny()
-        max_batch, n_req, bs = 4, (10 if quick else 32), 16
-        budget, chunk = 48, 32
-        head_len, plens, olens = 16, (4, 12, 24, 36), (2, 3, 5, 8)
-
-    try:
-        paddle.seed(0)
-        model = LlamaForCausalLM(cfg)
-        model.eval()
-    finally:
-        if on_tpu:
-            paddle.set_default_dtype("float32")
-
-    # mixed stream: a shared system-prompt head on half the requests
-    # (prefix-cache food), prompt/output lengths cycling the mix
-    rng = np.random.RandomState(3)
-    head = rng.randint(0, cfg.vocab_size, head_len).tolist()
-    reqs = []
-    for i in range(n_req):
-        body = rng.randint(0, cfg.vocab_size,
-                           int(plens[i % len(plens)])).tolist()
-        prompt = (head + body) if i % 2 else body
-        reqs.append((prompt, int(olens[i % len(olens)])))
-    max_total = max(len(p) + n for p, n in reqs)
-    nb = max_batch * (-(-(max_total + bs) // bs)) + 2
-
-    def run_ragged():
-        eng = ContinuousBatchingEngine(
-            model, max_batch=max_batch, num_blocks=nb, block_size=bs,
-            temperature=0.0, token_budget=budget, prefill_chunk=chunk)
-        for p, n in reqs:
-            eng.add_request(p, max_new_tokens=n)
-        eng.run()
-        return eng
-
-    def run_gang():
-        eng = GangScheduledEngine(
-            model, max_batch=max_batch, num_blocks=nb, block_size=bs,
-            temperature=0.0)
-        for p, n in reqs:
-            eng.add_request(p, max_new_tokens=n)
-        eng.run()
-        return eng
-
-    run_ragged()          # warmup: compiles the ragged step
-    run_gang()            # warmup: compiles every prefill width + decode
-    pc_hits0 = obs_metrics.registry().get(
-        "serving.prefix_cache.hit_blocks").value
-    t0 = time.perf_counter()
-    eng_r = run_ragged()
-    t_ragged = time.perf_counter() - t0
-    pc_hits = obs_metrics.registry().get(
-        "serving.prefix_cache.hit_blocks").value - pc_hits0
-    t0 = time.perf_counter()
-    eng_g = run_gang()
-    t_gang = time.perf_counter() - t0
-
-    tokens = float(sum(n for _, n in reqs))
-    done = [eng_r.results[r] for r in eng_r.results]
-    ttft = np.asarray(sorted((r.t_first - r.t_arrive) * 1e3 for r in done))
-    tpot = np.asarray(sorted(
-        (r.t_done - r.t_first) / (len(r.out_tokens) - 1) * 1e3
-        for r in done if len(r.out_tokens) > 1))
-    return {
-        "metric": "serving_ragged_tok_per_sec",
-        "value": round(tokens / t_ragged, 1),
-        "unit": "tokens/sec",
-        "vs_baseline": round((tokens / t_ragged) / (tokens / t_gang), 4),
-        "detail": {
-            "requests": n_req, "max_batch": max_batch,
-            "token_budget": budget, "prefill_chunk": chunk,
-            "block_size": bs, "num_blocks": nb,
-            "prompt_lens": sorted({len(p) for p, _ in reqs}),
-            "out_lens": sorted({n for _, n in reqs}),
-            "ragged_steps": eng_r.steps,
-            "gang_steps": eng_g.steps,
-            "gang_prefills": eng_g.prefills,
-            "prefix_cache_hit_blocks": int(pc_hits),
-            "ttft_p50_ms": round(float(np.percentile(ttft, 50)), 2),
-            "ttft_p99_ms": round(float(np.percentile(ttft, 99)), 2),
-            "tpot_p50_ms": round(float(np.percentile(tpot, 50)), 2),
-            "tpot_p99_ms": round(float(np.percentile(tpot, 99)), 2),
-            "gang_tok_per_sec": round(tokens / t_gang, 1),
-            "baseline": "GangScheduledEngine (batch-1 prefill + "
-                        "gang-scheduled decode), same request stream, "
-                        "wall clock after a full warmup run"
-                        + ("" if on_tpu else
-                           " (CPU proxy: Pallas runs interpreted)"),
         },
     }
 
@@ -3456,8 +3074,8 @@ def main():
         sys.exit(bench_compare(sys.argv[i + 1], cand))
     which = os.environ.get(
         "PTPU_BENCH_CONFIGS",
-        "llama,llamapeak,llama4k,llamalong,resnet,bert,ocr,moe,serving,"
-        "cbatch,serving_ragged,serving_regimes,serving_recovery,"
+        "llama,llamapeak,llama4k,llamalong,resnet,bert,ocr,moe,"
+        "serving_regimes,serving_recovery,"
         "serving_fleet,aot,tp_attention,micro,"
         "dispatch,observability,step_capture,multi_step,"
         "checkpoint_overlap,anomaly_overhead,fused_optimizer")
@@ -3548,8 +3166,6 @@ def main():
         })
     for name, fn in (("resnet", bench_resnet), ("bert", bench_bert),
                      ("ocr", bench_ocr), ("moe", bench_moe),
-                     ("serving", bench_serving), ("cbatch", bench_cbatch),
-                     ("serving_ragged", bench_serving_ragged),
                      ("serving_regimes", bench_serving_regimes),
                      ("serving_recovery", bench_serving_recovery),
                      ("serving_fleet", bench_serving_fleet),

@@ -1,5 +1,5 @@
-"""Autoregressive generation: KV caches (contiguous + paged) and the decode
-loop.
+"""Autoregressive generation: the dense KV cache and its decode loop, and
+the paged block pool the serving engine writes into.
 
 Reference: the serving path around
 phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu (paged KV) and
@@ -7,13 +7,14 @@ PaddleNLP's GenerationMixin API (generate with greedy/top-k/top-p).
 
 TPU shape: fixed-capacity cache buffers so every decode step hits ONE cached
 executable (position/length are tensor inputs, never static attrs); the
-paged cache adds a host-side block allocator over a device block pool —
-sequences share the pool, blocks are recycled on release.
+paged cache is a host-side block allocator over a device block pool —
+sequences share the pool, blocks are recycled on release. Its one consumer
+is `models/serving.py: ContinuousBatchingEngine`.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -122,19 +123,9 @@ class PagedKVCache:
                            else ("k", "v"))
         self._free = list(range(num_blocks - 1, -1, -1))
         self.block_tables = np.zeros((batch, max_blocks_per_seq), np.int32)
-        self.context_lens = np.zeros((batch,), np.int32)
         # blocks handed to each sequence so far — allocation is per TOKEN,
         # not per layer-write (all layers share one block table)
         self._allocated = np.zeros((batch,), np.int32)
-        self._slot_cache_key = None   # memoized update() slot map key
-        self._prefill_kv: dict = {}   # per-layer prompt K/V, prefill only
-        # continuous-batching hook (models/serving.py): when set, s==1
-        # updates write to these precomputed per-row slots and skip the
-        # allocator/length bookkeeping (the engine owns both)
-        self._decode_override: Optional[Tensor] = None
-
-    def set_decode_override(self, slots: Optional[Tensor]):
-        self._decode_override = slots
 
     # -- the pools as one flat group of arrays --------------------------------
     def pool_lists(self) -> List[List[Tensor]]:
@@ -169,9 +160,8 @@ class PagedKVCache:
 
     def write(self, layer: int, k_new: Tensor, v_new: Tensor,
               slots: Tensor):
-        """THE pool write: every append path (prefill bulk, decode
-        override, ragged step, slot view) funnels here so the int8
-        quantize-on-append and the plain write stay one implementation.
+        """THE pool write: the int8 quantize-on-append and the plain
+        write are one implementation.
         Pure: the pools it returns replace the ones it read. Called per
         op it copies a whole pool to write a few slots; the ragged step
         calls it inside the one program that owns the pools, where the
@@ -191,8 +181,8 @@ class PagedKVCache:
         return self.k[layer], self.v[layer]
 
     def scale_kwargs(self, layer: int) -> dict:
-        """Dequant-scale kwargs for the paged/ragged attention ops
-        (empty for an unquantized pool)."""
+        """Dequant-scale kwargs for the ragged attention op (empty for
+        an unquantized pool)."""
         if not self.quantized:
             return {}
         return dict(k_scale=self.k_scale[layer],
@@ -211,28 +201,14 @@ class PagedKVCache:
         return per * self.num_layers
 
     # -- host-side allocator -------------------------------------------------
-    def _ensure_block(self, seq: int, pos: int) -> int:
-        blk_idx = pos // self.block_size
-        if blk_idx >= self.block_tables.shape[1]:
-            raise RuntimeError(
-                f"PagedKVCache: position {pos} needs block {blk_idx} but "
-                f"max_blocks_per_seq={self.block_tables.shape[1]}")
-        while self._allocated[seq] <= blk_idx:
-            if not self._free:
-                raise RuntimeError("PagedKVCache: block pool exhausted")
-            self.block_tables[seq, self._allocated[seq]] = self._free.pop()
-            self._allocated[seq] += 1
-        return self.block_tables[seq, blk_idx]
-
     def alloc_slots(self, seq: int, pos0: int, n: int,
                     alloc_block=None) -> np.ndarray:
         """Vectorized write slots for ``n`` tokens at ``pos0..pos0+n-1``:
-        block allocation runs once per NEW BLOCK (not per token, the old
-        `_ensure_block`-per-token loop), and the flat slot ids come out
-        of one vectorized expression. ``alloc_block`` overrides the
-        free-list pop — the serving engine routes allocation through its
-        prefix-cache-aware allocator (evictable cached blocks count as
-        free there)."""
+        block allocation runs once per NEW BLOCK, not per token, and the
+        flat slot ids come out of one vectorized expression.
+        ``alloc_block`` overrides the free-list pop — the serving engine
+        routes allocation through its prefix-cache-aware allocator
+        (evictable cached blocks count as free there)."""
         if n <= 0:
             return np.empty((0,), np.int64)
         blk_hi = (pos0 + n - 1) // self.block_size
@@ -259,87 +235,7 @@ class PagedKVCache:
         used = int(self._allocated[seq])
         self._free.extend(int(b) for b in self.block_tables[seq, :used])
         self.block_tables[seq, :] = 0
-        self.context_lens[seq] = 0
         self._allocated[seq] = 0
-        # the memoized slot map points into blocks just freed — a
-        # re-prefill at the same (pos, len) must re-run the allocator
-        self._slot_cache_key = None
-
-    def write_token(self, layer: int, seq_positions: np.ndarray,
-                    k_new: Tensor, v_new: Tensor):
-        """Write one token per sequence at its current position."""
-        slots = []
-        for b, pos in enumerate(seq_positions):
-            blk = self._ensure_block(b, int(pos))
-            slots.append(blk * self.block_size + int(pos) % self.block_size)
-        slot_ids = Tensor(jnp.asarray(slots, jnp.int32))
-        self.write(layer, k_new, v_new, slot_ids)
-        # advance lengths at the FIRST layer's write: forward order is
-        # write(i) → attend(i) → write(i+1)..., so every layer (including
-        # layer 0) must already see the just-written token in its mask
-        if layer == 0:
-            for b, pos in enumerate(seq_positions):
-                self.context_lens[b] = max(self.context_lens[b],
-                                           int(pos) + 1)
-
-    # -- model-facing cache interface (same contract as KVCache, so
-    # LlamaAttention's decode path and generate() can run fully paged:
-    # reference block_multi_head serving flow) ------------------------------
-    def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
-        b, s = k_new.shape[0], k_new.shape[1]
-        if self._decode_override is not None and s == 1:
-            return self.write(layer, k_new, v_new, self._decode_override)
-        p0 = int(np.asarray(pos._data)) if isinstance(pos, Tensor) \
-            else int(pos)
-        if s == 1 and self._prefill_kv:
-            # decode has begun: the stashed prompt K/V (only needed for
-            # the prefill attend) would otherwise pin ~prompt-sized HBM
-            # for the whole decode
-            self._prefill_kv.clear()
-        if self._slot_cache_key != (p0, s):
-            slots = np.stack([self.alloc_slots(seq, p0, s)
-                              for seq in range(b)])
-            self._slots = Tensor(jnp.asarray(slots.reshape(-1), jnp.int32))
-            self._slot_cache_key = (p0, s)
-        self.write(layer, k_new, v_new, self._slots)
-        if layer == 0:
-            self.context_lens[:] = np.maximum(self.context_lens, p0 + s)
-        if s > 1:
-            # prefill: stash the prompt k/v so attend() can run ordinary
-            # causal attention instead of gathering the pool back out
-            self._prefill_kv[layer] = (k_new, v_new)
-        return self.k[layer], self.v[layer]
-
-    def attend(self, layer: int, q: Tensor, pos=None,
-               attn_mask: Optional[Tensor] = None) -> Tensor:
-        if pos is None and attn_mask is None:
-            # legacy 2-arg decode form
-            return call_op("paged_attention", q, self.k[layer],
-                           self.v[layer],
-                           Tensor(jnp.asarray(self.block_tables)),
-                           Tensor(jnp.asarray(self.context_lens)),
-                           **self.scale_kwargs(layer))
-        s = q.shape[1]
-        if s > 1:
-            p0 = int(np.asarray(pos._data)) if isinstance(pos, Tensor) \
-                else int(pos)
-            if p0 != 0 or layer not in getattr(self, "_prefill_kv", {}):
-                raise NotImplementedError(
-                    "PagedKVCache prefill attends only the freshly "
-                    "written prompt (pos 0); chunked prefill is not "
-                    "supported")
-            k_new, v_new = self._prefill_kv[layer]
-            return call_op("scaled_dot_product_attention", q, k_new,
-                           v_new, attn_mask=attn_mask, is_causal=True)
-        if attn_mask is not None:
-            raise NotImplementedError(
-                "PagedKVCache decode attention has no attn_mask input "
-                "(context_lens bound what each sequence attends to); "
-                "left-padded batches need the contiguous KVCache")
-        return call_op("paged_attention", q, self.k[layer], self.v[layer],
-                       Tensor(jnp.asarray(self.block_tables)),
-                       Tensor(jnp.asarray(self.context_lens)),
-                       **self.scale_kwargs(layer))
 
 
 class GenerationMixin:
@@ -349,12 +245,9 @@ class GenerationMixin:
     def generate(self, input_ids: Tensor, max_new_tokens: int = 32,
                  temperature: float = 1.0, top_k: int = 0,
                  top_p: float = 1.0, eos_token_id: Optional[int] = None,
-                 max_cache_len: Optional[int] = None,
-                 cache_type: str = "contiguous", block_size: int = 64):
-        """cache_type="paged" runs the whole loop over the block-pool
-        cache (bulk prefill write + Pallas paged decode attention — the
-        reference block_multi_head serving flow); "contiguous" is the
-        dense [B, T] cache."""
+                 max_cache_len: Optional[int] = None):
+        """Static-batch decode over the dense [B, T] cache. Paged serving
+        is `ContinuousBatchingEngine`."""
         from ..autograd.engine import no_grad
         cfg = self.config
         b, s = input_ids.shape[0], input_ids.shape[1]
@@ -370,28 +263,17 @@ class GenerationMixin:
                 f"(rope table would clamp positions)")
         from .. import flags as _flags
         kv_dtype = _flags.get_flag("kv_cache_dtype")
-        if cache_type == "paged":
-            mb = -(-(max_cache_len or total) // block_size)
-            cache = PagedKVCache(
-                cfg.num_hidden_layers, b, num_blocks=b * mb,
-                block_size=block_size,
-                num_kv_heads=cfg.num_key_value_heads,
-                head_dim=cfg.hidden_size // cfg.num_attention_heads,
-                max_blocks_per_seq=mb,
-                dtype=getattr(cfg, "dtype", "float32"),
-                kv_dtype=kv_dtype)
-        else:
-            if kv_dtype == "int8":
-                from ..ops.kernels.serving import record_fallback
-                record_fallback(
-                    "kv", "kv_int8_dense_cache",
-                    "contiguous KVCache has no quantized layout; "
-                    "cache stays at the compute dtype")
-            cache = KVCache(cfg.num_hidden_layers, b,
-                            max_cache_len or total,
-                            cfg.num_key_value_heads,
-                            cfg.hidden_size // cfg.num_attention_heads,
-                            dtype=getattr(cfg, "dtype", "float32"))
+        if kv_dtype == "int8":
+            from ..ops.kernels.serving import record_fallback
+            record_fallback(
+                "kv", "kv_int8_dense_cache",
+                "contiguous KVCache has no quantized layout; "
+                "cache stays at the compute dtype")
+        cache = KVCache(cfg.num_hidden_layers, b,
+                        max_cache_len or total,
+                        cfg.num_key_value_heads,
+                        cfg.hidden_size // cfg.num_attention_heads,
+                        dtype=getattr(cfg, "dtype", "float32"))
         tokens = [input_ids]
         finished = np.zeros((b,), bool)
         with no_grad():

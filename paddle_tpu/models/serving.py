@@ -10,14 +10,13 @@ chunked prefill and prefix caching:
   of tokens — one per decoding row plus fixed-size prefill chunks of the
   admitted prompts — into ONE model invocation over the shared pool
   (`ragged_paged_attention`): static shapes, so XLA compiles the step
-  once and every mix of prefill/decode replays it. Batch-1 prompt
-  prefill and the decode gang-stall around it are gone: long prompts
+  once and every mix of prefill/decode replays it. Long prompts
   prefill in chunks interleaved with everyone else's decode tokens.
 - **Token-budget admission.** Requests queue until a row slot AND enough
   pool blocks for their worst case (prompt + max_new_tokens, minus the
   prefix-cached head) are free — the vLLM reservation rule, so decode
   never exhausts the pool mid-flight. Head-of-line starvation preempts
-  the LIFO victim (recompute-on-resume) exactly as before.
+  the LIFO victim (recompute-on-resume).
 - **Prefix cache.** Full prompt blocks are content-hashed (chained, so a
   block's identity covers its whole prefix) and published after being
   written; a later request whose prompt shares the head acquires the
@@ -36,9 +35,8 @@ chunked prefill and prefix caching:
   request id, then the token index), so stochastic output is identical
   whatever the batching, chunking, or preemption schedule.
 
-`GangScheduledEngine` preserves the previous execution model (batch-1
-prefill + gang-scheduled decode) as the measured baseline and the
-equivalence reference for tests and `bench.py serving_ragged`.
+This is the only serving engine and the only consumer of `PagedKVCache`;
+the tests' independent oracle is the dense `generate()` loop.
 """
 
 from __future__ import annotations
@@ -65,8 +63,8 @@ from ..ops.dispatcher import call_op
 from ..ops.kernels.pallas import ragged_paged_attention as _rpa
 from .generation import PagedKVCache, kv_pool_blocks
 
-__all__ = ["Request", "ContinuousBatchingEngine", "GangScheduledEngine",
-           "PrefixCache", "QueueFull"]
+__all__ = ["Request", "ContinuousBatchingEngine", "PrefixCache",
+           "QueueFull"]
 
 
 class QueueFull(RuntimeError):
@@ -276,33 +274,6 @@ class PrefixCache:
         del self._map[self._hash_of.pop(block)]
         del self._ref[block]
         return block
-
-
-class _SlotView:
-    """Batch-1 cache facade targeting ONE slot of the shared pool: the
-    model's prefill pass (update + causal attend) runs unchanged, but
-    writes land in the slot's block table. (GangScheduledEngine only —
-    the ragged engine prefills through the packed step.)"""
-
-    def __init__(self, cache: PagedKVCache, slot: int):
-        self._c = cache
-        self._slot = slot
-        self._stash: Dict[int, tuple] = {}
-
-    def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
-        c, slot = self._c, self._slot
-        p0 = int(np.asarray(pos._data)) if isinstance(pos, Tensor) \
-            else int(pos)
-        sl = Tensor(jnp.asarray(
-            c.alloc_slots(slot, p0, k_new.shape[1]), jnp.int32))
-        c.write(layer, k_new, v_new, sl)
-        self._stash[layer] = (k_new, v_new)
-        return c.k[layer], c.v[layer]
-
-    def attend(self, layer: int, q: Tensor, pos=None, attn_mask=None):
-        k_new, v_new = self._stash[layer]
-        return call_op("scaled_dot_product_attention", q, k_new, v_new,
-                       attn_mask=attn_mask, is_causal=True)
 
 
 class _RaggedView:
@@ -756,7 +727,6 @@ class ContinuousBatchingEngine:
                 self.cache.block_tables[i, bi] = hits[bi]
             self.cache._allocated[i] = n_use
             req.ctx = n_use * self.block_size
-            self.cache.context_lens[i] = req.ctx
             _M_ADMITTED.inc()
             _inc_tenant("serving.admitted", req.tenant)
             if n_use:
@@ -780,9 +750,7 @@ class ContinuousBatchingEngine:
             if not self._pc.release_block(blk):
                 self.cache._free.append(blk)
         self.cache.block_tables[i, :] = 0
-        self.cache.context_lens[i] = 0
         self.cache._allocated[i] = 0
-        self.cache._slot_cache_key = None
         self.slots[i] = None
         self.tok[i] = 0
 
@@ -1107,10 +1075,9 @@ class ContinuousBatchingEngine:
                         _M_SPEC_REJ.inc(nd - a)
                         _M_SPEC_ROWS.inc()
                     # rejected-draft KV rows (positions ctx+1+a..ctx+n-1) are
-                    # garbage: context_lens hides them and the next step
+                    # garbage: the row's length hides them and the next step
                     # overwrites those slots in place
                     req.ctx += 1 + a
-                    self.cache.context_lens[i] = req.ctx
                     for j in range(a + 1):
                         self._append_token(req, i, int(sampled[base + j]),
                                            now, finished)
@@ -1118,7 +1085,6 @@ class ContinuousBatchingEngine:
                             break
                 else:
                     req.ctx += n
-                    self.cache.context_lens[i] = req.ctx
                     _M_PREFILL_TOKENS.inc(n)
                     self._register_blocks(req, i, req.ctx)
                     if req.ctx == req.target:
@@ -1172,210 +1138,3 @@ class ContinuousBatchingEngine:
         for rid, req in self.results.items():
             out.setdefault(rid, req.out_tokens)
         return out
-
-
-class GangScheduledEngine:
-    """The PREVIOUS execution model, preserved as baseline + reference:
-    admitted requests prefill alone at batch-1 against a single slot,
-    and every decode step gang-schedules the whole batch around those
-    stalls. `bench.py serving_ragged` measures the ragged engine against
-    this, and the equivalence tests use it as the sequential
-    batch-1-prefill + gang-decode reference."""
-
-    def __init__(self, model, max_batch: int, num_blocks: int,
-                 block_size: int = 64,
-                 max_blocks_per_seq: Optional[int] = None,
-                 eos_token_id: Optional[int] = None,
-                 temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, preempt_after: Optional[int] = None):
-        from .. import flags as _flags
-        cfg = model.config
-        self.model = model
-        self.eos = eos_token_id
-        self.sampling = dict(temperature=temperature, top_k=top_k,
-                             top_p=top_p)
-        mb = max_blocks_per_seq or (
-            -(-cfg.max_position_embeddings // block_size))
-        self.cache = PagedKVCache(
-            cfg.num_hidden_layers, max_batch, num_blocks=num_blocks,
-            block_size=block_size, num_kv_heads=cfg.num_key_value_heads,
-            head_dim=cfg.hidden_size // cfg.num_attention_heads,
-            max_blocks_per_seq=mb, dtype=getattr(cfg, "dtype", "float32"),
-            kv_dtype=str(_flags.get_flag("kv_cache_dtype")))
-        if int(_flags.get_flag("speculative_k")) > 0:
-            # the gang engine's decode path is strictly batch-wide
-            # single-token; speculation only exists in the ragged engine
-            from ..ops.kernels.serving import record_fallback
-            record_fallback("spec", "spec_gang_engine",
-                            "gang-scheduled engine ignores speculative_k")
-        self.block_size = block_size
-        self.max_batch = max_batch
-        # one reserved block absorbs the masked writes of inactive slots
-        self._trash_slot = self.cache._free.pop() * block_size
-        self.slots: List[Optional[Request]] = [None] * max_batch
-        self.pending: deque[Request] = deque()
-        self.results: Dict[int, Request] = {}
-        self.tok = np.zeros((max_batch, 1), np.int32)
-        self.pos = np.zeros((max_batch,), np.int32)
-        self._next_rid = 0
-        self._admit_seq = 0
-        self.steps = 0
-        self.prefills = 0
-        self.preempt_after = preempt_after
-        self._head_waited = 0
-        self.preempt_count = 0
-
-    # -- request intake ------------------------------------------------------
-    def add_request(self, prompt, max_new_tokens: int = 32) -> int:
-        rid = self._next_rid
-        self._next_rid += 1
-        req = Request(rid, np.asarray(prompt, np.int32).reshape(-1),
-                      max_new_tokens)
-        total_pool = (len(self.cache._free)
-                      + int(self.cache._allocated.sum()))
-        if self._blocks_needed(req) > total_pool:
-            raise ValueError(
-                f"request needs {self._blocks_needed(req)} blocks but the "
-                f"pool only has {total_pool}: it could never be admitted")
-        self.pending.append(req)
-        self.results[rid] = req
-        return rid
-
-    def _blocks_needed(self, req: Request) -> int:
-        return -(-(len(req.prompt) + req.max_new_tokens)
-                 // self.block_size)
-
-    def _outstanding_reservation(self) -> int:
-        return sum(self._blocks_needed(r)
-                   - int(self.cache._allocated[r.slot])
-                   for r in self.slots if r is not None)
-
-    def _admit(self):
-        from ..autograd.engine import no_grad
-        for i in range(self.max_batch):
-            if not self.pending:
-                return
-            if self.slots[i] is not None:
-                continue
-            req = self.pending[0]
-            if (self._blocks_needed(req)
-                    > len(self.cache._free)
-                    - self._outstanding_reservation()):
-                return                 # reservation: wait for reclaims
-            self.pending.popleft()
-            self._head_waited = 0
-            req.slot = i
-            req.admit_order = self._admit_seq
-            self._admit_seq += 1
-            self.slots[i] = req
-            view = _SlotView(self.cache, i)
-            # a preempted request resumes by re-prefilling prompt + what
-            # it already generated (recompute-on-resume)
-            full = (np.concatenate([req.prompt,
-                                    np.asarray(req.out_tokens[:-1],
-                                               np.int32)])
-                    if req.out_tokens else req.prompt)
-            ids = Tensor(jnp.asarray(full.reshape(1, -1)))
-            with no_grad():
-                logits = self.model(ids, cache=view,
-                                    start_pos=Tensor(
-                                        jnp.asarray(0, jnp.int32)))
-                self.prefills += 1
-                if req.out_tokens:
-                    # resumed: the next input token was already sampled
-                    self.tok[i, 0] = req.out_tokens[-1]
-                else:
-                    nxt = call_op("sample_logits", logits[:, -1, :],
-                                  **self.sampling)
-                    first = int(np.asarray(nxt._data).reshape(-1)[0])
-                    req.out_tokens.append(first)
-                    self.tok[i, 0] = first
-            self.cache.context_lens[i] = len(full)
-            self.pos[i] = len(full)
-            self._finish_if_done(req)
-
-    def _finish_if_done(self, req: Request) -> bool:
-        if (len(req.out_tokens) >= req.max_new_tokens
-                or (self.eos is not None and req.out_tokens
-                    and req.out_tokens[-1] == self.eos)):
-            req.done = True
-            self._release_slot(req.slot)
-            return True
-        return False
-
-    def _release_slot(self, i: int):
-        self.cache.release(i)
-        self.slots[i] = None
-        self.pos[i] = 0
-        self.tok[i, 0] = 0
-
-    # -- the gang-scheduled loop ---------------------------------------------
-    @property
-    def num_active(self) -> int:
-        return sum(1 for r in self.slots if r is not None)
-
-    def _preempt_lifo(self):
-        victim = max((r for r in self.slots if r is not None),
-                     key=lambda r: r.admit_order, default=None)
-        if victim is None:
-            return
-        self._release_slot(victim.slot)
-        victim.slot = None
-        victim.preemptions += 1
-        self.preempt_count += 1
-        self.pending.insert(1, victim)  # right behind the starved head
-
-    def step(self) -> List[Request]:
-        """Admit + one decode step for every active slot. Returns the
-        requests that finished during this step."""
-        from ..autograd.engine import no_grad
-
-        self._admit()
-        if self.pending and self.preempt_after is not None:
-            self._head_waited += 1
-            if self._head_waited > self.preempt_after:
-                self._preempt_lifo()
-                self._head_waited = 0
-                self._admit()
-        if self.num_active == 0:
-            return []
-        # per-row write slots: active rows append at pos; inactive rows
-        # overwrite the reserved trash block
-        slot_vec = np.full((self.max_batch,), self._trash_slot, np.int64)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            p = int(self.pos[i])
-            blk = self.cache._ensure_block(i, p)
-            slot_vec[i] = blk * self.block_size + p % self.block_size
-            self.cache.context_lens[i] = p + 1  # visible to the attend
-        self.cache.set_decode_override(
-            Tensor(jnp.asarray(slot_vec, jnp.int32)))
-        try:
-            with no_grad():
-                logits = self.model(
-                    Tensor(jnp.asarray(self.tok)), cache=self.cache,
-                    start_pos=Tensor(jnp.asarray(self.pos, jnp.int32)))
-                nxt = call_op("sample_logits", logits[:, -1, :],
-                              **self.sampling)
-        finally:
-            self.cache.set_decode_override(None)
-        self.steps += 1
-        sampled = np.asarray(nxt._data).reshape(-1)
-        finished = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(sampled[i])
-            req.out_tokens.append(tok)
-            self.pos[i] += 1
-            self.tok[i, 0] = tok
-            if self._finish_if_done(req):
-                finished.append(req)
-        return finished
-
-    def run(self) -> Dict[int, List[int]]:
-        """Drive until every request (queued + active) completes."""
-        while self.pending or self.num_active:
-            self.step()
-        return {rid: r.out_tokens for rid, r in self.results.items()}

@@ -100,7 +100,7 @@ METRIC_NAMES = frozenset({
     "serving.kv.bytes_per_token", "serving.kv.dequant_blocks",
     "serving.kv.fallback", "serving.spec.proposed",
     "serving.spec.accepted", "serving.spec.rejected",
-    "serving.spec.verify_rows", "serving.spec.fallback",
+    "serving.spec.verify_rows",
     # serving/resilience/ (request journal + replay, drain, warm-start)
     "serving.resilience.journal_records",
     "serving.resilience.journal_flushes",

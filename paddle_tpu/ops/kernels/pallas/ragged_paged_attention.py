@@ -3,13 +3,13 @@
 Reference counterpart: the "Ragged Paged Attention" TPU serving kernel
 (arXiv:2604.15464) that vLLM-lineage TPU backends use to serve a ragged
 mix of prefill chunks and decode rows in a single invocation over the
-paged KV pool. The per-regime split the old serving path had — batch-1
-SDPA prefill + `paged_attention.py` gang decode — forced the scheduler
-to stall every decode step around each admitted prompt; this kernel
-removes the regime split entirely: every row of a step contributes
-``q_len`` query tokens (1 for decode rows, the chunk size for prefill
-chunks) and attends causally against its own block-table slice of the
-shared pool.
+paged KV pool. There is no per-regime split (a prefill kernel beside a
+decode kernel, with the scheduler stalling decode around each admitted
+prompt): every row of a step contributes ``q_len`` query tokens (1 for
+decode rows, the chunk size for prefill chunks) and attends causally
+against its own block-table slice of the shared pool. It is the
+repo's only paged attention kernel; a decode-only step is this kernel
+with ``q_len = 1`` rows.
 
 Layout: packed queries ``q[T, H, D]`` segmented by ``cu_q_lens[R+1]``
 (row r owns tokens ``cu[r]:cu[r+1]`` at absolute positions

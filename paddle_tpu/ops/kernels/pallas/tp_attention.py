@@ -135,7 +135,7 @@ TP_FALLBACK_REASONS = frozenset({
     "heads_indivisible",     # num_heads % tp != 0
     "kv_heads_indivisible",  # kv_heads % tp != 0 (GQA replication edge)
     "shard_unsupported",     # per-shard shape outside the kernel's support
-    "head_dim_mismatch",     # paged: q head_dim != pool head_dim
+    "head_dim_mismatch",     # ragged: q head_dim != pool head_dim
     "ring_head_replicated",  # ring attention running head-replicated
     "ragged_rows_replicated",  # ragged serving: rows asked onto dp, but
                                # the packed token axis is ragged — heads
@@ -280,45 +280,6 @@ def sharded_flash_varlen(q, k, v, cu_q, cu_k, mesh, head_axis,
     return fn(q, k, v, cu_q.astype(jnp.int32), cu_k.astype(jnp.int32))
 
 
-def sharded_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                            mesh, head_axis, batch_axis=None, scale=None):
-    """Serving paged-KV decode with q heads AND the pool's kv heads
-    sharded over `head_axis`; block tables / context lens ride the
-    batch axis. Returns None (recorded) on the divisibility edges."""
-    from . import paged_attention as pa
-
-    B, _, H, D = q.shape
-    KV = k_pool.shape[2]
-    tp = mesh.shape[head_axis]
-    fb = _tp_reason(tp, H, KV)
-    if fb is None and D != k_pool.shape[3]:
-        fb = ("head_dim_mismatch",
-              f"q head_dim {D} != pool head_dim {k_pool.shape[3]}")
-    if fb is not None:
-        record_fallback("paged", *fb)
-        return None
-    if scale is None:
-        scale = D ** -0.5
-    ba = _batch_axis(mesh, batch_axis, B)
-
-    def build():
-        qspec = P(ba, None, head_axis, None)
-        pspec = P(None, None, head_axis, None)
-        tspec = P(ba, None)
-        lspec = P(ba)
-
-        def local(q_, kp, vp, tbl, lens):
-            return pa.paged_attention(q_, kp, vp, tbl, lens, scale=scale)
-
-        return _manual(local, mesh, (qspec, pspec, pspec, tspec, lspec),
-                       qspec)
-
-    fn = _cached(("paged", mesh, head_axis, ba, float(scale)), build)
-    _M_SHARDED.inc()
-    return fn(q, k_pool, v_pool, block_tables.astype(jnp.int32),
-              context_lens.astype(jnp.int32))
-
-
 def sharded_ragged_paged_attention(q, k_pool, v_pool, block_tables,
                                    context_lens, cu_q_lens, mesh,
                                    head_axis, batch_axis=None, scale=None,
@@ -326,8 +287,8 @@ def sharded_ragged_paged_attention(q, k_pool, v_pool, block_tables,
     """Ragged mixed prefill+decode serving attention with q heads AND
     the pool's kv heads sharded over `head_axis`. The packed token axis
     is ragged (cu_q_lens segments it), so rows CANNOT co-shard over a
-    data axis the way gang decode's batch dim does — when the caller
-    asks for one anyway the request is recorded (frozen reason
+    data axis the way a dense batch dim does — when the caller asks
+    for one anyway the request is recorded (frozen reason
     `ragged_rows_replicated`) and the kernel still runs head-sharded
     with rows replicated. Returns None (recorded) on the divisibility /
     head-dim edges; the caller then takes the composite."""

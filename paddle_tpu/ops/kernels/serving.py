@@ -1,4 +1,4 @@
-"""Serving-path kernels: KV-cache write + cache/paged attention.
+"""Serving-path kernels: KV-cache write + cache/ragged paged attention.
 
 Reference: phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu (paged
 KV decode attention) and the write-cache/masked-attention pieces of the
@@ -6,9 +6,9 @@ fused_multi_transformer serving path.
 
 TPU-native: fixed-capacity cache buffers with dynamic-slice writes (position
 is a TENSOR input, so every decode step reuses one compiled executable), and
-paged attention as block-table gather + masked SDPA — XLA keeps the gather
-and the attention in one fusion; a Pallas specialization can override via
-the same op names.
+one attention op over the paged pool, `ragged_paged_attention`: the Pallas
+tile kernel, or a block-table gather + masked SDPA composite under the same
+op name.
 """
 
 from __future__ import annotations
@@ -23,42 +23,29 @@ from .pallas.quant_common import (INT8_BOUND, absmax_scale,
 from ...observability import flight_recorder as _flight_mod
 from ...observability import metrics as _metrics_mod
 
-# Frozen fallback-reason taxonomies for the quantized-KV and speculative
-# serving prongs (same discipline as tp_attention.TP_FALLBACK_REASONS:
-# graftcheck's taxonomy rule checks literal call sites statically, the
-# runtime membership check below covers computed keys).
+# Frozen fallback-reason taxonomy of the quantized-KV pool (same discipline
+# as tp_attention.TP_FALLBACK_REASONS: graftcheck's taxonomy rule checks
+# literal call sites statically, the runtime membership check below covers
+# computed keys).
 KV_QUANT_FALLBACK_REASONS = frozenset({
-    "kv_int8_gang_pallas",   # pallas gang-decode kernel has no dequant
-                             # tile path; quantized pool takes the XLA
-                             # gather composite
     "kv_int8_dense_cache",   # dense KVCache has no quantized layout;
                              # cache stays at the compute dtype
-})
-SPEC_FALLBACK_REASONS = frozenset({
-    "spec_gang_engine",      # gang engine packs no verify rows;
-                             # FLAGS_speculative_k ignored there
 })
 
 _M_KV_FALLBACK = _metrics_mod.registry().counter(
     "serving.kv.fallback",
     "quantized-KV dispatches that left the dequant fast path "
     "(frozen KV_QUANT_FALLBACK_REASONS)")
-_M_SPEC_FALLBACK = _metrics_mod.registry().counter(
-    "serving.spec.fallback",
-    "speculative-decode requests that fell back to plain decode "
-    "(frozen SPEC_FALLBACK_REASONS)")
 
 
 def record_fallback(kind: str, key: str, reason: str) -> None:
-    """Count + flight-record a serving quant/spec fallback. `key` is the
+    """Count + flight-record a quantized-KV fallback. `key` is the
     frozen taxonomy member; `reason` carries the parameterized detail."""
-    if key not in KV_QUANT_FALLBACK_REASONS | SPEC_FALLBACK_REASONS:
+    if key not in KV_QUANT_FALLBACK_REASONS:
         raise ValueError(
             f"unregistered serving fallback reason {key!r} — add it to "
-            f"KV_QUANT_FALLBACK_REASONS / SPEC_FALLBACK_REASONS (frozen "
-            f"so counters cannot fork)")
-    (_M_SPEC_FALLBACK if key in SPEC_FALLBACK_REASONS
-     else _M_KV_FALLBACK).inc()
+            f"KV_QUANT_FALLBACK_REASONS (frozen so counters cannot fork)")
+    _M_KV_FALLBACK.inc()
     if _flight_mod.enabled():
         _flight_mod.recorder().record(
             f"serving.fallback[{kind}]", (reason,), key)
@@ -138,65 +125,6 @@ def paged_cache_write_q_kernel(pool, scale_pool, new, slot_ids):
     return flat.reshape(pool.shape), sflat.reshape(scale_pool.shape)
 
 
-@register_kernel("paged_attention")
-def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
-                           k_scale=None, v_scale=None, scale=None):
-    """Decode attention over paged KV (block_multi_head_attention analog).
-
-    q[B,1,H,D]; pools [NB,BS,KV,D]; block_tables[B,MB] int32 (block ids per
-    sequence, padded arbitrarily); context_lens[B] valid token counts.
-    Routed to the Pallas block-table kernel (pallas/paged_attention.py —
-    streams pool blocks into VMEM, no dense HBM gather) when
-    FLAGS_use_pallas_kernels; under an ambient TP mesh the q heads and
-    the pool's kv heads shard over the mp axis via shard_map
-    (pallas/tp_attention.py) so GSPMD-partitioned serving keeps the
-    fast path. XLA gather+SDPA composite otherwise (TP fallbacks record
-    their reason in the flight recorder).
-    """
-    from ... import flags
-    quantized = k_scale is not None
-    decode_ok = (q.shape[1] == 1 and q.shape[3] == k_pool.shape[3]
-                 and q.shape[2] % k_pool.shape[2] == 0)
-    if decode_ok and quantized and flags.get_flag("use_pallas_kernels"):
-        # the gang-decode Pallas kernel has no dequant tile path (the
-        # ragged kernel is the quantized fast path); composite below
-        record_fallback("paged", "kv_int8_gang_pallas",
-                        "pallas gang decode has no int8 dequant tile; "
-                        "quantized pool takes the XLA gather composite")
-    if decode_ok and not quantized:
-        from .pallas import tp_attention as tpa
-        ctx = tpa.current_tp_context()
-        if ctx is not None:
-            if not flags.get_flag("use_pallas_kernels"):
-                tpa.record_fallback("paged", "flags_off",
-                                    "FLAGS_use_pallas_kernels off")
-            else:
-                mesh, head_axis, batch_axis = ctx
-                out = tpa.sharded_paged_attention(
-                    q, k_pool, v_pool, block_tables, context_lens,
-                    mesh, head_axis, batch_axis, scale)
-                if out is not None:
-                    return out
-        elif flags.get_flag("use_pallas_kernels"):
-            from .pallas import paged_attention as pa
-            return pa.paged_attention(q, k_pool, v_pool, block_tables,
-                                      context_lens, scale)
-    B = q.shape[0]
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
-    mb = block_tables.shape[1]
-    tbl = block_tables.astype(jnp.int32)
-    k = k_pool[tbl]                    # [B, MB, BS, KV, D]
-    v = v_pool[tbl]
-    if quantized:
-        k = k.astype(jnp.float32) * k_scale[tbl][..., None]
-        v = v.astype(jnp.float32) * v_scale[tbl][..., None]
-    k = k.reshape(B, mb * bs, *k.shape[3:])
-    v = v.reshape(B, mb * bs, *v.shape[3:])
-    mask = (jnp.arange(mb * bs, dtype=jnp.int32)[None, None, None, :]
-            < context_lens.astype(jnp.int32)[:, None, None, None])
-    return scaled_dot_product_attention(q, k, v, attn_mask=mask, scale=scale)
-
-
 def _ragged_composite(q, k_pool, v_pool, block_tables, context_lens,
                       cu_q_lens, scale=None, k_scale=None, v_scale=None):
     """XLA composite for ragged mixed prefill+decode attention: per-token
@@ -204,8 +132,7 @@ def _ragged_composite(q, k_pool, v_pool, block_tables, context_lens,
     row's blocks and attends as a batch-1 decode row whose visible
     context is its own absolute position + 1 — causality inside a
     prefill chunk falls out of the per-token bound. Memory scales with
-    T * MB * BS (vs B * MB * BS for gang decode); the Pallas kernel
-    streams blocks instead."""
+    T * MB * BS; the Pallas kernel streams blocks instead."""
     T = q.shape[0]
     nb, bs = k_pool.shape[0], k_pool.shape[1]
     R, mb = block_tables.shape

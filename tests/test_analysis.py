@@ -621,19 +621,17 @@ class TestTaxonomyRule:
             incident.IncidentRecorder().record("no.such.kind")
 
     def test_serving_quant_spec_taxonomies_exist_in_package(self):
-        # the int8-KV / speculative-decode fallback reasons and their
-        # serving metrics are frozen taxonomy, same as the TP reasons
+        # the int8-KV fallback reason and the int8-KV / speculative-decode
+        # serving metrics are frozen taxonomy, same as the TP reasons. The
+        # set holds the one reason the tree can still record: the dense
+        # generate() loop has no quantized layout
         from paddle_tpu.observability.metrics import METRIC_NAMES
-        from paddle_tpu.ops.kernels.serving import (
-            KV_QUANT_FALLBACK_REASONS, SPEC_FALLBACK_REASONS)
-        assert "kv_int8_gang_pallas" in KV_QUANT_FALLBACK_REASONS
-        assert "kv_int8_dense_cache" in KV_QUANT_FALLBACK_REASONS
-        assert "spec_gang_engine" in SPEC_FALLBACK_REASONS
+        from paddle_tpu.ops.kernels import serving
+        assert serving.KV_QUANT_FALLBACK_REASONS == {"kv_int8_dense_cache"}
         for name in ("serving.kv.bytes_per_token",
                      "serving.kv.dequant_blocks", "serving.kv.fallback",
                      "serving.spec.proposed", "serving.spec.accepted",
-                     "serving.spec.rejected", "serving.spec.verify_rows",
-                     "serving.spec.fallback"):
+                     "serving.spec.rejected", "serving.spec.verify_rows"):
             assert name in METRIC_NAMES, name
 
     def test_planted_kv_quant_reason_typo_fires(self):

@@ -158,32 +158,6 @@ class TestTpAttentionMicro:
         assert d["xla_composite_us"] > 0.0
 
 
-class TestServingRaggedMicro:
-    def test_micro_runs_and_reports(self):
-        """bench.py serving_ragged smoke (ISSUE 8 acceptance): the ragged
-        chunked-prefill engine vs the gang-scheduled baseline on a mixed
-        prompt/output stream must produce a well-formed entry with the
-        TTFT/TPOT percentile fields on CPU. The >=1.5x throughput gate is
-        asserted loosely here (wall clock on a shared CI host) — the
-        artifact ratio is the acceptance record."""
-        r = bench.bench_serving_ragged(False, quick=True)
-        assert r["metric"] == "serving_ragged_tok_per_sec"
-        assert r["unit"] == "tokens/sec"
-        assert r["value"] > 0.0
-        d = r["detail"]
-        for k in ("ttft_p50_ms", "ttft_p99_ms", "tpot_p50_ms",
-                  "tpot_p99_ms"):
-            assert d[k] > 0.0, k
-        assert d["ttft_p99_ms"] >= d["ttft_p50_ms"]
-        assert d["gang_prefills"] == d["requests"]
-        assert d["prefix_cache_hit_blocks"] > 0    # shared head really hit
-        assert d["gang_tok_per_sec"] > 0.0
-        # scheduling-model gate, with one retry to absorb a busy host
-        if r["vs_baseline"] < 1.2:
-            r = bench.bench_serving_ragged(False, quick=True)
-        assert r["vs_baseline"] > 1.2, r
-
-
 class TestServingRegimesMicro:
     def test_matrix_runs_and_meets_gates(self):
         """bench.py serving_regimes smoke (ISSUE 20 acceptance): the

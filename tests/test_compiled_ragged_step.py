@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.autograd.engine import no_grad
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.serving import ContinuousBatchingEngine
@@ -164,6 +165,49 @@ def test_a_tap_at_eng_model_sees_concrete_logits_of_the_same_program(model):
     # engines over one model share its program: under the tap the second
     # engine ran the very executable the first had traced
     assert _metric("serving.step.traces") == traces0
+
+
+# the largest gap between the engine's logits and the uncached forward's,
+# as a share of the largest logit: float32 differs by summation order,
+# a bf16 pool by K and V at 8 mantissa bits, an int8 pool by 1/254 of each
+# token's absmax
+@pytest.mark.parametrize("kv_dtype,tol", [("auto", 1e-5), ("bf16", 1e-2),
+                                          ("int8", 2e-2)],
+                         ids=["float32", "bf16-pool", "int8-pool"])
+def test_logits_at_the_tap_match_the_uncached_forward(model, kv_dtype, tol):
+    # a 21-token prompt in chunks of 8 beside another request's decode
+    # rows, then 6 decode steps. The request holds row 0, so the first
+    # tokens each step packed are its own: its logits at those positions
+    prompt, other = _prompts(34, (21, 4))
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=12, prefill_chunk=8,
+                                   kv_dtype=kv_dtype)
+    tap = _Tap(model)
+    eng.model = tap
+    rid = eng.add_request(prompt, max_new_tokens=6)
+    eng.add_request(other, max_new_tokens=12)
+    req = eng.results[rid]
+    rows, ctx_before = [], 0
+    while not req.done:
+        eng.step()
+        n = req.ctx - ctx_before
+        rows.append(np.asarray(tap.logits[-1][0, :n], np.float32))
+        ctx_before = req.ctx
+    # three prefill chunks (the later two beside the other row's decode
+    # token), then one token a step
+    assert [len(r) for r in rows] == [8, 11, 2, 1, 1, 1, 1, 1]
+    got = np.concatenate(rows)
+    out = list(req.out_tokens)
+    ids = np.asarray(prompt + out[:-1], np.int32)[None]
+    with no_grad():
+        want = np.asarray(model(Tensor(jnp.asarray(ids)))._data[0],
+                          np.float32)
+    assert got.shape == want.shape == (26, VOCAB)
+    gap = np.abs(got - want).max() / np.abs(want).max()
+    assert gap <= tol
+    # and the tokens are the argmax of the engine's own rows
+    assert out == got[len(prompt) - 1:].argmax(-1).tolist()
 
 
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"],

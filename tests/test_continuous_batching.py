@@ -2,8 +2,8 @@
 ragged in ISSUE 8).
 
 The ragged engine packs chunked prefill + decode into one compiled step
-over the paged pool; greedy outputs must match BOTH the static
-generate() loop and the preserved gang-scheduled reference engine
+over the paged pool; greedy outputs must match the dense static
+generate() loop (no pool, no packing: the independent oracle)
 token-for-token, the prefix cache must change nothing but the work, and
 stochastic sampling must be schedule-independent. Reference serving
 flow: block_multi_head_attention
@@ -16,8 +16,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.models.serving import (ContinuousBatchingEngine,
-                                       GangScheduledEngine, PrefixCache)
+from paddle_tpu.models.serving import ContinuousBatchingEngine, PrefixCache
 from paddle_tpu.observability import metrics as obs_metrics
 
 import jax.numpy as jnp
@@ -37,8 +36,7 @@ def model():
 
 def _greedy_reference(model, prompt, n_new):
     ids = Tensor(jnp.asarray(np.asarray(prompt, np.int32)[None]))
-    out = model.generate(ids, max_new_tokens=n_new, temperature=0.0,
-                         cache_type="paged", block_size=16)
+    out = model.generate(ids, max_new_tokens=n_new, temperature=0.0)
     return list(np.asarray(out._data)[0, len(prompt):])
 
 
@@ -160,18 +158,7 @@ def _metric(name):
 
 
 class TestRaggedScheduling:
-    def test_gang_reference_matches_static_generate(self, model):
-        # the preserved baseline engine must keep its original semantics
-        rng = np.random.RandomState(7)
-        prompts = [rng.randint(0, 128, n).tolist() for n in (5, 9)]
-        eng = GangScheduledEngine(model, max_batch=2, num_blocks=32,
-                                  block_size=16, temperature=0.0)
-        rids = [eng.add_request(p, max_new_tokens=5) for p in prompts]
-        results = eng.run()
-        for rid, p in zip(rids, prompts):
-            assert results[rid] == _greedy_reference(model, p, 5)
-
-    def test_chunked_prefill_matches_gang(self, model):
+    def test_chunked_prefill_matches_static_generate(self, model):
         # a prompt longer than the chunk prefills across several steps,
         # interleaved with the other rows' decode — outputs unchanged
         rng = np.random.RandomState(2)
@@ -183,13 +170,8 @@ class TestRaggedScheduling:
         a = eng.add_request(short_p, max_new_tokens=12)
         b = eng.add_request(long_p, max_new_tokens=6)
         results = eng.run()
-        gang = GangScheduledEngine(model, max_batch=2, num_blocks=32,
-                                   block_size=16, temperature=0.0)
-        ga = gang.add_request(short_p, max_new_tokens=12)
-        gb = gang.add_request(long_p, max_new_tokens=6)
-        want = gang.run()
-        assert results[a] == want[ga]
-        assert results[b] == want[gb]
+        assert results[a] == _greedy_reference(model, short_p, 12)
+        assert results[b] == _greedy_reference(model, long_p, 6)
 
     def test_one_executable_across_steps(self, model):
         # fixed token budget + row count = static step shapes: after the
@@ -550,18 +532,6 @@ class TestSpeculativeDecode:
         # fewer steps than tokens iff any draft was accepted; at worst
         # equal (verify rows always emit their one guaranteed token)
         assert eng.steps <= base.steps
-
-    def test_gang_engine_records_spec_fallback(self, model):
-        import paddle_tpu as paddle
-        fb0 = _metric("serving.spec.fallback")
-        saved = paddle.get_flags(["FLAGS_speculative_k"])
-        paddle.set_flags({"FLAGS_speculative_k": 4})
-        try:
-            GangScheduledEngine(model, max_batch=2, num_blocks=32,
-                                block_size=16, temperature=0.0)
-        finally:
-            paddle.set_flags(saved)
-        assert _metric("serving.spec.fallback") == fb0 + 1
 
 
 PHASES = ("admit", "schedule", "pack", "dispatch", "sync", "commit")

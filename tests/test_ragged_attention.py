@@ -80,7 +80,7 @@ def _quantize_pools(kp, vp):
 
 def _reference(q, kp, vp, tbl, ctx, cu, bs):
     """Sequential per-row reference: gather each row's blocks densely and
-    run one masked SDPA per TOKEN (the gang-decode math, row by row)."""
+    run one masked SDPA per TOKEN (a decode row's math, token by token)."""
     q, kp, vp = (np.asarray(q, np.float32), np.asarray(kp, np.float32),
                  np.asarray(vp, np.float32))
     tbl, ctx, cu = np.asarray(tbl), np.asarray(ctx), np.asarray(cu)

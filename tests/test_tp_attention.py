@@ -1,9 +1,10 @@
 """GSPMD-composable Pallas attention (ISSUE 4): shard_map'd flash /
-varlen / paged kernels under a forced multi-device CPU mesh.
+varlen kernels under a forced multi-device CPU mesh (the serving
+kernel's wrap is held in test_ragged_attention.py::TestShardedRagged).
 
 Acceptance evidence: sharded output == the unsharded single-device
-reference (allclose + EXACT dtype) for the training (flash/varlen) and
-serving (paged decode) flows; every guard edge (heads not divisible by
+reference (allclose + EXACT dtype) for the training (flash/varlen)
+flows; every guard edge (heads not divisible by
 tp, KV-heads < tp i.e. GQA replication, FLAGS_use_pallas_kernels off)
 takes the composite path with a flight-recorder-visible reason and
 never errors; per-op executables traced under a mesh never replay
@@ -21,7 +22,6 @@ from paddle_tpu.observability import flight_recorder as fr
 from paddle_tpu.ops.dispatcher import call_op
 from paddle_tpu.ops.kernels.pallas import flash_attention as fa
 from paddle_tpu.ops.kernels.pallas import flash_varlen as fv
-from paddle_tpu.ops.kernels.pallas import paged_attention as pa
 from paddle_tpu.ops.kernels.pallas import tp_attention as tpa
 
 pytestmark = [
@@ -155,44 +155,6 @@ class TestShardedVarlen:
                                        atol=2e-3, rtol=2e-3)
 
 
-class TestShardedPaged:
-    def _decode_case(self, rng, B=4, H=8, KV=4, D=32, NB=16, BS=16, MB=4):
-        q = jnp.asarray(rng.randn(B, 1, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
-        tbl = jnp.asarray(rng.randint(0, NB, (B, MB)), jnp.int32)
-        lens = jnp.asarray(rng.randint(BS, MB * BS, B), jnp.int32)
-        return q, kp, vp, tbl, lens
-
-    def test_matches_unsharded_pallas_and_composite(self):
-        rng = np.random.RandomState(6)
-        q, kp, vp, tbl, lens = self._decode_case(rng)
-        out = tpa.sharded_paged_attention(q, kp, vp, tbl, lens,
-                                          _mp_mesh(4), "mp")
-        ref = pa.paged_attention(q, kp, vp, tbl, lens)
-        assert out.dtype == ref.dtype
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
-        # and against the XLA gather+SDPA composite
-        prev = paddle.get_flags("FLAGS_use_pallas_kernels")
-        paddle.set_flags({"FLAGS_use_pallas_kernels": False})
-        try:
-            from paddle_tpu.ops.kernels.serving import paged_attention_kernel
-            comp = paged_attention_kernel(q, kp, vp, tbl, lens)
-        finally:
-            paddle.set_flags(prev)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(comp),
-                                   atol=1e-4, rtol=1e-4)
-
-    def test_bf16_exact_dtype(self):
-        rng = np.random.RandomState(7)
-        q, kp, vp, tbl, lens = self._decode_case(rng)
-        out = tpa.sharded_paged_attention(
-            q.astype(jnp.bfloat16), kp.astype(jnp.bfloat16),
-            vp.astype(jnp.bfloat16), tbl, lens, _mp_mesh(4), "mp")
-        assert out.dtype == jnp.bfloat16
-
-
 class TestFallbackEdges:
     """Guard edges must take the composite path with a recorded reason,
     never error (reasons record at trace time — once per compiled
@@ -250,20 +212,6 @@ class TestFallbackEdges:
         assert any("FLAGS_use_pallas_kernels off" in r
                    for r in _fallback_reasons())
 
-    def test_paged_kv_not_divisible_composite(self):
-        rng = np.random.RandomState(11)
-        B, H, KV, D, NB, BS, MB = 2, 6, 3, 16, 8, 8, 2   # 3 % 4 != 0
-        q = jnp.asarray(rng.randn(B, 1, H, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
-        tbl = jnp.asarray(rng.randint(0, NB, (B, MB)), jnp.int32)
-        lens = jnp.asarray(rng.randint(1, MB * BS, B), jnp.int32)
-        from paddle_tpu.ops.kernels.serving import paged_attention_kernel
-        with tpa.tp_shard_context(_mp_mesh(4), "mp"):
-            out = paged_attention_kernel(q, kp, vp, tbl, lens)
-        assert out.shape == q.shape
-        assert any("not divisible" in r for r in _fallback_reasons("paged"))
-
     def test_varlen_fallback_composite(self):
         rng = np.random.RandomState(12)
         T, h, d = 128, 6, 16   # 6 % 4 != 0
@@ -309,19 +257,23 @@ class TestOpDispatchUnderTopology:
                        Tensor(vn), is_causal=True).numpy()
         np.testing.assert_allclose(out2, ref, atol=2e-5, rtol=2e-5)
 
-    def test_paged_op_under_topology(self):
+    def test_ragged_op_under_topology(self):
+        """The serving op under the fleet topology: decode-only rows
+        (`q_len = 1`) run head-sharded over mp with rows replicated."""
         from paddle_tpu.distributed import topology
         rng = np.random.RandomState(14)
         B, H, KV, D, NB, BS, MB = 4, 8, 4, 16, 16, 16, 4
-        args = (rng.randn(B, 1, H, D).astype(np.float32),
+        args = (rng.randn(B, H, D).astype(np.float32),
                 rng.randn(NB, BS, KV, D).astype(np.float32),
                 rng.randn(NB, BS, KV, D).astype(np.float32),
                 rng.randint(0, NB, (B, MB)).astype(np.int32),
-                rng.randint(BS, MB * BS, B).astype(np.int32))
+                rng.randint(BS, MB * BS, B).astype(np.int32),
+                np.arange(B + 1, dtype=np.int32))
         self._install()
-        out = call_op("paged_attention", *map(Tensor, args)).numpy()
+        out = call_op("ragged_paged_attention", *map(Tensor, args)).numpy()
+        assert any("rows replicated" in r for r in _fallback_reasons("ragged"))
         topology.set_hybrid_communicate_group(None)
-        ref = call_op("paged_attention", *map(Tensor, args)).numpy()
+        ref = call_op("ragged_paged_attention", *map(Tensor, args)).numpy()
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
     def test_sharded_metric_counts(self):
