@@ -407,11 +407,63 @@ def _requests(sz: Sizes, seed: int, vocab: int):
     return reqs
 
 
-def _ragged_step_text(eng) -> str:
-    """The engine's step program, compiled for the shapes it ran with: a
+def _ragged_step_texts(eng) -> List[str]:
+    """The engine's step program, compiled for each geometry it runs: a
     ragged attention dispatcher that gave way to the composite shows in
     the text as a program without its Mosaic call."""
-    return eng._program.compiled().as_text()
+    return [eng._program.compiled(n).as_text() for n in eng.geometries]
+
+
+class _LogitTap:
+    """Stands where the engine holds its model and keeps each step's
+    logits."""
+
+    def __init__(self, model):
+        self._model = model
+        self.config = model.config
+        self.logits: List = []
+
+    def __call__(self, *args, **kwargs):
+        out = self._model(*args, **kwargs)
+        self.logits.append(out._data)
+        return out
+
+
+def _geometry_gap(new_engine, prompt, decode_steps: int) -> Dict:
+    """One prompt's decode steps in each geometry of the step program: an
+    engine as built runs them in its half-width program, one held to the
+    full width as every step ran before there were two. The largest
+    difference between their logits over the largest logit, held to the
+    kernels' tolerance."""
+    def decode_logits(eng):
+        tap = _LogitTap(eng.model)
+        eng.model = tap
+        req = eng.results[eng.add_request(prompt,
+                                          max_new_tokens=decode_steps + 1)]
+        rows, slots = [], set()
+        while not req.done:
+            decoding = req.slot is not None and req.ctx >= req.target
+            eng.step()
+            if decoding:        # the only row: its token is packed first
+                rows.append(tap.logits[-1][0, 0])
+                slots.add(int(tap.logits[-1].shape[1]))
+        return jnp.stack(rows), slots, list(req.out_tokens)
+
+    as_built, full_width = new_engine(), new_engine()
+    built = as_built.geometries
+    full_width.geometries = built[-1:]
+    half, half_slots, half_tokens = decode_logits(as_built)
+    full, full_slots, full_tokens = decode_logits(full_width)
+    _check(len(half) == len(full) == decode_steps,
+           f"{len(half)} and {len(full)} decode steps of {decode_steps}")
+    _check(half_slots | full_slots == set(built),
+           f"decode steps ran {half_slots} and {full_slots} slots")
+    err = _rel_err(half, full)
+    _check(err <= KERNEL_RTOL,
+           f"a decode step's logits differ by {err} between geometries")
+    return {"slots": sorted(half_slots | full_slots),
+            "decode_steps": decode_steps, "rel_err": err,
+            "same_tokens": half_tokens == full_tokens}
 
 
 def serve_phase(sz: Sizes, seed: int) -> Dict:
@@ -430,11 +482,14 @@ def serve_phase(sz: Sizes, seed: int) -> Dict:
                                   // sz.block_size) + 2
     hits = metrics.registry().get("serving.prefix_cache.hit_blocks")
 
-    def run():
-        eng = ContinuousBatchingEngine(
+    def new_engine():
+        return ContinuousBatchingEngine(
             model, max_batch=sz.max_batch, num_blocks=num_blocks,
             block_size=sz.block_size, temperature=0.0,
             token_budget=sz.token_budget, prefill_chunk=sz.prefill_chunk)
+
+    def run():
+        eng = new_engine()
         rids = [eng.add_request(p, max_new_tokens=n) for p, n in reqs]
         t = time.perf_counter()
         out = eng.run()
@@ -450,8 +505,8 @@ def serve_phase(sz: Sizes, seed: int) -> Dict:
                "token id outside the vocabulary")
     _check(hit_blocks >= 1, "the prefix cache reported no hit")
     _check(first == second, "a second greedy run gave other tokens")
-    kernels = _mosaic_calls(_ragged_step_text(eng),
-                            "the engine's step program")
+    kernels = [_mosaic_calls(text, "the engine's step program")
+               for text in _ragged_step_texts(eng)]
     return {
         "phase": "serve", "reduced": _reduced(sz, sz.serve_layers),
         "requests": len(reqs), "max_batch": sz.max_batch,
@@ -459,6 +514,8 @@ def serve_phase(sz: Sizes, seed: int) -> Dict:
         "tokens_out": sum(len(t) for t in first), "steps": eng.steps,
         "prefix_cache_hit_blocks": int(hit_blocks),
         "run_seconds": [t_first, t_second],
+        "geometries": list(eng.geometries),
+        "geometry_logit_gap": _geometry_gap(new_engine, reqs[0][0], 4),
         "pallas_custom_calls": kernels,
         "memory": _mem(jax.devices()[0]),
         "seconds": time.perf_counter() - t0,

@@ -408,11 +408,16 @@ class PersistentJit:
             # loaded Compiled cannot be called — inline the jit fn, the
             # OUTER program owns the compile and the cache entry
             return self._jfn(*args)
+        return self.executable(*args)(*args)
+
+    def executable(self, *args) -> Callable:
+        """What a call with ``args`` (arrays or their shapes) runs: loaded
+        from the store or compiled, once a signature. Nothing runs."""
         sig = _aval_sig(args)
         fn = self._memo.get(sig)
         if fn is None:
             fn = self._resolve(sig, args)
-        return fn(*args)
+        return fn
 
     def _resolve(self, sig, args) -> Callable:
         with self._lock:

@@ -6,12 +6,15 @@ driven by an insert/evict scheduler, modernised to the "Ragged Paged
 Attention" TPU serving discipline (arXiv:2604.15464) with vLLM-lineage
 chunked prefill and prefix caching:
 
-- **One ragged step.** Every scheduler step packs a fixed ``token_budget``
-  of tokens — one per decoding row plus fixed-size prefill chunks of the
+- **One ragged step.** Every scheduler step packs up to ``token_budget``
+  tokens — one per decoding row plus fixed-size prefill chunks of the
   admitted prompts — into ONE model invocation over the shared pool
-  (`ragged_paged_attention`): static shapes, so XLA compiles the step
-  once and every mix of prefill/decode replays it. Long prompts
-  prefill in chunks interleaved with everyone else's decode tokens.
+  (`ragged_paged_attention`). The step's arrays have one of two static
+  slot counts (`_geometries`: half the budget, and the budget), the
+  smaller that holds what was packed, so XLA compiles the step twice,
+  both on the engine's first step, and every mix of prefill/decode
+  replays one of the two. Long prompts prefill in chunks interleaved
+  with everyone else's decode tokens.
 - **Token-budget admission.** Requests queue until a row slot AND enough
   pool blocks for their worst case (prompt + max_new_tokens, minus the
   prefix-cached head) are free — the vLLM reservation rule, so decode
@@ -41,6 +44,7 @@ the tests' independent oracle is the dense `generate()` loop.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time
@@ -90,9 +94,14 @@ _M_STEPS = _M.counter(
 _M_LAUNCHES = _M.counter("dispatch.count")
 _M_TRACES = _M.counter(
     "serving.step.traces",
-    "times a ragged step program was traced (1 per engine when healthy)")
+    "times a ragged step program was traced (1 per model and geometry "
+    "when healthy, all on the first step)")
 _M_STEP_TOKENS = _M.counter(
     "serving.step_tokens", "packed tokens processed (prefill + decode)")
+_M_STEP_SLOTS = _M.counter(
+    "serving.step_slots",
+    "token slots of the geometries the steps ran (step_tokens over this "
+    "is the share of the model's rows that carried a token)")
 _M_GEN_TOKENS = _M.counter(
     "serving.generated_tokens", "tokens sampled and emitted to requests")
 _M_PREFILL_TOKENS = _M.counter(
@@ -310,26 +319,67 @@ class _RaggedView:
         return out.reshape([b, s, h, d])
 
 
-# model -> {(flags.version, pool names): (jitted serving_step, its state)}:
-# engines over one model (the replicas of a fleet, a relaunch, a warm-up
-# engine) share the traced program; jax.jit keeps one executable for each
-# geometry it is called with. Keyed on flags.version like the dispatcher's
-# cache, so a store attached later wraps anew
+# model -> {(flags.version, pool names): its `_ModelProgram`}: engines over
+# one model (the replicas of a fleet, a relaunch, a warm-up engine) share
+# the traced program and its executables, one for each geometry they ask
+# for. Keyed on flags.version like the dispatcher's cache, so a store
+# attached later wraps anew
 _STEP_PROGRAMS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
-def _step_program(model, pool_names: Tuple[str, ...]):
+def _geometries(token_budget: int, max_batch: int,
+                spec_k: int) -> Tuple[int, ...]:
+    """The slot counts a step's token-sized arrays may have, ascending; a
+    step runs the first that holds what it packed. Half the budget is one
+    only where a full decode-or-verify step fits it, so the choice is
+    between "rows alone, or a short chunk beside them" and "a prompt's
+    chunks". (The ragged kernel takes any count: its last q tile may be
+    partial.)"""
+    small = token_budget // 2
+    if small >= max_batch * (spec_k + 1):
+        return (small, token_budget)
+    return (token_budget,)
+
+
+class _ModelProgram:
+    """A model's step program (`_step_program`): the jitted function, the
+    tensors whose buffers are its first argument, and the executables made
+    from it ahead of any call, one for each set of argument shapes."""
+
+    def __init__(self, jit, state: List[Tensor]):
+        self.jit = jit
+        self.state = state
+        self._executables: Dict[Tuple, Any] = {}
+        self._lock = threading.Lock()
+
+    def executable(self, avals):
+        """The program compiled for ``avals`` (`_StepProgram._args`' order):
+        traced, lowered and compiled, or loaded from an attached exec
+        store, once; nothing runs and nothing is donated."""
+        key = tuple(jax.tree.leaves(avals))
+        with self._lock:        # replicas' threads ask for the same one
+            exe = self._executables.get(key)
+            if exe is None:
+                # with an exec store attached the jit resolves through it
+                # (a relaunching replica loads from disk); plain jax.jit
+                # compiles ahead of time
+                exe = (self.jit.executable(*avals)
+                       if isinstance(self.jit, _exec_store.PersistentJit)
+                       else self.jit.lower(*avals).compile())
+                self._executables[key] = exe
+            return exe
+
+
+def _step_program(model, pool_names: Tuple[str, ...]) -> _ModelProgram:
     """The ragged step's model call as ONE XLA program:
 
         (parameters and buffers, the pools, ids, pos, slots, tables, lens,
-         cu)  ->  (logits [1, B, V], the same pools)
+         cu)  ->  (logits [1, slots, V], the same pools)
 
     Built by tracing the model's own forward (its ops run inline on
     tracers through the dispatcher) over a `_RaggedView` of tracers, with
     every pool array donated and returned: the pool writes scatter in
-    place, and one launch replaces the forward's per-op launches. Returns
-    (the jitted function, the tensors whose buffers are its first
-    argument)."""
+    place, and one launch replaces the forward's per-op launches."""
     from .. import flags
     from ..autograd.engine import no_grad
     programs = _STEP_PROGRAMS.setdefault(model, {})
@@ -357,8 +407,15 @@ def _step_program(model, pool_names: Tuple[str, ...]):
     jit = _exec_store.persistent(
         jax.jit(serving_step, donate_argnums=(1,)), "serving",
         label="serving_step")
-    programs[key] = (jit, state)
+    programs[key] = _ModelProgram(jit, state)
     return programs[key]
+
+
+def _aval(a) -> jax.ShapeDtypeStruct:
+    """An argument's shape for lowering; an array that was placed keeps its
+    placement, as a call of the jitted function would have kept it."""
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=a.sharding if a.committed else None)
 
 
 class _StepProgram:
@@ -366,22 +423,29 @@ class _StepProgram:
     the program owns the engine's pools across a call. The arrays it was
     given are gone when it returns (where the backend donates), and the
     cache is rebound to the ones it gave back before anyone else can read
-    them. Shapes are the engine's static ones, so one trace and one
-    executable serve every step."""
+    them. Every shape but the slot count is the engine's static one, so
+    there is one trace and one executable for each of ``geometries``, all
+    of them made on the first call, and every later call runs one of
+    them."""
 
     # dispatches counted while a program was traced: they launched nothing
     traced_ops = 0
 
-    def __init__(self, cache: PagedKVCache):
+    def __init__(self, cache: PagedKVCache, geometries: Tuple[int, ...]):
         self._cache = cache
-        self._model = None
-        self._avals = None
+        self._geometries = geometries
+        self._executables: Dict[int, Any] = {}     # slots -> executable
 
     @classmethod
     def launches(cls) -> int:
         """``dispatch.count`` less the dispatches that ran on tracers while
         a program was traced: a clock of launches to take differences of."""
         return _M_LAUNCHES.value - cls.traced_ops
+
+    @property
+    def cold(self) -> bool:
+        """Before the first call: no geometry has its executable yet."""
+        return not self._executables
 
     def _args(self, state: List[Tensor], ids: Tensor, pos: Tensor,
               view: _RaggedView):
@@ -393,14 +457,20 @@ class _StepProgram:
 
     def __call__(self, model, ids: Tensor, pos: Tensor,
                  view: _RaggedView) -> Tensor:
-        jit, state = _step_program(model, self._cache.pool_names)
-        args = self._args(state, ids, pos, view)
-        if self._avals is None:
-            self._model = weakref.ref(model)
-            self._avals = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        program = _step_program(model, self._cache.pool_names)
+        args = self._args(program.state, ids, pos, view)
+        if self.cold:
+            # every geometry before the first step returns: a server's
+            # first 300-token prompt must not be the one that compiles
+            state, pools, ids_a, pos_a, slots_a, *rows = jax.tree.map(
+                _aval, args)
+            for n in self._geometries:
+                self._executables[n] = program.executable((
+                    state, pools, ids_a.update(shape=(1, n)),
+                    pos_a.update(shape=(1, n)), slots_a.update(shape=(n,)),
+                    *rows))
         _M_LAUNCHES.inc()
-        logits, pools = jit(*args)
+        logits, pools = self._executables[ids.shape[1]](*args)
         self._cache.set_pools(pools)
         return Tensor(logits)
 
@@ -408,25 +478,27 @@ class _StepProgram:
         """The program lowered for ``args`` (arrays or their shapes, as
         `_args` orders them); nothing runs and nothing is donated.
         ``lower(...).compile()`` has its text, cost and memory analysis."""
-        return _step_program(model, self._cache.pool_names)[0].lower(*args)
+        return _step_program(model, self._cache.pool_names).jit.lower(*args)
 
-    def compiled(self):
-        """The program compiled for the shapes of this engine's first
-        call; None before it."""
-        if self._avals is None:
-            return None
-        return self.lower(self._model(), self._avals).compile()
+    def compiled(self, slots: int):
+        """The executable of the ``slots``-slot geometry, with its text,
+        cost and memory analysis; None before the first call."""
+        return self._executables.get(slots)
 
 
 class ContinuousBatchingEngine:
     """Ragged continuous batching: chunked prefill + decode in one
     compiled step over the paged pool, with prefix-cache block sharing.
 
-    ``token_budget`` fixes the packed token count per step (static
-    shapes -> one executable); it must cover at least one token per row
-    (``max_batch``). ``prefill_chunk`` is the fixed chunk size long
-    prompts are sliced into, so a long admission never stalls decode
-    for more than one chunk's worth of compute.
+    ``token_budget`` bounds the packed token count per step; it must
+    cover at least one token per row (``max_batch``). A step's arrays
+    have the smallest slot count of ``geometries`` (`_geometries`: half
+    the budget where every row's tokens fit it, and the budget) that
+    holds what the scheduler packed: static shapes -> two executables,
+    both made on the first step, so 7 or 64 decode rows do not pay for
+    the matmuls of a prompt's chunks. ``prefill_chunk`` is the fixed
+    chunk size long prompts are sliced into, so a long admission never
+    stalls decode for more than one chunk's worth of compute.
 
     The pools are ``self.cache``'s. During a step's model call they
     belong to the engine's `_StepProgram`, which donates them and rebinds
@@ -478,7 +550,6 @@ class ContinuousBatchingEngine:
             max_blocks_per_seq=mb, dtype=getattr(cfg, "dtype", "float32"),
             kv_dtype=kv_dtype)
         _M_KV_BPT.set(self.cache.kv_bytes_per_token())
-        self._program = _StepProgram(self.cache)
         # speculative decoding: K draft tokens per decode row, verified
         # as one q_len=K+1 ragged row out of the leftover token budget.
         # Acceptance is EXACT-MATCH against the row's keyed sample at
@@ -500,6 +571,9 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"token_budget={self.token_budget} < max_batch={max_batch}:"
                 f" decode rows alone would not fit one step")
+        self.geometries = _geometries(self.token_budget, max_batch,
+                                      self.spec_k)
+        self._program = _StepProgram(self.cache, self.geometries)
         self.enable_prefix_cache = enable_prefix_cache
         # one reserved block absorbs the writes of step-padding tokens
         self._trash_slot = self.cache._free.pop() * block_size
@@ -919,6 +993,9 @@ class ContinuousBatchingEngine:
             sp.set(decode_rows=len(decode_rows),
                    prefill_rows=len(prefill_rows),
                    granted=sum(grants.values()))
+            # what the step packs (decode rows, drafts, grants) is known
+            # here: its arrays get the smallest geometry that holds it
+            T = next(n for n in self.geometries if B - left <= n)
 
         with _tracing.start_span("serving.step.pack",
                                  trace=_tracing.UNTRACED,
@@ -927,9 +1004,11 @@ class ContinuousBatchingEngine:
             # position len(out)+j from the logits of packed token t+j. With
             # spec off L=1 and the arrays are exactly the legacy geometry.
             L = self.spec_k + 1
-            ids = np.zeros((B,), np.int32)
-            pos = np.zeros((B,), np.int32)
-            slot_vec = np.full((B,), self._trash_slot, np.int64)
+            ids = np.zeros((T,), np.int32)
+            pos = np.zeros((T,), np.int32)
+            # int32 on the host: handed over as int64 the upload would
+            # convert on the device, in a program of its own a geometry
+            slot_vec = np.full((T,), self._trash_slot, np.int32)
             qlen = np.zeros((R,), np.int32)
             lens = np.zeros((R,), np.int32)
             sample_idx = np.zeros((R * L,), np.int32)
@@ -978,29 +1057,31 @@ class ContinuousBatchingEngine:
             # what one layer's attention call walks: its tiles' live kv
             # blocks, beside the tile x table-column pairs of the whole grid
             kv_tile_blocks = _rpa.live_tile_blocks(qlen, lens, bs)
-            kv_table_blocks = (_rpa.num_tiles(R, B)
+            kv_table_blocks = (_rpa.num_tiles(R, T)
                                * self.cache.block_tables.shape[1])
 
         with _tracing.start_span("serving.step.dispatch",
                                  trace=_tracing.UNTRACED,
                                  attrs={"step": n_step}) as sp_dispatch:
             launches = self._program.launches()
-            # ledger row of the ragged step: the model call is one jax.jit
-            # (`_StepProgram`), whose cost analysis gives the row its FLOPs
-            # and HBM bytes; gather and sampling are two small ops beside
-            # it. The host sync below makes the device-time sample free
+            # ledger row of the ragged step, one a geometry: the model call
+            # is one executable (`_StepProgram`), whose cost analysis gives
+            # the row its FLOPs and HBM bytes; gather and sampling are two
+            # small ops beside it. The host sync below makes the
+            # device-time sample free
             _pe = _p_sample = None
             if _perf_mod.enabled():
                 _led = _perf_mod.ledger()
                 _pe = _led.register(
-                    ("serving", self.max_batch, self.token_budget,
+                    ("serving", self.max_batch, T,
                      self.spec_k, self.cache.kv_dtype),
                     "serving", name="serving_step",
-                    lower=self._program.compiled)
+                    lower=functools.partial(self._program.compiled, T))
                 _p_sample = _led.tick(_pe)
+            cold = self._program.cold
             view = _RaggedView(
                 self.cache,
-                Tensor(jnp.asarray(slot_vec, jnp.int32)),
+                Tensor(jnp.asarray(slot_vec)),
                 Tensor(jnp.asarray(self.cache.block_tables, jnp.int32)),
                 Tensor(jnp.asarray(lens, jnp.int32)),
                 Tensor(jnp.asarray(cu, jnp.int32)),
@@ -1009,16 +1090,25 @@ class ContinuousBatchingEngine:
                 logits = self.model(
                     Tensor(jnp.asarray(ids[None])), cache=view,
                     start_pos=Tensor(jnp.asarray(pos[None], jnp.int32)))
-                lrows = call_op("gather", logits.reshape([B, -1]),
-                                Tensor(jnp.asarray(sample_idx, jnp.int32)))
-                nxt = call_op("sample_logits_keyed", lrows,
-                              Tensor(jnp.asarray(keys)),
-                              Tensor(jnp.asarray(stream_pos, jnp.int32)),
-                              **self.sampling)
-            launches = self._program.launches() - launches
+                tail = (Tensor(jnp.asarray(sample_idx, jnp.int32)),
+                        Tensor(jnp.asarray(keys)),
+                        Tensor(jnp.asarray(stream_pos, jnp.int32)))
+                nxt = self._sample(logits, *tail)
+                launches = self._program.launches() - launches
+                if cold:
+                    # the step program made every geometry's executable on
+                    # this first call; the per-op programs behind it follow
+                    # the logits' shape, so each other geometry's run once
+                    # here, on zeros
+                    for n in self.geometries:
+                        if n != T:
+                            self._sample(Tensor(jnp.zeros(
+                                (1, n) + tuple(logits.shape[2:]),
+                                logits._data.dtype)), *tail)
             self.steps += 1
             _M_STEPS.inc()
             _M_STEP_TOKENS.inc(t)
+            _M_STEP_SLOTS.inc(T)
         with _tracing.start_span("serving.step.sync",
                                  trace=_tracing.UNTRACED,
                                  attrs={"step": n_step}) as sp_sync:
@@ -1041,7 +1131,8 @@ class ContinuousBatchingEngine:
                 # through the host sync (dispatch + sync above)
                 _tracing.record_span(
                     "serving.step", t0_ns, t1_ns,
-                    attrs={"tokens": t, "decode_rows": len(decode_rows),
+                    attrs={"tokens": t, "slots": T,
+                           "decode_rows": len(decode_rows),
                            "prefill_rows": len(prefill_rows),
                            "launches": launches,
                            "kv_tile_blocks": kv_tile_blocks,
@@ -1101,6 +1192,16 @@ class ContinuousBatchingEngine:
                 with self.finish_cv:
                     self.finish_cv.notify_all()
         return finished
+
+    def _sample(self, logits: Tensor, sample_idx: Tensor, keys: Tensor,
+                stream_pos: Tensor) -> Tensor:
+        """The step's three per-op programs behind the model call: the
+        packed logits ``[1, slots, V]`` reshaped, the sampling lanes' rows
+        gathered, and each lane's token drawn from its own stream."""
+        lrows = call_op("gather",
+                        logits.reshape([logits.shape[1], -1]), sample_idx)
+        return call_op("sample_logits_keyed", lrows, keys, stream_pos,
+                       **self.sampling)
 
     def pop_result(self, rid: int,
                    timeout: Optional[float] = None) -> Optional[Request]:

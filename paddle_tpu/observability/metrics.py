@@ -87,9 +87,10 @@ METRIC_NAMES = frozenset({
     "anomaly.nonfinite_steps", "anomaly.skipped_updates",
     "anomaly.loss_spikes", "anomaly.rewinds", "anomaly.rewind_seconds",
     # models/serving.py (ragged continuous-batching engine)
-    "serving.steps", "serving.step_tokens", "serving.generated_tokens",
-    "serving.prefill_tokens", "serving.admitted", "serving.finished",
-    "serving.preemptions", "serving.queue_depth", "serving.active_rows",
+    "serving.steps", "serving.step_tokens", "serving.step_slots",
+    "serving.generated_tokens", "serving.prefill_tokens",
+    "serving.admitted", "serving.finished", "serving.preemptions",
+    "serving.queue_depth", "serving.active_rows",
     "serving.prefill_backlog_tokens", "serving.free_blocks",
     "serving.prefix_cache.hit_blocks", "serving.prefix_cache.miss_blocks",
     "serving.prefix_cache.shared_tokens", "serving.prefix_cache.evictions",

@@ -192,18 +192,21 @@ def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):
 
 
 def _serving_step_text(one_chip):
-    """The engine's step program at chipbench's `mistral-7b-v0.3-serve`:
-    Mistral-7B-v0.3 widths, 8 layers, 64 rows, 512 packed tokens and 16
-    bf16 pools of 4096 blocks, lowered from shapes. The parameters stay the
-    zeros `LazyGuard` puts in host memory (4 GB), never initialized."""
+    """The engine's step program at chipbench's `mistral-7b-v0.3-serve`,
+    in both of its geometries (slots -> compiled text): Mistral-7B-v0.3
+    widths, 8 layers, 64 rows, 512 or 256 token slots and 16 bf16 pools of
+    4096 blocks, lowered from shapes. The parameters stay the zeros
+    `LazyGuard` puts in host memory (4 GB), never initialized."""
     import paddle_tpu as paddle
     from paddle_tpu.jit.api import _collect_state
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.models.generation import PagedKVCache
-    from paddle_tpu.models.serving import _StepProgram
+    from paddle_tpu.models.serving import _StepProgram, _geometries
 
     def build():
-        layers, rows, tokens, blocks, width = 8, 64, 512, 4096, 512
+        layers, rows, blocks, width = 8, 64, 4096, 512
+        geometries = _geometries(512, rows, 0)
+        assert geometries == (256, 512)
         cfg = LlamaConfig(
             vocab_size=32768, hidden_size=4096, intermediate_size=14336,
             num_hidden_layers=layers, num_attention_heads=32,
@@ -231,19 +234,25 @@ def _serving_step_text(one_chip):
         def i32(*shape):
             return _sds(one_chip, shape, jnp.int32)
 
-        args = (state, pools, i32(1, tokens), i32(1, tokens), i32(tokens),
-                i32(rows, width), i32(rows), i32(rows + 1))
-        text = _StepProgram(cache).lower(model, args).compile().as_text()
-        return text, len(state), len(pools)
+        program = _StepProgram(cache, geometries)
+        texts = {n: program.lower(model, (
+            state, pools, i32(1, n), i32(1, n), i32(n),
+            i32(rows, width), i32(rows), i32(rows + 1))).compile().as_text()
+            for n in geometries}
+        return texts, len(state), len(pools)
 
     return _compiled("serving_step", build)
 
 
-def test_serving_step_owns_its_pools(mosaic, one_chip):
+@pytest.mark.parametrize("slots", [512, 256], ids=["budget", "half"])
+def test_serving_step_owns_its_pools(mosaic, one_chip, slots):
     # ISSUE 30: one program for the ragged step's model call. Every pool is
-    # an argument aliased to an output, so the 512 slots are written in
-    # place; the per-op path copied a 537 MB pool for each of 16 writes
-    text, n_state, n_pools = _serving_step_text(one_chip)
+    # an argument aliased to an output, so the slots are written in place;
+    # the per-op path copied a 537 MB pool for each of 16 writes. ISSUE 32:
+    # the same holds for the half-width program of a step that packs at
+    # most 256 tokens
+    texts, n_state, n_pools = _serving_step_text(one_chip)
+    text = texts[slots]
     assert n_pools == 16
     head = text[:text.index("\n")]
     alias = head[head.index("input_output_alias={"):

@@ -38,6 +38,12 @@ def test_phase_runs_tiny(phase, no_mesh_left_behind):
         assert rec["pallas_custom_calls"] == 0      # interpreted here
     if phase == "serve":
         assert rec["prefix_cache_hit_blocks"] >= 1
+        # the check prompt's decode steps in both geometries of the step
+        # program (ISSUE 32): float32 here, so the rows agree closely
+        assert rec["geometries"] == [24, 48]
+        gap = rec["geometry_logit_gap"]
+        assert gap["slots"] == [24, 48] and gap["decode_steps"] == 4
+        assert gap["rel_err"] <= 1e-5 and gap["same_tokens"]
     if phase == "mesh":
         assert rec["rel_diff"] <= 2e-2
         assert rec["collectives"]["all-reduce"] > 0

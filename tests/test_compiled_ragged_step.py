@@ -3,10 +3,17 @@
 
 `ContinuousBatchingEngine.step` hands the model a `_RaggedView` that names
 the engine's `_StepProgram`; the forward runs as that one donated program,
-traced once, whatever stands at ``eng.model``. These cases hold the program
-to the tokens the per-op path gave (the uncached ``generate`` for a float32
-pool, schedule independence and exact speculation for every pool), to its
-launch count, to one trace an engine, and to the pools' hand-over.
+traced once a geometry, whatever stands at ``eng.model``. These cases hold
+the program to the tokens the per-op path gave (the uncached ``generate``
+for a float32 pool, schedule independence and exact speculation for every
+pool), to its launch count, to one trace a geometry, all on the first step,
+and to the pools' hand-over.
+
+Since ISSUE 32 the program has two geometries, half the token budget and the
+budget (``eng.geometries``), and a step runs the smaller that holds what the
+scheduler packed: the cases below the logits' hold it to the choice, to the
+counter and span attribute that report it, and to the same tokens and logits
+in either.
 """
 import threading
 
@@ -56,10 +63,15 @@ def _prompts(seed, lengths):
     return [rng.randint(0, VOCAB, n).tolist() for n in lengths]
 
 
+def _step_spans():
+    """The attributes of every `serving.step` span on record."""
+    return [s.attrs for s in tracing.finished_spans("serving.step")
+            if s.name == "serving.step"]
+
+
 def _step_launches():
     """The ``launches`` attribute of every `serving.step` span on record."""
-    return [s.attrs["launches"] for s in tracing.finished_spans("serving.step")
-            if s.name == "serving.step"]
+    return [a["launches"] for a in _step_spans()]
 
 
 def _serve(model, prompts, n_new, **engine):
@@ -95,6 +107,9 @@ def test_tokens_and_launches(model, kv_dtype, spec_k):
 
 
 def test_one_trace_through_prefill_decode_preemption_and_cow(model):
+    # one trace and one executable a geometry, all of them after the first
+    # step, none later
+    tracing.clear()
     want_a = _generate(model, [3, 4, 5], 24)
     want_b = _generate(model, [9, 8, 7], 24)
     want_c = _generate(model, [7, 8, 9], 6)
@@ -108,8 +123,17 @@ def test_one_trace_through_prefill_decode_preemption_and_cow(model):
                                    preempt_after=4)
     a = eng.add_request([3, 4, 5], max_new_tokens=24)
     b = eng.add_request([9, 8, 7], max_new_tokens=24)
+    assert eng.geometries == (9, 18)
+    eng.step()
+    assert _metric("serving.step.traces") - traces0 == 2
+    assert all(eng._program.compiled(n) is not None for n in eng.geometries)
+    compiles0 = _metric("jit.compiles")
     res = eng.run()
     assert eng.preempt_count >= 1
+    assert {a["slots"] for a in _step_spans()} >= {9, 18}
+    # the resumed row's 16-token chunks ran the wide program, which the
+    # first step (three prompt tokens) had made and not run
+    assert _metric("jit.compiles") == compiles0
     assert res[a] == want_a and res[b] == want_b
     # then a row whose partial block another holder has cached: its next
     # write copies the block first
@@ -121,7 +145,7 @@ def test_one_trace_through_prefill_decode_preemption_and_cow(model):
     eng._pc.acquire(blk)
     assert eng.run()[c] == want_c
     assert _metric("serving.cow_copies") > cow0
-    assert _metric("serving.step.traces") - traces0 == 1
+    assert _metric("serving.step.traces") - traces0 == 2
 
 
 class _Tap:
@@ -159,7 +183,7 @@ def test_a_tap_at_eng_model_sees_concrete_logits_of_the_same_program(model):
     for logits in tap.logits:
         assert isinstance(logits, jax.Array)
         assert not isinstance(logits, jax.core.Tracer)
-        assert logits.shape == (1, 12, VOCAB)
+        assert logits.shape in [(1, n, VOCAB) for n in eng.geometries]
         assert np.isfinite(np.asarray(logits, np.float32)).all()
     assert _step_launches() == plain_launches
     # engines over one model share its program: under the tap the second
@@ -188,15 +212,18 @@ def test_logits_at_the_tap_match_the_uncached_forward(model, kv_dtype, tol):
     rid = eng.add_request(prompt, max_new_tokens=6)
     eng.add_request(other, max_new_tokens=12)
     req = eng.results[rid]
-    rows, ctx_before = [], 0
+    rows, slots, ctx_before = [], [], 0
     while not req.done:
         eng.step()
         n = req.ctx - ctx_before
         rows.append(np.asarray(tap.logits[-1][0, :n], np.float32))
+        slots.append(tap.logits[-1].shape[1])
         ctx_before = req.ctx
     # three prefill chunks (the later two beside the other row's decode
-    # token), then one token a step
+    # token), then one token a step: the first two in the 12-slot program,
+    # every later step in the 6-slot one
     assert [len(r) for r in rows] == [8, 11, 2, 1, 1, 1, 1, 1]
+    assert slots == [12, 12, 6, 6, 6, 6, 6, 6]
     got = np.concatenate(rows)
     out = list(req.out_tokens)
     ids = np.asarray(prompt + out[:-1], np.int32)[None]
@@ -204,10 +231,168 @@ def test_logits_at_the_tap_match_the_uncached_forward(model, kv_dtype, tol):
         want = np.asarray(model(Tensor(jnp.asarray(ids)))._data[0],
                           np.float32)
     assert got.shape == want.shape == (26, VOCAB)
-    gap = np.abs(got - want).max() / np.abs(want).max()
-    assert gap <= tol
+    at = np.repeat(slots, [len(r) for r in rows])
+    for n in eng.geometries:        # the file's tolerance, in each geometry
+        gap = np.abs(got - want)[at == n].max() / np.abs(want).max()
+        assert gap <= tol, (n, gap)
     # and the tokens are the argmax of the engine's own rows
     assert out == got[len(prompt) - 1:].argmax(-1).tolist()
+
+
+# -- the two geometries (ISSUE 32) ---------------------------------------------
+
+POOLS = pytest.mark.parametrize("kv_dtype", ["auto", "bf16", "int8"],
+                                ids=["float32", "bf16-pool", "int8-pool"])
+SPEC = pytest.mark.parametrize("spec_k", [0, 2], ids=["spec0", "spec2"])
+
+
+class _Compiles:
+    """Lowerings and backend compilations while ``armed``, as chipbench
+    counts them inside a measured window."""
+    EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+              "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.count, self.armed = 0, False
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event, duration, **kw):
+        if self.armed and event in self.EVENTS:
+            self.count += 1
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    return _Compiles()
+
+
+def _smallest(geometries, tokens):
+    return min(n for n in geometries if tokens <= n)
+
+
+@SPEC
+@POOLS
+def test_a_step_runs_the_smallest_geometry_that_holds_it(model, compiles,
+                                                         kv_dtype, spec_k):
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=16, prefill_chunk=16,
+                                   kv_dtype=kv_dtype, speculative_k=spec_k)
+    assert eng.geometries == (8, 16)
+    tap = _Tap(model)
+    eng.model = tap
+    fits, over, short = _prompts(41, (8, 9, 3))
+    slots0 = _metric("serving.step_slots")
+    tokens0 = _metric("serving.step_tokens")
+    tracing.clear()
+    # t = T_small: the whole prompt in the half-width program, which is
+    # this engine's first call: both executables exist when it returns
+    a = eng.add_request(fits, max_new_tokens=5)
+    eng.step()
+    assert [(s["tokens"], s["slots"]) for s in _step_spans()] == [(8, 8)]
+    assert all(eng._program.compiled(n) is not None for n in (8, 16))
+    compiles.count, compiles.armed = 0, True
+    try:
+        out = eng.run()
+        # t = T_small + 1: one token more takes the full width
+        b = eng.add_request(over, max_new_tokens=5)
+        eng.step()
+        assert (_step_spans()[-1]["tokens"], _step_spans()[-1]["slots"]) \
+            == (9, 16)
+        # and a prompt beside a decoding row: 1 (+ drafts) + 3 tokens
+        c = eng.add_request(short, max_new_tokens=4)
+        out.update(eng.run())
+    finally:
+        compiles.armed = False
+    assert compiles.count == 0, "a step after the first lowered or compiled"
+    spans = _step_spans()
+    assert len(spans) == eng.steps == len(tap.logits)
+    for sp, logits in zip(spans, tap.logits):
+        assert sp["slots"] == _smallest(eng.geometries, sp["tokens"])
+        assert logits.shape == (1, sp["slots"], VOCAB)
+    assert {sp["slots"] for sp in spans} == {8, 16}
+    # the counter beside `serving.step_tokens` adds up the geometries run
+    assert _metric("serving.step_slots") - slots0 \
+        == sum(sp["slots"] for sp in spans)
+    assert _metric("serving.step_tokens") - tokens0 \
+        == sum(sp["tokens"] for sp in spans)
+    if kv_dtype == "auto":
+        assert [out[a], out[b], out[c]] == [_generate(model, fits, 5),
+                                            _generate(model, over, 5),
+                                            _generate(model, short, 4)]
+
+
+@SPEC
+def test_a_budget_that_cannot_be_halved_keeps_one_geometry(model, compiles,
+                                                           spec_k):
+    # half the budget must hold a full decode-or-verify step, max_batch x
+    # (k + 1) tokens: one short of that and the engine is as before, one
+    # program of token_budget slots for every step, traced once
+    rows = 3
+    budget = 2 * rows * (spec_k + 1) - 1
+    prompts = _prompts(42, (11, 4, 7))
+    want = [_generate(model, p, 6) for p in prompts]
+    traces0 = _metric("serving.step.traces")
+    tracing.clear()
+    eng = ContinuousBatchingEngine(model, max_batch=rows, num_blocks=48,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=budget, prefill_chunk=4,
+                                   speculative_k=spec_k)
+    assert eng.geometries == (budget,)
+    rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+    eng.step()
+    assert _metric("serving.step.traces") - traces0 == 1
+    compiles.count, compiles.armed = 0, True
+    try:
+        res = eng.run()
+    finally:
+        compiles.armed = False
+    assert compiles.count == 0
+    assert _metric("serving.step.traces") - traces0 == 1
+    assert [res[r] for r in rids] == want
+    spans = _step_spans()
+    assert len(spans) == eng.steps
+    assert {sp["slots"] for sp in spans} == {budget}
+    assert set(_step_launches()) == {4}
+
+
+@SPEC
+@POOLS
+def test_a_stream_that_alternates_geometries_gives_the_oracles_tokens(
+        model, kv_dtype, spec_k):
+    # prompts arrive while others decode, so the stream goes 16, 8, 8, 16,
+    # 8, ... slots: greedy tokens are the dense generate()'s (float32 pool)
+    # and, whatever the pool rounds to, those of the same stream through an
+    # engine held to the full width (what every step ran before ISSUE 32)
+    prompts = _prompts(43, (12, 10, 5, 13))
+    n_new = (9, 7, 8, 6)
+
+    def stream(full_width_only):
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                       block_size=16, temperature=0.0,
+                                       token_budget=16, prefill_chunk=16,
+                                       kv_dtype=kv_dtype,
+                                       speculative_k=spec_k)
+        if full_width_only:
+            eng.geometries = eng.geometries[-1:]
+        tracing.clear()
+        rids = []
+        for p, n in zip(prompts, n_new):
+            rids.append(eng.add_request(p, max_new_tokens=n))
+            for _ in range(3):
+                eng.step()
+        res = eng.run()
+        return [res[r] for r in rids], [sp["slots"] for sp in _step_spans()]
+
+    got, slots = stream(False)
+    want, wide = stream(True)
+    assert set(wide) == {16}
+    changes = sum(a != b for a, b in zip(slots, slots[1:]))
+    assert set(slots) == {8, 16} and changes >= 4, slots
+    assert got == want
+    assert [len(t) for t in got] == list(n_new)
+    if kv_dtype == "auto":
+        assert got == [_generate(model, p, n) for p, n in zip(prompts, n_new)]
 
 
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"],

@@ -174,20 +174,25 @@ class TestRaggedScheduling:
         assert results[b] == _greedy_reference(model, long_p, 6)
 
     def test_one_executable_across_steps(self, model):
-        # fixed token budget + row count = static step shapes: after the
-        # first step compiles, later steps must be pure exec-cache hits
+        # fixed row count and two slot counts (half the token budget, and
+        # the budget) = static step shapes: one trace and one executable
+        # a geometry, all of them after the first step, none later
         rng = np.random.RandomState(3)
         eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=32,
                                        block_size=16, temperature=0.0)
+        assert eng.geometries == (9, 18)
         for n in (5, 9, 7, 3):
             eng.add_request(rng.randint(0, 128, n).tolist(),
                             max_new_tokens=6)
-        eng.step()
-        eng.step()
+        slots0 = _metric("serving.step_slots")
+        eng.step()                 # 5 + 9 prompt tokens: the 18-slot program
+        assert _metric("serving.step_slots") - slots0 == 18
         compiles0 = _metric("jit.compiles")
-        eng.run()
+        eng.run()                  # decode steps: the 9-slot one
         assert _metric("jit.compiles") == compiles0, (
-            "steady-state ragged steps recompiled")
+            "a ragged step after the first compiled")
+        slots = _metric("serving.step_slots") - slots0
+        assert 9 * eng.steps < slots < 18 * eng.steps
 
     def test_randomized_stream_invariants(self, model):
         # randomized mixed prompt/output stream through a tight pool with
@@ -621,9 +626,13 @@ class TestStepPhases:
                 for s in steps] == [(0, 1), (1, 1), (1, 1)]
         assert [s.attrs["kv_tile_blocks"] for s in steps] == [
             2, 2 + 2 + 4 + 6, 2 + 8 + 10]
-        # 3 rows + ceil(25 / 8) tiles, each against every table column
-        table = (3 + 4) * eng.cache.block_tables.shape[1]
-        assert [s.attrs["kv_table_blocks"] for s in steps] == [table] * 3
+        # 3 rows + ceil(slots / 8) tiles, each against every table column:
+        # the table follows the geometry the step ran (ISSUE 32), 12 slots
+        # for step 1's 6 tokens, the budget's 25 for 25 and 17 tokens
+        assert [s.attrs["slots"] for s in steps] == [12, 25, 25]
+        width = eng.cache.block_tables.shape[1]
+        assert [s.attrs["kv_table_blocks"] for s in steps] == [
+            (3 + 2) * width, (3 + 4) * width, (3 + 4) * width]
 
     def test_idle_step_records_admit_only(self, model):
         from paddle_tpu.observability import tracing
