@@ -1,16 +1,18 @@
-"""What both drivers need: the run's context, the model built from a
-configuration file, the compile counter, the tracer's slice."""
+"""What both drivers need: the run's context, the configuration's family,
+the compile counter, the heartbeat, the tracer's slice."""
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import os
 import shutil
+import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
-from .. import reference, trace
+from .. import trace
 
 # a new program was lowered, or the backend compiled one: neither may
 # happen inside a measured window
@@ -31,6 +33,8 @@ class Context:
     t_process: float            # time.perf_counter() when the process began
     scratch: str                # a directory inside the checkout
     device_kind: str
+    heartbeat: bool = True      # False: no heartbeat thread in the window
+    control: bool = False       # True: the check also reads its control
 
     def emit(self, event: str, **fields: Any) -> None:
         """An earlier line of standard output; never the last."""
@@ -53,6 +57,11 @@ class Result:
     config: Dict = dataclasses.field(default_factory=dict)
     cell: Dict = dataclasses.field(default_factory=dict)
     device_kind: str = ""
+    # what ``correct`` compared: name -> [number, limit]
+    compared: Dict[str, list] = dataclasses.field(default_factory=dict)
+    # read by the driver once its window has closed, before any reference
+    # of the check runs on the device and raises it
+    memory_peak_bytes: int = 0
 
 
 class CompileCounter:
@@ -67,6 +76,56 @@ class CompileCounter:
     def _on_event(self, event: str, duration: float, **kw) -> None:
         if self.armed and event in _COMPILE_EVENTS:
             self.count += 1
+
+
+class Heartbeat:
+    """Says when the machine stood still. A daemon thread sleeps ``period_s``
+    at a time from ``start()`` to ``stop()`` and keeps every wake-up that
+    came more than ``late_s`` after it was due: a stop. It touches no metric
+    and no ``correct``; the notes line carries what it found, so that a
+    reader of a set of runs can tell which of them the machine spoiled. The
+    clock and the sleep are arguments so that a test can make a stop."""
+
+    def __init__(self, period_s: float = 0.005, late_s: float = 0.05,
+                 clock: Callable[[], float] = time.perf_counter,
+                 sleep: Callable[[float], None] = time.sleep):
+        self.period_s, self.late_s = period_s, late_s
+        self._clock, self._sleep = clock, sleep
+        self.stops_ms: List[float] = []
+        self.beats = 0
+        self.done = False
+        self._thread: Optional[threading.Thread] = None
+
+    def beat(self) -> None:
+        """Sleeps and wakes until ``done``; the thread's whole work."""
+        while not self.done:
+            asleep = self._clock()
+            self._sleep(self.period_s)
+            late = self._clock() - asleep - self.period_s
+            self.beats += 1
+            if late > self.late_s:
+                self.stops_ms.append(late * 1e3)
+
+    def start(self, on: bool = True) -> "Heartbeat":
+        """Starts the thread; with ``on`` false it starts nothing, and
+        ``stop()`` then reports no beat."""
+        if not on:
+            return self
+        self._thread = threading.Thread(target=self.beat, daemon=True,
+                                        name="chipbench-heartbeat")
+        self._thread.start()
+        return self
+
+    def stop(self) -> Dict[str, float]:
+        """Ends the thread and returns the notes: how many stops over
+        ``late_s``, the longest and their sum (0 where there was none)."""
+        self.done = True
+        if self._thread is not None:
+            self._thread.join()
+        return {"stops_over_50ms": len(self.stops_ms),
+                "stop_longest_ms": max(self.stops_ms, default=0.0),
+                "stop_sum_ms": sum(self.stops_ms, 0.0),
+                "heartbeats": self.beats}
 
 
 class Slice:
@@ -108,6 +167,13 @@ class Slice:
         return reduced
 
 
+def memory_peak_bytes(chips: int) -> int:
+    """The peak on the fullest of the cell's chips, as JAX reports it."""
+    import jax
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.devices()[:chips])
+
+
 def sized(section: Dict, rehearse: bool) -> Dict:
     """A cell's or a configuration's values, with the ``rehearse`` group laid
     over them for a CPU rehearsal (one level deep)."""
@@ -126,42 +192,12 @@ def model_sizes(config: Dict) -> Dict:
                if not isinstance(v, dict)}, **config.get("model", {})}
 
 
-def build_model(sizes: Dict, seed: int, train_options: Optional[Dict] = None):
-    """``LlamaForCausalLM`` at the configuration's sizes, holding weights
-    made by ``reference.make_weights`` from the seed. Returns (model,
-    LlamaConfig, weights); the weights are the arrays the model holds."""
-    import jax.numpy as jnp
-    import paddle_tpu as paddle
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    if sizes.get("sliding_window") is not None:
-        raise ValueError("LlamaForCausalLM has no sliding window")
-    options = train_options or {}
-    cfg = LlamaConfig(
-        vocab_size=sizes["vocab_size"], hidden_size=sizes["hidden_size"],
-        intermediate_size=sizes["intermediate_size"],
-        num_hidden_layers=sizes["num_hidden_layers"],
-        num_attention_heads=sizes["num_attention_heads"],
-        num_key_value_heads=sizes["num_key_value_heads"],
-        max_position_embeddings=sizes["max_position_embeddings"],
-        rms_norm_eps=sizes["rms_norm_eps"], rope_theta=sizes["rope_theta"],
-        tie_word_embeddings=sizes["tie_word_embeddings"],
-        use_flash_attention=options.get("use_flash_attention", True),
-        recompute=options.get("recompute", False),
-        dtype=sizes["torch_dtype"])
-    paddle.seed(seed % (2 ** 31 - 1))
-    model = LlamaForCausalLM(cfg)
-    weights = reference.make_weights(sizes, seed, jnp.dtype(cfg.dtype))
-    named = dict(model.named_parameters())
-    if set(named) != set(weights):
-        raise RuntimeError(
-            f"the model's parameters are not the reference's: "
-            f"{sorted(set(named) ^ set(weights))}")
-    for name, p in named.items():
-        if tuple(p._data.shape) != tuple(weights[name].shape):
-            raise RuntimeError(f"{name}: {p._data.shape} in the model, "
-                               f"{weights[name].shape} in the reference")
-        p._set_data(weights[name])
-    return model, cfg, weights
+def family(config: Dict):
+    """The module that builds a configuration's model and points at its
+    plain reference: ``families/<family>.py``, ``llama`` where the
+    configuration names none."""
+    return importlib.import_module(
+        "chipbench.families." + config.get("family", "llama"))
 
 
 def quantile(values: List[float], q: float) -> float:

@@ -10,15 +10,16 @@ it was due, not when the loop got round to adding it.
 
 from __future__ import annotations
 
+import gc
 import time
 from statistics import median
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from .. import reference, traffic
-from .common import (CompileCounter, Context, Result, Slice, build_model,
-                     model_sizes, quantile, sized)
+from .. import traffic
+from .common import (CompileCounter, Context, Heartbeat, Result, Slice,
+                     family, memory_peak_bytes, model_sizes, quantile, sized)
 
 UNITS = {"ttft_p50_ms": "ms", "ttft_mean_ms": "ms", "itl_p95_ms": "ms",
          "serve_tokens_per_s": "tokens/s", "setup_s": "s"}
@@ -65,7 +66,9 @@ class Session:
         config = sized(ctx.config, ctx.rehearse)
         self.sizes = model_sizes(config)
         self.cell = sized(ctx.cell, ctx.rehearse)
-        self.model, self.cfg, self.weights = build_model(self.sizes, ctx.seed)
+        self.family = family(config)
+        self.model, self.cfg, self.weights = self.family.build_model(
+            self.sizes, ctx.seed)
         self.model.eval()
         jax.block_until_ready(list(self.weights.values()))
         self.parts["weights"] = time.perf_counter() - t
@@ -74,14 +77,14 @@ class Session:
         self.step_tokens = metrics.registry().get("serving.step_tokens")
         self.compiles = CompileCounter()
         self.sent: Dict[int, _Sent] = {}       # rid -> request in flight
-        self.check = self._check()
-        self.parts["engine_check_warmup"] = time.perf_counter() - t
+        self.warm = self._warm_up()
+        self.parts["engine_warmup"] = time.perf_counter() - t
 
     # -- correctness, outside any window -------------------------------------
-    def _check(self) -> Dict:
+    def _warm_up(self) -> Dict:
         """One seeded prompt through the engine's own path: chunked prefill,
-        then decode steps through the paged cache; every position's logits
-        against the reference's full forward over the same tokens."""
+        then decode steps through the paged cache. It warms both geometries
+        up, and every position's logits are kept for ``judge``."""
         import jax.numpy as jnp
         spec = self.cell["check"]
         rng = np.random.default_rng([self.ctx.seed, 1])
@@ -101,11 +104,25 @@ class Session:
         finally:
             self.eng.model = self.model
         self.eng.pop_result(rid)
-        got = jnp.concatenate(rows)
-        out = list(req.out_tokens)
+        return {"prompt": prompt, "out": list(req.out_tokens),
+                "got": jnp.concatenate(rows)}
+
+    def release_engine(self) -> None:
+        """The window is closed and the peak is read: the engine and its
+        pools go, and the reference has the chip."""
+        self.eng = self.model = None
+        gc.collect()
+
+    def judge(self) -> Dict:
+        """The warm-up's logits against the reference's full forward over
+        the same tokens. Run once the engine is released: the reference's
+        float32 copies would otherwise set the process's peak memory."""
+        import jax.numpy as jnp
+        spec = self.cell["check"]
+        prompt, out, got = (self.warm[k] for k in ("prompt", "out", "got"))
         ids = np.concatenate([prompt, out[:-1]]).astype(np.int32)
-        want = reference.logits(self.sizes, self.weights, ids,
-                                query_block=512)
+        want = self.family.logits(self.sizes, self.weights, ids,
+                                  query_block=512)
         scale = float(jnp.max(jnp.abs(want)))
         gap = float(jnp.max(jnp.abs(got - want))) / scale
         # Greedy decoding must agree with the reference wherever the
@@ -246,10 +263,11 @@ def summarize(phase: Dict, vocab: int, step_ends: List[float]) -> Dict:
 
 
 def window_tokens(all_sent: List[_Sent], t0: float, t1: float):
-    """Output tokens stamped inside [t0, t1) and the gaps between
-    consecutive tokens of one request whose later token is inside it; over
+    """Output tokens stamped inside [t0, t1), the gaps between consecutive
+    tokens of one request whose later token is inside it, and for each gap
+    the stamp of that later token (the end of the step that made it); over
     every request that was in flight, whenever it arrived."""
-    tokens, gaps = 0, []
+    tokens, gaps, gap_stamps = 0, [], []
     for s in all_sent:
         prev = None
         for t in s.stamps:
@@ -257,8 +275,106 @@ def window_tokens(all_sent: List[_Sent], t0: float, t1: float):
                 tokens += 1
                 if prev is not None:
                     gaps.append((t - prev) * 1e3)
+                    gap_stamps.append(t)
             prev = t
-    return tokens, gaps
+    return tokens, gaps, gap_stamps
+
+
+def over_half_budget_share(gap_stamps: List[float], steps: List[Dict],
+                           smallest_geometry: int) -> Optional[float]:
+    """Share (%) of the gap samples whose later token came from a step that
+    packed more tokens than the engine's smallest geometry holds, so ran a
+    larger program. It says on which side of its two step times the 95th
+    percentile of the gaps sits: near 5 % it swings with the seed."""
+    if not gap_stamps:
+        return None
+    packed = {s["t_end"]: s["tokens"] for s in steps}
+    over = sum(packed[t] > smallest_geometry for t in gap_stamps)
+    return 100.0 * over / len(gap_stamps)
+
+
+def served_sample(phases: List[Dict], t0: float, t1: float, n: int,
+                  rng: np.random.Generator) -> List[_Sent]:
+    """``n`` of the requests that finished inside [t0, t1): the longest
+    (prompt and answer), and the others drawn by ``rng``."""
+    done = [s for ph in phases for s in ph["sent"]
+            if s.error is None and s.req is not None and s.req.done
+            and s.stamps and t0 <= s.stamps[-1] < t1]
+    if not done:
+        return []
+    longest = max(done, key=lambda s: len(s.plan.prompt)
+                  + len(s.req.out_tokens))
+    rest = [s for s in done if s is not longest]
+    pick = rng.choice(len(rest), min(n - 1, len(rest)), replace=False)
+    return [longest] + [rest[i] for i in sorted(pick)]
+
+
+def _widest_gap(want, tokens, n):
+    """Over the first ``n`` rows of ``want`` [rows, vocab]: the widest
+    distance of ``tokens``' logits below the row's best, the largest
+    |logit|, and how many of the tokens are the row's best."""
+    import jax.numpy as jnp
+    live = jnp.arange(want.shape[0]) < n
+    best = jnp.max(want, -1)
+    theirs = jnp.take_along_axis(want, tokens[:, None], -1)[:, 0]
+    return (jnp.max(jnp.where(live, best - theirs, 0.0)),
+            jnp.max(jnp.where(live[:, None], jnp.abs(want), 0.0)),
+            jnp.sum(live & (theirs == best)))
+
+
+def served_check(fam, sizes: Dict, weights: Dict, sample: List[_Sent],
+                 spec: Dict, answer_rows: int, control: bool = False) -> Dict:
+    """The window's own answers against the plain reference. For each
+    sampled request the reference runs once over the prompt and the tokens
+    that were served, and every served token's logit is held against the
+    reference's best at its position: ``served_gap`` is the widest distance
+    below it, over the largest |reference logit| of the sample. A greedy
+    engine that rounds as the configuration says stays within its rounding
+    of the best; a token from a coarser path, or one altered after it was
+    sampled, lies further down. With ``control`` the same positions are
+    also run through the reference at each of ``control_precisions`` and
+    the gap of the token that it puts first is read. Every request runs at
+    one shape, ``pad_tokens`` positions and ``answer_rows`` logit rows (the
+    traffic's longest answer), so the reference compiles once."""
+    import jax
+    import jax.numpy as jnp
+    widest = jax.jit(_widest_gap)
+    gap_max, top, tokens, agree = 0.0, 0.0, 0, 0
+    controls = {p: 0.0 for p in spec.get("control_precisions", [])} \
+        if control else {}
+    for s in sample:
+        out = np.asarray(s.req.out_tokens, np.int32)
+        lo = len(s.plan.prompt) - 1
+        ids = np.concatenate([s.plan.prompt, out[:-1]]).astype(np.int32)
+        # causal: zeros after the last position change nothing before it
+        need = max(len(ids), lo + answer_rows)
+        ids = np.pad(ids, (0, need + -need % spec["pad_tokens"] - len(ids)))
+        rows = (lo, answer_rows)
+        want = fam.logits(sizes, weights, ids, spec["query_block"], rows=rows)
+        served = jnp.asarray(np.pad(out, (0, answer_rows - len(out))))
+        gap, biggest, same = widest(want, served, len(out))
+        gap_max, top = max(gap_max, float(gap)), max(top, float(biggest))
+        tokens += len(out)
+        agree += int(same)
+        for p in controls:
+            low = fam.logits(sizes, weights, ids, spec["query_block"],
+                             rows=rows, precision=p)
+            gap, _, _ = widest(want, jnp.argmax(low, -1), len(out))
+            controls[p] = max(controls[p], float(gap))
+    # nothing finished, nothing compared: the widest a gap can be, two of
+    # the largest |logit|, so the run is not correct and the line stays JSON
+    gap = gap_max / top if top > 0 else 2.0
+    found = {"ok": bool(sample) and gap <= spec["served_gap_tol"],
+             "served_gap": gap, "served_gap_tol": spec["served_gap_tol"],
+             "requests": len(sample), "served_tokens": tokens,
+             "served_is_reference_best_share": agree / max(tokens, 1),
+             "longest_tokens": max((len(s.plan.prompt)
+                                    + len(s.req.out_tokens)
+                                    for s in sample), default=0),
+             "ref_logit_max": top}
+    for p, g in controls.items():
+        found[f"control_gap_{p}"] = g / top if top > 0 else 2.0
+    return found
 
 
 def report(ses: Session, before: List[Dict], win: Dict):
@@ -268,9 +384,10 @@ def report(ses: Session, before: List[Dict], win: Dict):
     seconds = win["t1"] - win["t0"]
     steps = win["steps"]
     everyone = [s for ph in before + [win] for s in ph["sent"]]
-    all_ends = [s["t_end"] for ph in before + [win] for s in ph["steps"]]
+    all_steps = [s for ph in before + [win] for s in ph["steps"]]
+    all_ends = [s["t_end"] for s in all_steps]
     summary = summarize(win, ses.cfg.vocab_size, all_ends)
-    tokens, gaps = window_tokens(everyone, win["t0"], win["t1"])
+    tokens, gaps, gap_stamps = window_tokens(everyone, win["t0"], win["t1"])
     step_ms = [(s["t_end"] - s["t_begin"]) * 1e3 for s in steps]
     thirds = [[s["waiting"] for s in steps
                if k / 3 <= (s["t_end"] - win["t0"]) / seconds < (k + 1) / 3]
@@ -293,9 +410,14 @@ def report(ses: Session, before: List[Dict], win: Dict):
         "ttft_p50_ms_by_prompt_tokens": by_prompt,
         "itl_p50_ms": median(gaps) if gaps else None,
         "itl_p95_ms": quantile(gaps, 0.95) if gaps else None,
+        "itl_p99_ms": quantile(gaps, 0.99) if gaps else None,
+        "itl_over_half_budget_share": over_half_budget_share(
+            gap_stamps, all_steps, ses.eng.geometries[0]),
         "itl_samples": len(gaps), "tokens_in_window": tokens,
         "serve_tokens_per_s": tokens / seconds,
         "steps": len(steps),
+        "steps_over_half_budget": sum(
+            s["tokens"] > ses.eng.geometries[0] for s in steps),
         "step_ms_p50": median(step_ms) if step_ms else None,
         "rows_mean": (float(np.mean([s["rows"] for s in steps]))
                       if steps else None),
@@ -319,16 +441,19 @@ def run(ctx: Context) -> Result:
     cell = ses.cell
     spec = cell["traffic"]
     ramp_s = float(spec.get("ramp_s", 0))
-    ctx.emit("check", **ses.check)
     t_ramp = time.perf_counter()
+    heart = Heartbeat().start(ctx.heartbeat)
     ramp = ses.phase(spec, ramp_s, phase_seed=2)
+    ramp_stops = heart.stop()
     t_window = t_ramp + ramp_s
     ses.parts["ramp"] = ramp_s
     setup_s = t_window - ctx.t_process
     tracer = Slice(ctx, t_window, cell["trace_slice_s"])
     ses.compiles.armed = True
+    heart = Heartbeat().start(ctx.heartbeat)
     win = ses.phase(spec, ctx.seconds, phase_seed=3, tracer=tracer,
                     start_at=t_window)
+    stops = heart.stop()
     ses.compiles.armed = False
     tracer.finish()
 
@@ -336,16 +461,43 @@ def run(ctx: Context) -> Result:
     traced = [s for s in win["steps"]
               if tracer.covers(s["t_begin"], s["t_end"])]
     ctx.emit("setup", setup_s=setup_s, parts=ses.parts)
+    # a stop in the ramp leaves a backlog that the window then works off:
+    # its longest says whether the window began in the steady state
     ctx.emit("notes", compiles_in_window=ses.compiles.count,
-             traced_steps=len(traced), **notes)
+             traced_steps=len(traced), **notes, **stops,
+             ramp_stops_over_50ms=ramp_stops["stops_over_50ms"],
+             ramp_stop_longest_ms=ramp_stops["stop_longest_ms"],
+             waiting_at_start=(win["steps"][0]["waiting"]
+                               if win["steps"] else None))
     e2e["setup_s"] = setup_s
-    correct = (ses.check["ok"] and ses.compiles.count == 0
+    reduced = tracer.reduce("chipbench.step")
+    peak = memory_peak_bytes(cell["chips"])
+    ses.release_engine()
+    check = ses.judge()
+    ctx.emit("check", **check)
+    compared = {"logit_gap": [check["logit_gap"], check["logit_gap_tol"]],
+                "compiles_in_window": [ses.compiles.count, 0]}
+    correct = (check["ok"] and ses.compiles.count == 0
                and summary["attempted"] > 0 and notes["tokens_in_window"] > 0)
+    if "served" in cell["check"]:
+        sample = served_sample(
+            [ramp, win], win["t0"], win["t1"], cell["check"]["served"][
+                "requests"], np.random.default_rng([ctx.seed, 4]))
+        t = time.perf_counter()
+        answers = spec["output_tokens"]
+        served = served_check(ses.family, ses.sizes, ses.weights, sample,
+                              cell["check"]["served"],
+                              int(answers.get("max") or answers["value"]),
+                              ctx.control)
+        ctx.emit("served_check", seconds=time.perf_counter() - t, **served)
+        compared["served_gap"] = [served["served_gap"],
+                                  served["served_gap_tol"]]
+        correct = correct and served["ok"]
     return Result(
         correct=bool(correct), attempted=summary["attempted"],
         failed=summary["failed"],
         end_to_end={k: (v, UNITS[k]) for k, v in e2e.items()},
         steps=win["steps"], traced_steps=traced, requests=summary["records"],
-        first_steps=summary["first_steps"],
-        reduced=tracer.reduce("chipbench.step"), config=ses.sizes, cell=cell,
-        device_kind=ctx.device_kind)
+        first_steps=summary["first_steps"], reduced=reduced,
+        config=ses.sizes, cell=cell, device_kind=ctx.device_kind,
+        compared=compared, memory_peak_bytes=peak)

@@ -15,9 +15,9 @@ import time
 from statistics import median
 from typing import Dict, List
 
-from .. import model_math, reference, traffic
-from .common import (CompileCounter, Context, Result, Slice, build_model,
-                     model_sizes, sized)
+from .. import model_math, traffic
+from .common import (CompileCounter, Context, Heartbeat, Result, Slice,
+                     family, memory_peak_bytes, model_sizes, sized)
 
 UNITS = {"train_tokens_per_s": "tokens/s", "setup_s": "s"}
 
@@ -28,7 +28,6 @@ def run(ctx: Context) -> Result:
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.jit.api import TrainStep
-    from paddle_tpu.models import LlamaPretrainingCriterion
     parts: Dict[str, float] = {}
     t = time.perf_counter()
     parts["imports"] = t - ctx.t_process
@@ -36,9 +35,10 @@ def run(ctx: Context) -> Result:
     sizes = model_sizes(config)
     cell = sized(ctx.cell, ctx.rehearse)
     spec, opts = cell["traffic"], config["train"]
-    model, cfg, weights = build_model(sizes, ctx.seed, opts)
+    fam = family(config)
+    model, cfg, weights = fam.build_model(sizes, ctx.seed, opts)
     model.train()
-    crit = LlamaPretrainingCriterion(cfg)
+    crit = fam.build_criterion(cfg)
     if opts["optimizer"] != "AdamW":
         raise ValueError(f"unknown optimizer {opts['optimizer']!r}")
     opt = paddle.optimizer.AdamW(
@@ -65,8 +65,8 @@ def run(ctx: Context) -> Result:
     # the reference first: the first step donates the weights it reads
     t = time.perf_counter()
     first = fetch()
-    ref_losses = [reference.loss(sizes, weights, row,
-                                 cell["check"]["reference_query_block"])
+    ref_losses = [fam.loss(sizes, weights, row,
+                           cell["check"]["reference_query_block"])
                   for row in jax.device_get(first._data)]
     ref_loss = sum(ref_losses) / len(ref_losses)
     del weights
@@ -84,6 +84,7 @@ def run(ctx: Context) -> Result:
     steps: List[Dict] = []
     losses = []
     compiles.armed = True
+    heart = Heartbeat().start(ctx.heartbeat)
     while True:
         now = time.perf_counter()
         if now >= t_window + ctx.seconds:
@@ -94,6 +95,7 @@ def run(ctx: Context) -> Result:
         losses.append(step(ids))
         steps.append({"t_begin": t_begin, "t_end": time.perf_counter()})
     t_done = time.perf_counter()
+    stops = heart.stop()
     compiles.armed = False
     tracer.finish()
 
@@ -121,7 +123,7 @@ def run(ctx: Context) -> Result:
             model_math.peaks(ctx.device_kind)["bf16_flops_per_s"])
     traced = [s for s in steps if tracer.covers(s["t_begin"], s["t_end"])]
     notes["traced_steps"] = len(traced)
-    ctx.emit("notes", **notes)
+    ctx.emit("notes", **notes, **stops)
     return Result(
         correct=bool(check["ok"] and compiles.count == 0 and steps),
         attempted=len(steps),
@@ -130,4 +132,10 @@ def run(ctx: Context) -> Result:
                     "setup_s": (setup_s, UNITS["setup_s"])},
         steps=steps, traced_steps=traced,
         reduced=tracer.reduce("chipbench.train_step"), config=sizes,
-        cell=cell, device_kind=ctx.device_kind)
+        cell=cell, device_kind=ctx.device_kind,
+        memory_peak_bytes=memory_peak_bytes(cell["chips"]),
+        compared={"loss_rel_diff": [rel, cell["check"]["loss_rel_tol"]],
+                  "losses_not_finite": [
+                      sum(not math.isfinite(x)
+                          for x in losses + [first_loss]), 0],
+                  "compiles_in_window": [compiles.count, 0]})

@@ -1,7 +1,8 @@
 """The ragged paged-attention kernel's share of the HBM roofline in the
 traced steps: the bytes it must move (each row's live context of K and V,
-the queries and the outputs, per layer and step; ``model_math``) over the
-chip's bandwidth, divided by the device time of the kernel's events."""
+the queries and the outputs, per attention layer and step; ``model_math``)
+over the chip's bandwidth, divided by the device time of the kernel's
+events."""
 
 LAYER = "kernels (ops/kernels/pallas)"
 UNIT = "%"
@@ -21,7 +22,7 @@ def compute(run):
     kernel_s = sum(s for name, s in run.reduced.ops if name in KERNEL_NAMES)
     if kernel_s <= 0:
         return None
-    layers = run.config["num_hidden_layers"]
+    layers = model_math.attention_layers(run.config)
     need = sum(model_math.ragged_attention_bytes(
         run.config, s["live_context"], s["tokens"]) for s in run.traced_steps)
     least_s = layers * need / model_math.peaks(

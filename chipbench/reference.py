@@ -12,11 +12,17 @@ used, so that the reference fits beside a model that fills the chip.
 Departure from a textbook forward, for memory only: attention and the head
 run over blocks of ``query_block`` query positions against the whole
 sequence's keys, which changes no value.
+
+``precision`` is for the control of the benchmark's check and for nothing
+else: the same forward with both operands of every matrix product, and the
+cached keys and values, rounded to a lower precision first (``bf16``,
+``int8`` or ``fp8``), as a later PR's faster path would. ``None`` is the
+reference.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +73,22 @@ def make_weights(cfg: Dict, seed: int, dtype) -> Dict[str, jax.Array]:
     return jax.jit(build)(jax.random.key(seed % (2 ** 31 - 1)))
 
 
+def _lower(x, axis: int, precision):
+    """``x`` rounded to ``precision`` and raised to float32 again. int8 and
+    fp8 are scaled so that the largest magnitude along ``axis`` (a row of
+    activations, a column of weights, one head's key) just fits: 127, or
+    float8_e4m3's 448."""
+    if precision is None:
+        return x
+    if precision == "bf16":
+        return x.astype(jnp.bfloat16).astype(F32)
+    top = {"int8": 127.0, "fp8": 448.0}[precision]
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis, keepdims=True), 1e-30) / top
+    if precision == "int8":
+        return jnp.round(x / scale) * scale
+    return (x / scale).astype(jnp.float8_e4m3fn).astype(F32) * scale
+
+
 def _rms_norm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * w.astype(F32)
@@ -102,31 +124,34 @@ def _attention(q, k, v, query_block):
     return jnp.concatenate(outs, 0)
 
 
-def _layer(cfg, x, w, prefix, positions, query_block):
+def _layer(cfg, x, w, prefix, positions, query_block, precision=None):
     heads = cfg["num_attention_heads"]
     kv_heads = cfg["num_key_value_heads"]
     d = cfg["hidden_size"] // heads
     s = x.shape[0]
 
-    def mat(name):
-        return w[prefix + name].astype(F32)
+    def times(a, name):
+        return _lower(a, -1, precision) @ _lower(
+            w[prefix + name].astype(F32), 0, precision)
 
     h = _rms_norm(x, w[prefix + "input_layernorm.weight"], cfg["rms_norm_eps"])
-    q = (h @ mat("self_attn.q_proj.weight")).reshape(s, heads, d)
-    k = (h @ mat("self_attn.k_proj.weight")).reshape(s, kv_heads, d)
-    v = (h @ mat("self_attn.v_proj.weight")).reshape(s, kv_heads, d)
+    q = times(h, "self_attn.q_proj.weight").reshape(s, heads, d)
+    k = times(h, "self_attn.k_proj.weight").reshape(s, kv_heads, d)
+    v = times(h, "self_attn.v_proj.weight").reshape(s, kv_heads, d)
     q = _rotary(q, positions, cfg["rope_theta"])
-    k = _rotary(k, positions, cfg["rope_theta"])
+    k = _lower(_rotary(k, positions, cfg["rope_theta"]), -1, precision)
+    v = _lower(v, -1, precision)
     a = _attention(q, k, v, query_block).reshape(s, heads * d)
-    x = x + a @ mat("self_attn.o_proj.weight")
+    x = x + times(a, "self_attn.o_proj.weight")
     h = _rms_norm(x, w[prefix + "post_attention_layernorm.weight"],
                   cfg["rms_norm_eps"])
-    gate = jax.nn.silu(h @ mat("mlp.gate_proj.weight"))
-    return x + (gate * (h @ mat("mlp.up_proj.weight"))) \
-        @ mat("mlp.down_proj.weight")
+    gate = jax.nn.silu(times(h, "mlp.gate_proj.weight"))
+    return x + times(gate * times(h, "mlp.up_proj.weight"),
+                     "mlp.down_proj.weight")
 
 
-def hidden_states(cfg: Dict, weights: Dict, ids, query_block: int = 1024):
+def hidden_states(cfg: Dict, weights: Dict, ids, query_block: int = 1024,
+                  precision=None):
     """Final-norm hidden states [s, hidden] of one sequence ``ids`` [s].
     One jitted call per layer, so only one layer's float32 copies live."""
     ids = jnp.asarray(ids, jnp.int32)
@@ -134,13 +159,13 @@ def hidden_states(cfg: Dict, weights: Dict, ids, query_block: int = 1024):
     with jax.default_matmul_precision("highest"):
         x = jax.jit(lambda e, i: e[i].astype(F32))(
             weights["llama.embed_tokens.weight"], ids)
-        layer = jax.jit(_layer, static_argnums=(0, 3, 5))
+        layer = jax.jit(_layer, static_argnums=(0, 3, 5, 6))
         frozen = _Frozen(cfg)
         for i in range(cfg["num_hidden_layers"]):
             p = f"llama.layers.{i}."
             x = layer(frozen, x, {k: v for k, v in weights.items()
                                   if k.startswith(p)}, p, positions,
-                      query_block)
+                      query_block, precision)
         return jax.jit(_rms_norm, static_argnums=2)(
             x, weights["llama.norm.weight"], cfg["rms_norm_eps"])
 
@@ -152,12 +177,20 @@ class _Frozen(dict):
         return hash(tuple(sorted((k, repr(v)) for k, v in self.items())))
 
 
-def logits(cfg: Dict, weights: Dict, ids, query_block: int = 1024):
-    """Float32 logits [s, vocab] of one sequence."""
-    x = hidden_states(cfg, weights, ids, query_block)
+def _head(x, w, start, count, precision):
+    rows = jax.lax.dynamic_slice_in_dim(x, start, count)
+    return _lower(rows, -1, precision) @ _lower(w.astype(F32), 0, precision)
+
+
+def logits(cfg: Dict, weights: Dict, ids, query_block: int = 1024,
+           rows: Optional[Tuple[int, int]] = None, precision=None):
+    """Float32 logits [s, vocab] of one sequence, or of ``rows[1]``
+    positions from ``rows[0]`` on alone (one program whatever the start)."""
+    x = hidden_states(cfg, weights, ids, query_block, precision)
+    start, count = rows if rows is not None else (0, x.shape[0])
     with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda x, w: x @ w.astype(F32))(
-            x, weights["lm_head.weight"])
+        return jax.jit(_head, static_argnums=(3, 4))(
+            x, weights["lm_head.weight"], start, count, precision)
 
 
 def loss(cfg: Dict, weights: Dict, ids, query_block: int = 1024) -> float:

@@ -72,7 +72,8 @@ def set_compile_cache() -> str:
 
 
 def open_cell(workload: str, seed: int, seconds: float, trace: bool,
-              rehearse: bool, t_process: float):
+              rehearse: bool, t_process: float, heartbeat: bool = True,
+              control: bool = False):
     """The cell's files, the compile cache, the device check and the run's
     context. Returns (context, device) or, where the device will not do,
     (None, exit code)."""
@@ -104,7 +105,8 @@ def open_cell(workload: str, seed: int, seconds: float, trace: bool,
                   seconds=seconds, trace=trace, rehearse=rehearse,
                   t_process=t_process,
                   scratch=os.path.join(CHECKOUT, ".chipbench_tmp"),
-                  device_kind=dev.device_kind)
+                  device_kind=dev.device_kind, heartbeat=heartbeat,
+                  control=control)
     ctx.emit("start", device=device, workload=workload, seed=seed,
              seconds=seconds, trace=int(trace), rehearse=rehearse,
              config=cell["config"], compile_cache=cache_dir,
@@ -119,21 +121,25 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=50.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--heartbeat", type=int, choices=(0, 1), default=1,
+                    help="0 runs the window without the heartbeat thread, "
+                         "to show what it costs; the driver never passes it")
+    ap.add_argument("--control", action="store_true",
+                    help="the check also reads its control, the reference "
+                         "in a lower precision; the driver never passes it")
     args = ap.parse_args(argv)
     ctx, device = open_cell(args.workload, args.seed, args.seconds,
-                            bool(args.trace), args.rehearse, _T_PROCESS)
+                            bool(args.trace), args.rehearse, _T_PROCESS,
+                            bool(args.heartbeat), args.control)
     if ctx is None:
         return device
     cell = ctx.cell
 
-    import jax
     from . import trace
     driver = importlib.import_module(f"chipbench.drivers.{cell['driver']}")
     result = driver.run(ctx)
 
-    device["memory_peak_bytes"] = max(
-        (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-        for d in jax.devices()[:cell["chips"]])
+    device["memory_peak_bytes"] = result.memory_peak_bytes
     if args.trace:
         metrics = {}
         readers = layer_metric_files(cell["driver"], cell["end_to_end"])
@@ -153,7 +159,13 @@ def main(argv=None) -> int:
         device["busy_s"] = result.reduced.busy_s
         device["window_s"] = result.reduced.window_s
         line["breakdown"] = trace.breakdown(result.reduced)
+    # every number ``correct`` compared beside its limit: the line's last
+    # key, and the last lines of standard error
+    line["compared"] = result.compared
     print(json.dumps(line), flush=True)
+    for name, (number, limit) in result.compared.items():
+        print(f"chipbench compared {name} {number!r} limit {limit!r}",
+              file=sys.stderr, flush=True)
     return 0
 
 

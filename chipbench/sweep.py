@@ -38,18 +38,22 @@ def main(argv=None) -> int:
     if ctx.cell["driver"] != "serve":
         raise SystemExit("a sweep is over a serving cell's rate")
     from .drivers import serve
+    from .drivers.common import Heartbeat
     ses = serve.Session(ctx)
-    ctx.emit("check", **ses.check)
     rates = [float(r) for r in args.rates.split(",")]
     base = ses.cell["traffic"]
     before = [ses.phase({**base, "rate_per_s": rates[0]},
                         float(base.get("ramp_s", 0)), phase_seed=2)]
     for k, rate in enumerate(rates):
+        heart = Heartbeat().start()
         win = ses.phase({**base, "rate_per_s": rate}, args.seconds,
                         phase_seed=3 + k)
+        stops = heart.stop()
         notes, _, _ = serve.report(ses, before, win)
-        ctx.emit("rate", device=device, **notes)
+        ctx.emit("rate", device=device, **notes, **stops)
         before.append(win)
+    ses.release_engine()
+    ctx.emit("check", **ses.judge())
     return 0
 
 

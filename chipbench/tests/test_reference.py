@@ -21,7 +21,7 @@ def test_logits_and_loss_agree_with_the_program():
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.models import LlamaPretrainingCriterion
     from chipbench import reference
-    from chipbench.drivers.common import build_model
+    from chipbench.families.llama import build_model
     sizes = tiny_sizes()
     model, cfg, weights = build_model(sizes, seed=2 ** 31 + 5)
     model.eval()

@@ -10,7 +10,8 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
+STOPS = {"stops_over_50ms", "stop_longest_ms", "stop_sum_ms", "heartbeats"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -30,6 +31,19 @@ def test_last_line_has_the_contracts_keys(cell, trace):
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert set(line) == KEYS          # no device trace on the CPU
+    # what ``correct`` compared, each number beside its limit: the line's
+    # last key and the last lines of standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    said = done.stderr.strip().splitlines()[-len(line["compared"]):]
+    for (name, (number, limit)), text in zip(line["compared"].items(), said):
+        assert text.split()[:3] == ["chipbench", "compared", name]
+        assert number <= limit
+    notes = [json.loads(x) for x in done.stdout.splitlines()
+             if x.startswith('{"event": "notes"')][0]
+    assert STOPS <= set(notes) and notes["heartbeats"] > 100
+    if cell.startswith("serve"):
+        assert {"itl_p50_ms", "itl_p99_ms",
+                "itl_over_half_budget_share"} <= set(notes)
     assert set(line["device"]) == DEVICE_KEYS
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True and line["failed"] == 0
