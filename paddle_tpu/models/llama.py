@@ -29,7 +29,7 @@ from ..ops.dispatcher import call_op
 from .. import nn
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
-from .generation import GenerationMixin
+from .generation import GenerationMixin, enters_step_program
 from ..distributed.topology import get_hybrid_communicate_group as _get_hcg
 
 
@@ -141,7 +141,10 @@ class LlamaAttention(Layer):
     (ops/kernels/pallas/tp_attention.py) — heads ride 'mp', batch rides
     'dp', and the only mp collective in the block stays o_proj's psum.
     Non-divisible head counts (e.g. kv_heads < tp) fall back to the XLA
-    composite with the reason in the flight recorder."""
+    composite with the reason in the flight recorder.
+
+    A config whose ``rope_theta`` is None has no positions in its
+    attention (models/jamba.py): no table is built and no rope op runs."""
 
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -154,8 +157,10 @@ class LlamaAttention(Layer):
         self.k_proj = _linear(h, self.num_kv_heads * self.head_dim, col=True)
         self.v_proj = _linear(h, self.num_kv_heads * self.head_dim, col=True)
         self.o_proj = _linear(self.num_heads * self.head_dim, h, col=False)
-        self.rotary = LlamaRotaryEmbedding(
-            self.head_dim, config.max_position_embeddings, config.rope_theta)
+        self.rotary = None if config.rope_theta is None else \
+            LlamaRotaryEmbedding(self.head_dim,
+                                 config.max_position_embeddings,
+                                 config.rope_theta)
 
     def forward(self, x, attn_mask=None, position_ids=None, cache=None,
                 start_pos=None, layer_idx=0):
@@ -170,25 +175,28 @@ class LlamaAttention(Layer):
             # every slot decodes at its own depth, models/serving.py) or a
             # [b, s] PER-TOKEN matrix (ragged mixed prefill+decode: the
             # packed token axis carries every row's chunk at its own depth)
-            if getattr(start_pos, "ndim", 0) == 2:
-                pos_ids = start_pos
-            elif getattr(start_pos, "ndim", 0) == 1:
-                pos_ids = (start_pos.reshape([b, 1])
-                           + call_op("arange", end=s, dtype="int32")
-                           .reshape([1, s]))
-            else:
-                pos_ids = (call_op("arange", end=s, dtype="int32")
-                           + start_pos).reshape([1, s]).broadcast_to([b, s])
-            cos, sin = self.rotary(self.config.max_position_embeddings)
-            q, k = call_op("rope", q, k, cos=cos, sin=sin,
-                           position_ids=pos_ids)
+            if self.rotary is not None:
+                if getattr(start_pos, "ndim", 0) == 2:
+                    pos_ids = start_pos
+                elif getattr(start_pos, "ndim", 0) == 1:
+                    pos_ids = (start_pos.reshape([b, 1])
+                               + call_op("arange", end=s, dtype="int32")
+                               .reshape([1, s]))
+                else:
+                    pos_ids = (call_op("arange", end=s, dtype="int32")
+                               + start_pos).reshape([1, s]) \
+                        .broadcast_to([b, s])
+                cos, sin = self.rotary(self.config.max_position_embeddings)
+                q, k = call_op("rope", q, k, cos=cos, sin=sin,
+                               position_ids=pos_ids)
             cache.update(layer_idx, k, v, start_pos)
             out = cache.attend(layer_idx, q, start_pos, attn_mask)
             out = out.reshape([b, s, self.num_heads * self.head_dim])
             return self.o_proj(out)
-        cos, sin = self.rotary(s)
-        q, k = call_op("rope", q, k, cos=cos, sin=sin,
-                       position_ids=position_ids)
+        if self.rotary is not None:
+            cos, sin = self.rotary(s)
+            q, k = call_op("rope", q, k, cos=cos, sin=sin,
+                           position_ids=position_ids)
         hcg = _get_hcg()
         if hcg is not None and hcg.get_sep_parallel_world_size() > 1:
             # context parallelism: seq dim sharded over sep, ring attention
@@ -314,13 +322,9 @@ class LlamaForCausalLM(Layer, GenerationMixin):
                 self.lm_head = _linear(config.hidden_size, config.vocab_size,
                                        col=True, gather_output=True)
 
+    @enters_step_program
     def forward(self, input_ids, attn_mask=None, position_ids=None,
                 cache=None, start_pos=None):
-        program = getattr(cache, "program", None)
-        if program is not None:
-            # a ragged serving step (models/serving.py): this forward, traced
-            # once over the view, is the engine's one XLA program
-            return program(self, input_ids, start_pos, cache)
         hidden = self.llama(input_ids, attn_mask, position_ids,
                             cache=cache, start_pos=start_pos)
         if self.lm_head is None:  # tied: logits = h @ E^T

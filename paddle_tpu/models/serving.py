@@ -65,7 +65,8 @@ from ..observability import perf as _perf_mod
 from ..observability import tracing as _tracing
 from ..ops.dispatcher import call_op
 from ..ops.kernels.pallas import ragged_paged_attention as _rpa
-from .generation import PagedKVCache, kv_pool_blocks
+from .generation import (PagedKV, PagedKVCache, kv_pool_blocks,
+                         layer_states)
 
 __all__ = ["Request", "ContinuousBatchingEngine", "PrefixCache",
            "QueueFull"]
@@ -156,6 +157,18 @@ _M_SPEC_REJ = _M.counter(
     "serving.spec.rejected", "draft tokens rejected at verify")
 _M_SPEC_ROWS = _M.counter(
     "serving.spec.verify_rows", "decode rows that carried draft tokens")
+_M_STATE_BYTES = _M.gauge(
+    "serving.state.bytes",
+    "resident bytes of the row-state cache (layers that keep a fixed-size "
+    "state a row; 0 for a model without any)")
+_M_STATE_RESETS = _M.counter(
+    "serving.state.resets",
+    "segments that started at position 0, so from a zero row state "
+    "(admission, resume after preemption, a reused row slot)")
+_M_PC_SKIPPED = _M.counter(
+    "serving.prefix.skipped_recurrent",
+    "prefix-cache lookups not taken because the model keeps row state: a "
+    "recurrent layer must see every token, so no block can stand in")
 
 # per-tenant children of the admission counters, cached so the hot path
 # pays one dict hit instead of the registry lock. Tenant cardinality is
@@ -293,9 +306,13 @@ class _RaggedView:
 
     The view the engine hands to the model names the engine's
     `_StepProgram`: the model's forward runs as that one XLA program,
-    which owns the pools for the call. ``update`` and ``attend`` run only
-    inside the program's trace, on a view over tracers (``program`` None);
-    a ragged step has no per-op path."""
+    which owns the pools for the call. ``update``, ``attend`` and the
+    row-state methods run only inside the program's trace, on a view over
+    tracers (``program`` None); a ragged step has no per-op path.
+
+    A layer that keeps row state (`generation.RowState`) reads the step's
+    ``segments`` and its own arrays through ``row_state`` and hands the
+    updated ones back through ``set_row_state``."""
 
     def __init__(self, cache: PagedKVCache, slots: Tensor, tables: Tensor,
                  lens: Tensor, cu: Tensor,
@@ -306,6 +323,7 @@ class _RaggedView:
         self._lens = lens
         self._cu = cu
         self.program = program
+        self._segments = None
 
     def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
         return self._c.write(layer, k_new, v_new, self._slots)
@@ -313,13 +331,34 @@ class _RaggedView:
     def attend(self, layer: int, q: Tensor, pos=None, attn_mask=None):
         b, s, h, d = q.shape
         out = call_op("ragged_paged_attention", q.reshape([s, h, d]),
-                      self._c.k[layer], self._c.v[layer],
+                      *self._c.kv(layer),
                       self._tables, self._lens, self._cu,
                       **self._c.scale_kwargs(layer))
         return out.reshape([b, s, h, d])
 
+    def segments(self) -> Tuple[Tensor, Tensor, Tensor]:
+        """The step's rows as a recurrent layer needs them: ``cu_q_lens``,
+        each row's index into the row-state cache (its own; the cache's
+        last for a row with no token in the step) and the position of each
+        row's first token (0: the segment starts from a zero state). Made
+        inside the program from what every step uploads already."""
+        if self._segments is None:
+            cu, lens = self._cu._data, self._lens._data
+            qlen = cu[1:] - cu[:-1]
+            rows = qlen.shape[0]
+            slots = jnp.where(qlen > 0, jnp.arange(rows, dtype=jnp.int32),
+                              rows)
+            self._segments = (self._cu, Tensor(slots), Tensor(lens - qlen))
+        return self._segments
 
-# model -> {(flags.version, pool names): its `_ModelProgram`}: engines over
+    def row_state(self, layer: int, name: str) -> Tensor:
+        return self._c.row(layer, name)
+
+    def set_row_state(self, layer: int, name: str, value: Tensor) -> None:
+        self._c.set_row(layer, name, value)
+
+
+# model -> {(flags.version, cache spec): its `_ModelProgram`}: engines over
 # one model (the replicas of a fleet, a relaunch, a warm-up engine) share
 # the traced program and its executables, one for each geometry they ask
 # for. Keyed on flags.version like the dispatcher's cache, so a store
@@ -370,7 +409,7 @@ class _ModelProgram:
             return exe
 
 
-def _step_program(model, pool_names: Tuple[str, ...]) -> _ModelProgram:
+def _step_program(model, spec: Tuple) -> _ModelProgram:
     """The ragged step's model call as ONE XLA program:
 
         (parameters and buffers, the pools, ids, pos, slots, tables, lens,
@@ -378,12 +417,13 @@ def _step_program(model, pool_names: Tuple[str, ...]) -> _ModelProgram:
 
     Built by tracing the model's own forward (its ops run inline on
     tracers through the dispatcher) over a `_RaggedView` of tracers, with
-    every pool array donated and returned: the pool writes scatter in
-    place, and one launch replaces the forward's per-op launches."""
+    every pool and row-state array (``spec``: the cache's) donated and
+    returned: the pool writes scatter in place, and one launch replaces
+    the forward's per-op launches."""
     from .. import flags
     from ..autograd.engine import no_grad
     programs = _STEP_PROGRAMS.setdefault(model, {})
-    key = (flags.version, pool_names)
+    key = (flags.version, spec)
     if key in programs:
         return programs[key]
     params, buffers = _collect_state(model)
@@ -393,7 +433,7 @@ def _step_program(model, pool_names: Tuple[str, ...]) -> _ModelProgram:
     def serving_step(state_arrays, pools, ids, pos, slots, tables, lens, cu):
         _M_TRACES.inc()
         before = _M_LAUNCHES.value
-        over = PagedKVCache.over(pool_names, pools)
+        over = PagedKVCache.over(spec, pools)
         view = _RaggedView(over, Tensor(slots), Tensor(tables),
                            Tensor(lens), Tensor(cu))
         with _swap_state(state, list(state_arrays)), no_grad():
@@ -457,7 +497,7 @@ class _StepProgram:
 
     def __call__(self, model, ids: Tensor, pos: Tensor,
                  view: _RaggedView) -> Tensor:
-        program = _step_program(model, self._cache.pool_names)
+        program = _step_program(model, self._cache.spec)
         args = self._args(program.state, ids, pos, view)
         if self.cold:
             # every geometry before the first step returns: a server's
@@ -478,7 +518,7 @@ class _StepProgram:
         """The program lowered for ``args`` (arrays or their shapes, as
         `_args` orders them); nothing runs and nothing is donated.
         ``lower(...).compile()`` has its text, cost and memory analysis."""
-        return _step_program(model, self._cache.pool_names).jit.lower(*args)
+        return _step_program(model, self._cache.spec).jit.lower(*args)
 
     def compiled(self, slots: int):
         """The executable of the ``slots``-slot geometry, with its text,
@@ -529,6 +569,10 @@ class ContinuousBatchingEngine:
                              top_p=top_p)
         if kv_dtype is None:
             kv_dtype = _flags.get_flag("kv_cache_dtype")
+        # what each layer keeps between a row's tokens, as the model
+        # declares it: paged K and V, or a fixed-size state a row
+        layers = layer_states(model)
+        paged = [l for l in layers if isinstance(l, PagedKV)]
         if num_blocks is None:
             # pool sized in BYTES: the admission math below is all in
             # blocks, so the storage regime's capacity win (int8 buys
@@ -537,19 +581,24 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     "pass num_blocks or kv_pool_bytes to size the pool")
             num_blocks = kv_pool_blocks(
-                kv_pool_bytes, block_size, cfg.num_key_value_heads,
-                cfg.hidden_size // cfg.num_attention_heads,
-                cfg.num_hidden_layers,
+                kv_pool_bytes, block_size, paged[0].num_kv_heads,
+                paged[0].head_dim, len(paged),
                 dtype=getattr(cfg, "dtype", "float32"), kv_dtype=kv_dtype)
         mb = max_blocks_per_seq or (
             -(-cfg.max_position_embeddings // block_size))
         self.cache = PagedKVCache(
-            cfg.num_hidden_layers, max_batch, num_blocks=num_blocks,
-            block_size=block_size, num_kv_heads=cfg.num_key_value_heads,
-            head_dim=cfg.hidden_size // cfg.num_attention_heads,
-            max_blocks_per_seq=mb, dtype=getattr(cfg, "dtype", "float32"),
-            kv_dtype=kv_dtype)
+            len(layers), max_batch, num_blocks=num_blocks,
+            block_size=block_size, max_blocks_per_seq=mb,
+            dtype=getattr(cfg, "dtype", "float32"), kv_dtype=kv_dtype,
+            layers=layers)
         _M_KV_BPT.set(self.cache.kv_bytes_per_token())
+        _M_STATE_BYTES.set(self.cache.row_state_bytes())
+        # a model with row state changes three of the scheduler's rules,
+        # each because a recurrent layer must see every token in order: no
+        # prefix-cache hit is taken, a preempted row re-prefills from
+        # position 0 (where the kernels start from a zero state), and a
+        # draft cannot be taken back out of a recurrence
+        self.recurrent = bool(self.cache.row_state)
         # speculative decoding: K draft tokens per decode row, verified
         # as one q_len=K+1 ragged row out of the leftover token budget.
         # Acceptance is EXACT-MATCH against the row's keyed sample at
@@ -559,6 +608,11 @@ class ContinuousBatchingEngine:
         if speculative_k is None:
             speculative_k = int(_flags.get_flag("speculative_k"))
         self.spec_k = max(0, int(speculative_k))
+        if self.spec_k and self.recurrent:
+            raise ValueError(
+                f"speculative_k={self.spec_k} with a model that keeps row "
+                f"state: a rejected draft token cannot be taken back out "
+                f"of a recurrent layer's state")
         if self.spec_k and draft_proposer is None:
             from .speculative import NGramProposer
             draft_proposer = NGramProposer()
@@ -736,7 +790,7 @@ class ContinuousBatchingEngine:
         # the [NB,BS,KV] scale pools ride the same one-block scatter
         # executable). Each pool is read here, after the last step
         # rebound it, and replaced by the write's result
-        for pool in self.cache.pool_lists():
+        for pool in self.cache.paged_lists():
             for layer in range(self.cache.num_layers):
                 rows = Tensor(pool[layer]._data[blk][None])  # [1,BS,...]
                 pool[layer] = call_op("paged_cache_write", pool[layer],
@@ -765,8 +819,11 @@ class ContinuousBatchingEngine:
                                                np.int32)])
                     if req.out_tokens else req.prompt)
             target = len(full)
-            hits = (self._pc.lookup(req.block_hashes)
-                    if self.enable_prefix_cache else [])
+            hits = []
+            if self.enable_prefix_cache and self.recurrent:
+                _M_PC_SKIPPED.inc()
+            elif self.enable_prefix_cache:
+                hits = self._pc.lookup(req.block_hashes)
             # never share the whole target: the last token must be
             # recomputed so its logits exist to sample from (and a
             # resumed row needs a well-formed write position)
@@ -852,7 +909,7 @@ class ContinuousBatchingEngine:
     def _register_blocks(self, req: Request, i: int, new_ctx: int):
         """Publish freshly-completed FULL prompt blocks to the prefix
         cache (never the recomputed tail of a resumed request)."""
-        if not self.enable_prefix_cache:
+        if not self.enable_prefix_cache or self.recurrent:
             return
         hi = min(new_ctx, len(req.prompt)) // self.block_size
         for bi in range(req._registered_upto, hi):
@@ -1054,6 +1111,14 @@ class ContinuousBatchingEngine:
                     t += n
             cu = np.zeros((R + 1,), np.int32)
             np.cumsum(qlen, out=cu[1:])
+            step_attrs = {}
+            if self.recurrent:
+                # every row with a token has its state updated, and one
+                # whose first token is at position 0 starts from zeros
+                live = qlen > 0
+                _M_STATE_RESETS.inc(int((live & (lens == qlen)).sum()))
+                step_attrs = {"state_rows": int(live.sum()),
+                              "scan_tokens": t}
             # what one layer's attention call walks: its tiles' live kv
             # blocks, beside the tile x table-column pairs of the whole grid
             kv_tile_blocks = _rpa.live_tile_blocks(qlen, lens, bs)
@@ -1136,7 +1201,8 @@ class ContinuousBatchingEngine:
                            "prefill_rows": len(prefill_rows),
                            "launches": launches,
                            "kv_tile_blocks": kv_tile_blocks,
-                           "kv_table_blocks": kv_table_blocks})
+                           "kv_table_blocks": kv_table_blocks,
+                           **step_attrs})
             if self.cache.quantized:
                 # every attended block is dequantized in-tile each step:
                 # bandwidth accounting for the int8 pool (per layer, per row)

@@ -202,6 +202,115 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, block_tables,
                              v_scale=v_scale)
 
 
+def _token_rows(tokens, cu_q_lens, slots, start_pos, padding_slot):
+    """For each packed token of a ragged step: its row's index into the
+    row-state cache (``padding_slot`` for step padding, whose tokens lie
+    past ``cu[R]``), whether its row's state starts from zeros at it (the
+    first token of a segment at position 0), and whether it belongs to a
+    row at all."""
+    cu = cu_q_lens.astype(jnp.int32)
+    tok = jnp.arange(tokens, dtype=jnp.int32)
+    row = jnp.clip(jnp.searchsorted(cu, tok, side="right")
+                   .astype(jnp.int32) - 1, 0, cu.shape[0] - 2)
+    real = tok < cu[-1]
+    slot = jnp.where(real, slots.astype(jnp.int32)[row], padding_slot)
+    fresh = real & (tok == cu[row]) & (start_pos[row] == 0)
+    return slot, fresh, real
+
+
+def _scan_composite(x, dt, B, C, z, A_log, D, cu_q_lens, slots, start_pos,
+                    state):
+    """XLA composite of the ragged selective scan: one `lax.scan` over the
+    packed tokens that reads and writes the owning row's state at every
+    token. Step padding reads and writes the cache's last row."""
+    shape = state.shape
+    flat = state.reshape(shape[0], shape[1], -1)           # [S, N, D]
+    slot, fresh, real = _token_rows(x.shape[0], cu_q_lens, slots, start_pos,
+                                    shape[0] - 1)
+    a = -jnp.exp(A_log.astype(jnp.float32))                # [N, D]
+    f32 = lambda v: v.astype(jnp.float32)
+
+    def token(flat, t):
+        slot_t, fresh_t, x_t, dt_t, b_t, c_t = t
+        delta = jax.nn.softplus(dt_t)
+        s = jnp.where(fresh_t, 0.0, flat[slot_t])
+        s = jnp.exp(delta[None, :] * a) * s \
+            + (delta * x_t)[None, :] * b_t[:, None]
+        return flat.at[slot_t].set(s), jnp.sum(s * c_t[:, None], 0)
+
+    flat, y = jax.lax.scan(
+        token, flat, (slot, fresh, f32(x), f32(dt), f32(B), f32(C)))
+    y = (y + f32(D) * f32(x)) * jax.nn.silu(f32(z))
+    y = jnp.where(real[:, None], y, 0.0).astype(x.dtype)
+    return y, flat.reshape(shape)
+
+
+def _conv_composite(x, weight, bias, cu_q_lens, slots, start_pos, tail):
+    """XLA composite of the ragged causal convolution: a `lax.scan` over
+    the packed tokens that shifts the owning row's tail at every token."""
+    shape = tail.shape
+    flat = tail.reshape(shape[0], shape[1], -1)            # [S, K-1, D]
+    slot, fresh, real = _token_rows(x.shape[0], cu_q_lens, slots, start_pos,
+                                    shape[0] - 1)
+    w = weight.astype(jnp.float32)
+
+    def token(flat, t):
+        slot_t, fresh_t, x_t = t
+        before = jnp.where(fresh_t, 0.0, flat[slot_t].astype(jnp.float32))
+        window = jnp.concatenate([before, x_t[None]])      # [K, D]
+        return (flat.at[slot_t].set(window[1:].astype(flat.dtype)),
+                jnp.sum(window * w, 0))
+
+    flat, y = jax.lax.scan(token, flat,
+                           (slot, fresh, x.astype(jnp.float32)))
+    y = jax.nn.silu(y + bias.astype(jnp.float32))
+    y = jnp.where(real[:, None], y, 0.0).astype(x.dtype)
+    return y, flat.reshape(shape)
+
+
+def _row_state_pallas(d_inner):
+    """The Pallas module of the two row-state ops where it takes the width
+    and FLAGS_use_pallas_kernels is on; None for the composites."""
+    from ... import flags
+    from .pallas import ragged_selective_scan as rss
+    if rss.supported(d_inner) and flags.get_flag("use_pallas_kernels"):
+        return rss
+    return None
+
+
+@register_kernel("ragged_selective_scan")
+def ragged_selective_scan_kernel(x, dt, B, C, z, A_log, D, cu_q_lens, slots,
+                                 start_pos, state):
+    """The selective scan of a Mamba-1 layer over a ragged step, each
+    row's segment continuing from the row's own state.
+
+    x, dt, z[T, D] packed over rows by cu_q_lens[R+1] (x after the
+    convolution and SiLU, dt before its softplus); B, C[T, N];
+    A_log[N, D]; D[D]; state[S, N, D/128, 128] float32, row r's at
+    slots[r]; start_pos[R] the position of each row's first token, a
+    segment at position 0 starting from zeros. Returns y * silu(z) and
+    the state, the rows that had tokens updated: s = exp(delta A) s +
+    delta x B, y = s C + D x, delta = softplus(dt), A = -exp(A_log).
+    The Pallas kernel (pallas/ragged_selective_scan.py) or the XLA
+    composite, chosen as ragged_paged_attention chooses."""
+    rss = _row_state_pallas(x.shape[1])
+    fn = rss.ragged_selective_scan if rss else _scan_composite
+    return fn(x, dt, B, C, z, A_log, D, cu_q_lens, slots, start_pos, state)
+
+
+@register_kernel("ragged_causal_conv")
+def ragged_causal_conv_kernel(x, weight, bias, cu_q_lens, slots, start_pos,
+                              tail):
+    """The depthwise causal convolution (and SiLU) of a Mamba-1 layer over
+    a ragged step: x[T, D], weight[K, D], bias[D], tail[S, K-1, D/128,
+    128] each row's last K-1 inputs at slots[r], zeros before a segment
+    at position 0. Returns silu(conv(x)) and the tail, the rows that had
+    tokens updated."""
+    rss = _row_state_pallas(x.shape[1])
+    fn = rss.ragged_causal_conv if rss else _conv_composite
+    return fn(x, weight, bias, cu_q_lens, slots, start_pos, tail)
+
+
 def _filter_logits(logits, temperature, top_k, top_p):
     """Temperature/top-k/top-p filtering shared by both sampling heads."""
     logits = logits.astype(jnp.float32) / max(temperature, 1e-6)

@@ -191,57 +191,76 @@ def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):
     assert _custom_call_names(text) == {"ragged_paged_attention"}
 
 
-def _serving_step_text(one_chip):
-    """The engine's step program at chipbench's `mistral-7b-v0.3-serve`,
-    in both of its geometries (slots -> compiled text): Mistral-7B-v0.3
-    widths, 8 layers, 64 rows, 512 or 256 token slots and 16 bf16 pools of
-    4096 blocks, lowered from shapes. The parameters stay the zeros
-    `LazyGuard` puts in host memory (4 GB), never initialized."""
+def _step_texts(one_chip, make_model, rows, blocks, width):
+    """The engine's step program of ``make_model()`` in both geometries of
+    a 512-token budget (slots -> compiled text), lowered from shapes:
+    ``rows`` rows, pools of ``blocks`` blocks of 64, tables ``width`` wide.
+    The parameters stay the zeros `LazyGuard` puts in host memory, never
+    initialized. Returns (texts, parameters and buffers, pool arrays)."""
     import paddle_tpu as paddle
     from paddle_tpu.jit.api import _collect_state
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.models.generation import PagedKVCache
+    from paddle_tpu.models.generation import PagedKVCache, layer_states
     from paddle_tpu.models.serving import _StepProgram, _geometries
 
-    def build():
-        layers, rows, blocks, width = 8, 64, 4096, 512
-        geometries = _geometries(512, rows, 0)
-        assert geometries == (256, 512)
-        cfg = LlamaConfig(
+    geometries = _geometries(512, rows, 0)
+    assert geometries == (256, 512)
+    with paddle.LazyGuard():
+        model = make_model()
+    model.eval()
+    for _, sub, _ in model._walk(""):
+        sub.__dict__.pop("_has_lazy", None)
+        for p in sub._parameters.values():
+            if p is not None and hasattr(p, "_lazy_spec"):
+                del p._lazy_spec
+    # the cache gives the program its geometry; the pools (and the
+    # row-state arrays) it is lowered for are the cell's, as shapes
+    layers = layer_states(model)
+    cache = PagedKVCache(len(layers), 1, num_blocks=2, block_size=64,
+                         max_blocks_per_seq=width, dtype="bfloat16",
+                         layers=layers)
+    params, buffers = _collect_state(model)
+    state = tuple(_sds(one_chip, t._data.shape, t._data.dtype)
+                  for t in params + buffers)
+    paged = sum(len(l) for l in cache.paged_lists())
+    pools = tuple(
+        _sds(one_chip, ((blocks,) if i < paged else (rows + 1,))
+             + a.shape[1:], a.dtype)
+        for i, a in enumerate(cache.pools()))
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    program = _StepProgram(cache, geometries)
+    texts = {n: program.lower(model, (
+        state, pools, i32(1, n), i32(1, n), i32(n),
+        i32(rows, width), i32(rows), i32(rows + 1))).compile().as_text()
+        for n in geometries}
+    return texts, len(state), len(pools)
+
+
+def _aliased(text):
+    """The parameter numbers that the compiled text aliases to outputs."""
+    head = text[:text.index("\n")]
+    alias = head[head.index("input_output_alias={"):
+                 head.index("entry_computation_layout")]
+    return {int(m) for m in re.findall(r"\((\d+), \{\}", alias)}
+
+
+def _serving_step_text(one_chip):
+    """The engine's step program at chipbench's `mistral-7b-v0.3-serve`:
+    Mistral-7B-v0.3 widths, 8 layers, 64 rows and 16 bf16 pools of 4096
+    blocks (4 GB of parameters in host memory)."""
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    def make():
+        return LlamaForCausalLM(LlamaConfig(
             vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-            num_hidden_layers=layers, num_attention_heads=32,
+            num_hidden_layers=8, num_attention_heads=32,
             num_key_value_heads=8, max_position_embeddings=32768,
-            rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16")
-        with paddle.LazyGuard():
-            model = LlamaForCausalLM(cfg)
-        model.eval()
-        for _, sub, _ in model._walk(""):
-            sub.__dict__.pop("_has_lazy", None)
-            for p in sub._parameters.values():
-                if p is not None and hasattr(p, "_lazy_spec"):
-                    del p._lazy_spec
-        # the cache gives the program its geometry; the pools it is
-        # lowered for are the cell's, as shapes
-        cache = PagedKVCache(layers, rows, num_blocks=2, block_size=64,
-                             num_kv_heads=8, head_dim=D,
-                             max_blocks_per_seq=width, dtype="bfloat16")
-        params, buffers = _collect_state(model)
-        state = tuple(_sds(one_chip, t._data.shape, t._data.dtype)
-                      for t in params + buffers)
-        pools = tuple(_sds(one_chip, (blocks,) + a.shape[1:], a.dtype)
-                      for a in cache.pools())
+            rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16"))
 
-        def i32(*shape):
-            return _sds(one_chip, shape, jnp.int32)
-
-        program = _StepProgram(cache, geometries)
-        texts = {n: program.lower(model, (
-            state, pools, i32(1, n), i32(1, n), i32(n),
-            i32(rows, width), i32(rows), i32(rows + 1))).compile().as_text()
-            for n in geometries}
-        return texts, len(state), len(pools)
-
-    return _compiled("serving_step", build)
+    return _compiled("serving_step", lambda: _step_texts(
+        one_chip, make, rows=64, blocks=4096, width=512))
 
 
 @pytest.mark.parametrize("slots", [512, 256], ids=["budget", "half"])
@@ -254,14 +273,43 @@ def test_serving_step_owns_its_pools(mosaic, one_chip, slots):
     texts, n_state, n_pools = _serving_step_text(one_chip)
     text = texts[slots]
     assert n_pools == 16
-    head = text[:text.index("\n")]
-    alias = head[head.index("input_output_alias={"):
-                 head.index("entry_computation_layout")]
-    aliased = {int(m) for m in re.findall(r"\((\d+), \{\}", alias)}
-    assert aliased == set(range(n_state, n_state + n_pools)), alias
+    assert _aliased(text) == set(range(n_state, n_state + n_pools))
     assert not re.search(r"= bf16\[4096,64,8,128\]\S* copy\(", text)
     assert _custom_call_names(text) == {"ragged_paged_attention"}
     assert text.count(CUSTOM_CALL) >= 8          # one call a layer
+
+
+def _jamba_step_text(one_chip):
+    """The step program at chipbench's `jamba2-3b-serve`: AI21-Jamba2-3B as
+    published, all 28 layers (6 GB of parameters in host memory), 256
+    rows, 2 x 2 pools of 8448 blocks and 26 x 2 row-state arrays."""
+    from paddle_tpu.models.jamba import JambaConfig, JambaForCausalLM
+
+    return _compiled("jamba_step", lambda: _step_texts(
+        one_chip, lambda: JambaForCausalLM(JambaConfig(dtype="bfloat16")),
+        rows=256, blocks=8448, width=32))
+
+
+@pytest.mark.parametrize("slots", [512, 256], ids=["budget", "half"])
+def test_jamba_step_owns_its_pools_and_row_state(mosaic, one_chip, slots):
+    # ISSUE 34: two kinds of state in one program. The 2 attention layers'
+    # pools and the 26 Mamba layers' convolution tails and SSM states are
+    # all donated and written in place: a copy of one SSM array would move
+    # 84 MB, of all of them 2.2 GB a step. Mosaic takes the scan's and the
+    # convolution's row walk (state tiles copied in and out of HBM by the
+    # kernel) and the ragged kernel at 20 query heads on 1 KV head, whose
+    # pool is float32 because a bfloat16 one cannot be copied by the block
+    texts, n_state, n_pools = _jamba_step_text(one_chip)
+    text = texts[slots]
+    assert n_pools == 2 + 2 + 26 + 26
+    assert _aliased(text) == set(range(n_state, n_state + n_pools))
+    for shape in (r"f32\[257,16,40,128\]", r"bf16\[257,3,40,128\]",
+                  r"f32\[8448,64,1,128\]"):
+        assert not re.search(rf"= {shape}\S* copy\(", text), shape
+    assert _custom_call_names(text) == {
+        "ragged_paged_attention", "ragged_selective_scan",
+        "ragged_causal_conv"}
+    assert text.count(CUSTOM_CALL) == 2 + 26 + 26
 
 
 def _fused_adamw_text(one_chip):
