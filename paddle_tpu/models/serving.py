@@ -15,6 +15,13 @@ chunked prefill and prefix caching:
   both on the engine's first step, and every mix of prefill/decode
   replays one of the two. Long prompts prefill in chunks interleaved
   with everyone else's decode tokens.
+- **One step in flight.** A call of `step()` launches step N+1 from the
+  host's picture of the rows and only then waits for step N's sampled
+  tokens, so scheduling, packing and the uploads run while the device is
+  busy. A decoding row's next id is gathered inside the step program from
+  the samples of the launch before, the host's commit runs one step
+  behind, and whatever reads or rewinds a row (preemption, copy-on-write)
+  or has nothing left to launch commits the step in flight first.
 - **Token-budget admission.** Requests queue until a row slot AND enough
   pool blocks for their worst case (prompt + max_new_tokens, minus the
   prefix-cached head) are free — the vLLM reservation rule, so decode
@@ -103,6 +110,19 @@ _M_STEP_SLOTS = _M.counter(
     "serving.step_slots",
     "token slots of the geometries the steps ran (step_tokens over this "
     "is the share of the model's rows that carried a token)")
+_M_OVERLAPPED = _M.counter(
+    "serving.pipeline.overlapped",
+    "steps launched while the step before them was still in flight (over "
+    "serving.steps: the share of steps whose host work hid behind the device)")
+_M_DRAINS = _M.counter(
+    "serving.pipeline.drains",
+    "times the step in flight was committed with none launched behind it: "
+    "speculation (the drafts need the committed tokens), before a "
+    "preemption or a copy-on-write, and when no row had work left")
+_M_DISCARDED = _M.counter(
+    "serving.pipeline.discarded_tokens",
+    "tokens sampled for a row whose request had ended (an EOS) while the "
+    "row's next step was already launched; never emitted")
 _M_GEN_TOKENS = _M.counter(
     "serving.generated_tokens", "tokens sampled and emitted to requests")
 _M_PREFILL_TOKENS = _M.counter(
@@ -199,7 +219,9 @@ class Request:
     admit_order: int = -1              # LIFO preemption victim choice
     preemptions: int = 0
     # -- ragged-engine occupancy state (reset on preemption) ---------------
-    ctx: int = 0                       # tokens written to the pool
+    ctx: int = 0                       # tokens whose pool writes were launched
+    in_flight: int = 0                 # tokens sampled by a launched step, not
+                                       # yet committed to out_tokens
     target: int = 0                    # prefill target length
     full_seq: Optional[np.ndarray] = None
     block_hashes: List[bytes] = field(default_factory=list)
@@ -219,6 +241,27 @@ class Request:
     t_arrive_ns: int = 0
     t_admit_ns: int = 0
     t_first_ns: int = 0
+
+
+def _fold_in_host(key: np.ndarray, data: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.fold_in(key, data))`` for a
+    threefry2x32 key given as its two words, computed on the host. The
+    device's version is a program and a transfer: issued from
+    `add_request` it would wait for whatever step is in flight, and the
+    device then idles through the next launch."""
+    with np.errstate(over="ignore"):
+        k0, k1 = np.uint32(key[0]), np.uint32(key[1])
+        ks = (k0, k1, k0 ^ k1 ^ np.uint32(0x1BD11BDA))
+        x0, x1 = ks[0], np.uint32(data) + ks[1]   # the count is (0, data)
+        rounds = ((13, 15, 26, 6), (17, 29, 16, 24))
+        for i in range(5):
+            for r in rounds[i % 2]:
+                x0 = x0 + x1
+                x1 = (x1 << np.uint32(r)) | (x1 >> np.uint32(32 - r))
+                x1 = x1 ^ x0
+            x0 = x0 + ks[(i + 1) % 3]
+            x1 = x1 + ks[(i + 2) % 3] + np.uint32(i + 1)
+    return np.array([x0, x1], np.uint32)
 
 
 def _req_trace(req: "Request"):
@@ -312,16 +355,24 @@ class _RaggedView:
 
     A layer that keeps row state (`generation.RowState`) reads the step's
     ``segments`` and its own arrays through ``row_state`` and hands the
-    updated ones back through ``set_row_state``."""
+    updated ones back through ``set_row_state``.
+
+    ``prev`` and ``src`` ride along for the program alone: the tokens the
+    launch before this one sampled, still on the device, and for each
+    packed token the lane of ``prev`` its id comes from (-1: the id the
+    host packed stands)."""
 
     def __init__(self, cache: PagedKVCache, slots: Tensor, tables: Tensor,
                  lens: Tensor, cu: Tensor,
-                 program: Optional["_StepProgram"] = None):
+                 program: Optional["_StepProgram"] = None,
+                 prev: Optional[Tensor] = None, src: Optional[Tensor] = None):
         self._c = cache
         self._slots = slots
         self._tables = tables
         self._lens = lens
         self._cu = cu
+        self._prev = prev
+        self._src = src
         self.program = program
         self._segments = None
 
@@ -413,13 +464,16 @@ def _step_program(model, spec: Tuple) -> _ModelProgram:
     """The ragged step's model call as ONE XLA program:
 
         (parameters and buffers, the pools, ids, pos, slots, tables, lens,
-         cu)  ->  (logits [1, slots, V], the same pools)
+         cu, prev, src)  ->  (logits [1, slots, V], the same pools)
 
     Built by tracing the model's own forward (its ops run inline on
     tracers through the dispatcher) over a `_RaggedView` of tracers, with
     every pool and row-state array (``spec``: the cache's) donated and
     returned: the pool writes scatter in place, and one launch replaces
-    the forward's per-op launches."""
+    the forward's per-op launches. A packed token whose ``src`` is not
+    negative takes its id from that lane of ``prev``, the tokens the
+    launch before sampled: a decoding row's next id never visits the host
+    before it is fed."""
     from .. import flags
     from ..autograd.engine import no_grad
     programs = _STEP_PROGRAMS.setdefault(model, {})
@@ -430,9 +484,11 @@ def _step_program(model, spec: Tuple) -> _ModelProgram:
     state = params + buffers
     model_ref = weakref.ref(model)
 
-    def serving_step(state_arrays, pools, ids, pos, slots, tables, lens, cu):
+    def serving_step(state_arrays, pools, ids, pos, slots, tables, lens, cu,
+                     prev, src):
         _M_TRACES.inc()
         before = _M_LAUNCHES.value
+        ids = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], ids)
         over = PagedKVCache.over(spec, pools)
         view = _RaggedView(over, Tensor(slots), Tensor(tables),
                            Tensor(lens), Tensor(cu))
@@ -493,7 +549,8 @@ class _StepProgram:
             state = tuple(t._data for t in state)
         return (state, self._cache.pools(),
                 ids._data, pos._data, view._slots._data, view._tables._data,
-                view._lens._data, view._cu._data)
+                view._lens._data, view._cu._data, view._prev._data,
+                view._src._data)
 
     def __call__(self, model, ids: Tensor, pos: Tensor,
                  view: _RaggedView) -> Tensor:
@@ -502,13 +559,13 @@ class _StepProgram:
         if self.cold:
             # every geometry before the first step returns: a server's
             # first 300-token prompt must not be the one that compiles
-            state, pools, ids_a, pos_a, slots_a, *rows = jax.tree.map(
+            state, pools, ids_a, pos_a, slots_a, *rows, src_a = jax.tree.map(
                 _aval, args)
             for n in self._geometries:
                 self._executables[n] = program.executable((
                     state, pools, ids_a.update(shape=(1, n)),
                     pos_a.update(shape=(1, n)), slots_a.update(shape=(n,)),
-                    *rows))
+                    *rows, src_a.update(shape=(n,))))
         _M_LAUNCHES.inc()
         logits, pools = self._executables[ids.shape[1]](*args)
         self._cache.set_pools(pools)
@@ -524,6 +581,25 @@ class _StepProgram:
         """The executable of the ``slots``-slot geometry, with its text,
         cost and memory analysis; None before the first call."""
         return self._executables.get(slots)
+
+
+# kinds of entry in a launched step's commit plan: a prefill chunk, the chunk
+# that ends a prompt and samples its first token, a decode or verify row
+_CHUNK, _FIRST, _DECODE = range(3)
+
+
+@dataclass(slots=True)
+class _Launched:
+    """A ragged step that was enqueued and not yet committed."""
+    nxt: Tensor                 # sampled tokens: on the device until commit
+    post: List[Tuple]           # the commit plan: `_CHUNK` / `_FIRST` /
+    #                             `_DECODE` entries naming their requests
+    drafts: Dict[int, np.ndarray]   # what its verify rows carried
+    dequant_blocks: int         # attended blocks of an int8 pool, a layer
+    t0_ns: Optional[int]        # dispatch began
+    td_ns: Optional[int]        # its last launch returned
+    perf_entry: Any             # the perf ledger's row, and whether this
+    perf_sample: Optional[bool]     # call's device time is sampled
 
 
 class ContinuousBatchingEngine:
@@ -636,7 +712,17 @@ class ContinuousBatchingEngine:
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.pending: deque[Request] = deque()
         self.results: Dict[int, Request] = {}
-        self.tok = np.zeros((max_batch,), np.int32)
+        # one step in flight: the launch whose tokens the host has not
+        # read yet (`_Launched`; None when the pipeline is empty), the
+        # tokens the last launch sampled, which the next one's decoding
+        # rows take their ids from on the device (zeros of the same shape
+        # before any launch, so the first call makes the executables every
+        # later call runs), and the requests finished by commits that
+        # `step()` has not returned yet
+        self._inflight: Optional[_Launched] = None
+        self._prev = jnp.zeros((max_batch * (self.spec_k + 1),), jnp.int32)
+        self._ready_ns = 0
+        self._finished: List[Request] = []
         self._next_rid = 0
         self._admit_seq = 0
         self.steps = 0
@@ -651,8 +737,9 @@ class ContinuousBatchingEngine:
         # threefry keys: rbg draws depend on the vmap row position (see
         # sample_logits_keyed), which would leak the slot assignment back
         # into the output
-        self._base_key = jax.random.key(seed, impl="threefry2x32")
-        self._key_w = np.asarray(jax.random.key_data(self._base_key)).shape[-1]
+        self._base_key_data = np.asarray(jax.random.key_data(
+            jax.random.key(seed, impl="threefry2x32")))
+        self._key_w = self._base_key_data.shape[-1]
         self.seed = seed
         # bounded intake (None = legacy unbounded) + finished hand-off:
         # with `on_finish` set, completed Requests are passed to the
@@ -737,8 +824,7 @@ class ContinuousBatchingEngine:
                                (bi + 1) * self.block_size].tobytes()
             ).digest()
             req.block_hashes.append(h)
-        req.key_data = np.asarray(jax.random.key_data(
-            jax.random.fold_in(self._base_key, rid)))
+        req.key_data = _fold_in_host(self._base_key_data, rid)
         self.pending.append(req)
         self.results[rid] = req
         return rid
@@ -867,7 +953,7 @@ class ContinuousBatchingEngine:
                                - n_use))
             # n_use is capped at (target-1)//block_size, so ctx < target
             # here always: every admission prefills at least one token
-            # (a resumed request re-enters decode via step()'s post loop)
+            # (a resumed request then decodes from its last emitted token)
 
     # -- lifecycle -----------------------------------------------------------
     @property
@@ -883,13 +969,15 @@ class ContinuousBatchingEngine:
         self.cache.block_tables[i, :] = 0
         self.cache._allocated[i] = 0
         self.slots[i] = None
-        self.tok[i] = 0
 
     def _preempt_lifo(self):
         """Evict the most-recently-admitted sequence (vLLM's default
         victim): reclaim its blocks now, requeue it right behind the
         starved head for recompute-on-resume (its private sampling
-        stream makes the resumed output identical)."""
+        stream makes the resumed output identical). The step in flight
+        still moves the victim's row, and its tokens belong to the prefix
+        the victim resumes from: it is committed first."""
+        self._drain()
         victim = max((r for r in self.slots if r is not None),
                      key=lambda r: r.admit_order, default=None)
         if victim is None:
@@ -934,7 +1022,6 @@ class ContinuousBatchingEngine:
                 trace=_req_trace(req), attrs={"rid": req.rid})
             _tracing.instant("serving.first_token", trace=_req_trace(req),
                              attrs={"rid": req.rid})
-        self.tok[i] = tok
         if (len(req.out_tokens) >= req.max_new_tokens
                 or (self.eos is not None and tok == self.eos)):
             req.done = True
@@ -963,15 +1050,43 @@ class ContinuousBatchingEngine:
 
     # -- the ragged step -----------------------------------------------------
     def step(self) -> List[Request]:
-        """Admit, then run ONE ragged mixed prefill+decode batch: a token
-        for every decoding row plus prefill chunks up to the token
-        budget, in a single compiled model invocation. Returns the
-        requests that finished during this step."""
-        from ..autograd.engine import no_grad
+        """One call of the serving loop: LAUNCH the next ragged step, then
+        COMMIT the one the call before launched, so the host's scheduling,
+        packing and uploads run while the device is still busy with the
+        step before.
 
-        # the host's phases of this step, each a live span on the thread's
-        # timeline (untraced: one ragged step serves many requests) and,
-        # under a jax.profiler trace, an annotation on the device's clock
+        *Launch*: admit, then pack ONE mixed prefill+decode batch (a token
+        for every decoding row plus prefill chunks up to the token budget)
+        and enqueue its single compiled model invocation and its sampling.
+        The host's picture moves here: ``req.ctx`` by what was packed, and
+        ``req.in_flight`` counts the sampled token the host has not seen; a
+        decoding row's next id is taken on the device from the tokens the
+        previous launch sampled. A row whose last token is in flight is not
+        packed again.
+
+        *Commit*: wait for a launched step's tokens and hand them to their
+        requests (``out_tokens``, first-token and finish times, the row's
+        release, the prefix cache's new blocks). A request that ended
+        unforeseen (``eos_token_id``) may have been launched once more: that
+        token is dropped and counted.
+
+        The step in flight is committed in the call that launched it (the
+        pipeline *drains*) when nothing could be launched behind it: with
+        speculation on (the drafts and the accepted count need the committed
+        tokens, so every call is launch-then-commit of the same step), and
+        when no row has a chunk or a token left to schedule, so a loop that
+        steps until its requests are done sees the last one finish in the
+        call that launched its last step. It is committed ahead of the
+        launch where the host is about to read or rewind a row: before a
+        preemption and before a copy-on-write.
+
+        Returns the requests that finished in the steps this call
+        committed."""
+        # the host's phases, each a live span on the thread's timeline
+        # (untraced: one ragged step serves many requests) and, under a
+        # jax.profiler trace, an annotation on the device's clock: admit,
+        # schedule, pack and dispatch of the step this call launches, sync
+        # and commit of what it commits. All six carry the call's number
         n_step = self.steps + 1
         with _tracing.start_span("serving.step.admit",
                                  trace=_tracing.UNTRACED,
@@ -989,8 +1104,60 @@ class ContinuousBatchingEngine:
             _M_BACKLOG.set(sum(r.target - r.ctx for r in self.slots
                                if r is not None and r.ctx < r.target))
             _M_FREE.set(self._free_effective())
-        if self.num_active == 0:
-            return []
+        if self._inflight is not None and self._copy_due():
+            self._drain(n_step)
+        if self._work_left():
+            launched = self._launch(n_step)
+            due = []
+            if self._inflight is not None:
+                _M_OVERLAPPED.inc()
+                due.append(self._inflight)
+            self._inflight = launched
+            if self.spec_k or not self._work_left():
+                _M_DRAINS.inc()
+                due.append(launched)
+                self._inflight = None
+            self._settle(due, n_step)
+        if self._inflight is not None and not self._work_left():
+            # no launch will come to carry the step in flight out: an EOS
+            # ended the last row that had work
+            self._drain(n_step)
+        finished, self._finished = self._finished, []
+        return finished
+
+    def _schedulable(self, req: Request) -> bool:
+        """A chunk of the prompt or a token left to launch. The token count
+        is the launched one: a row whose last token is in flight has none."""
+        return (req.ctx < req.target or
+                len(req.out_tokens) + req.in_flight < req.max_new_tokens)
+
+    def _work_left(self) -> bool:
+        return any(r is not None and self._schedulable(r)
+                   for r in self.slots)
+
+    def _copy_due(self) -> bool:
+        """Whether the next pack would copy a block before it writes
+        (`_ensure_writable`): a row about to append into a block the
+        prefix cache tracks."""
+        if not len(self._pc):
+            return False
+        bs, tables = self.block_size, self.cache.block_tables
+        return any(r is not None and r.ctx % bs and self._schedulable(r)
+                   and self._pc.tracked(int(tables[i, r.ctx // bs]))
+                   for i, r in enumerate(self.slots))
+
+    def _drain(self, n_step: Optional[int] = None) -> None:
+        """Commit the step in flight, if any, with none launched behind it
+        (``n_step``: the call it happens in; the next one's by default)."""
+        if self._inflight is not None:
+            launched, self._inflight = self._inflight, None
+            _M_DRAINS.inc()
+            self._settle([launched], n_step or self.steps + 1)
+
+    def _launch(self, n_step: int) -> "_Launched":
+        """Schedule, pack and enqueue one ragged step from the host's
+        picture of the rows; returns what its commit needs."""
+        from ..autograd.engine import no_grad
 
         with _tracing.start_span("serving.step.schedule",
                                  trace=_tracing.UNTRACED,
@@ -999,7 +1166,8 @@ class ContinuousBatchingEngine:
             # fixed-size prefill chunks, round-robin by admission order, into
             # the budget left after every decoding row's token
             decode_rows = [i for i, r in enumerate(self.slots)
-                           if r is not None and r.ctx >= r.target]
+                           if r is not None and r.ctx >= r.target
+                           and self._schedulable(r)]
             prefill_rows = sorted(
                 (i for i, r in enumerate(self.slots)
                  if r is not None and r.ctx < r.target),
@@ -1062,6 +1230,9 @@ class ContinuousBatchingEngine:
             # spec off L=1 and the arrays are exactly the legacy geometry.
             L = self.spec_k + 1
             ids = np.zeros((T,), np.int32)
+            # the lane of the previous launch's samples a token's id comes
+            # from, on the device; -1 where the host's id stands
+            src = np.full((T,), -1, np.int32)
             pos = np.zeros((T,), np.int32)
             # int32 on the host: handed over as int64 the upload would
             # convert on the device, in a program of its own a geometry
@@ -1071,16 +1242,26 @@ class ContinuousBatchingEngine:
             sample_idx = np.zeros((R * L,), np.int32)
             stream_pos = np.zeros((R * L,), np.int32)
             keys = np.zeros((R * L, self._key_w), np.uint32)
-            post = []                      # (row, is_decode, n) commit plan
+            # the commit plan: (row, request, tokens packed, ctx after them,
+            # kind). The request and not only its row: by the commit the
+            # row may be another's
+            post = []
+            dequant_blocks = 0
             t = 0
             for i in range(R):
                 req = self.slots[i]
                 if req is None:
                     continue
                 if req.ctx >= req.target:           # decode / verify row
+                    emitted = len(req.out_tokens) + req.in_flight
+                    if emitted >= req.max_new_tokens:
+                        continue            # its last token is in flight
                     d = drafts.get(i)
                     n = 1 + (0 if d is None else len(d))
-                    ids[t] = self.tok[i]
+                    if req.in_flight:
+                        src[t] = i * L      # the step in flight samples it
+                    else:
+                        ids[t] = req.out_tokens[-1]
                     if n > 1:
                         ids[t + 1:t + n] = d
                     pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
@@ -1089,11 +1270,13 @@ class ContinuousBatchingEngine:
                     lens[i] = req.ctx + n
                     sample_idx[i * L:(i + 1) * L] = t   # spare lanes: dup t
                     sample_idx[i * L:i * L + n] = np.arange(t, t + n)
-                    stream_pos[i * L:i * L + n] = (len(req.out_tokens)
-                                                   + np.arange(n))
+                    stream_pos[i * L:i * L + n] = emitted + np.arange(n)
                     keys[i * L:(i + 1) * L] = req.key_data
-                    post.append((i, True, n))
-                    t += n
+                    # the token every such row emits; a verify row's commit
+                    # adds the drafts it accepted
+                    req.ctx += 1
+                    req.in_flight += 1
+                    post.append((i, req, n, req.ctx, _DECODE))
                 else:                                           # prefill chunk
                     n = grants.get(i, 0)
                     lens[i] = req.ctx + n
@@ -1103,12 +1286,19 @@ class ContinuousBatchingEngine:
                     pos[t:t + n] = np.arange(req.ctx, req.ctx + n)
                     slot_vec[t:t + n] = self._write_slots(i, req.ctx, n)
                     qlen[i] = n
+                    kind = _CHUNK
                     if req.ctx + n == req.target and not req.out_tokens:
                         sample_idx[i * L] = t + n - 1  # first tok: last logits
                         stream_pos[i * L] = 0
                         keys[i * L] = req.key_data
-                    post.append((i, False, n))
-                    t += n
+                        req.in_flight += 1
+                        kind = _FIRST
+                    # (a resumed row's first decode id is its last emitted
+                    # token, on the host already)
+                    req.ctx += n
+                    post.append((i, req, n, req.ctx, kind))
+                dequant_blocks += (int(lens[i]) + bs - 1) // bs
+                t += n
             cu = np.zeros((R + 1,), np.int32)
             np.cumsum(qlen, out=cu[1:])
             step_attrs = {}
@@ -1132,7 +1322,7 @@ class ContinuousBatchingEngine:
             # ledger row of the ragged step, one a geometry: the model call
             # is one executable (`_StepProgram`), whose cost analysis gives
             # the row its FLOPs and HBM bytes; gather and sampling are two
-            # small ops beside it. The host sync below makes the
+            # small ops beside it. The commit's host sync makes the
             # device-time sample free
             _pe = _p_sample = None
             if _perf_mod.enabled():
@@ -1147,10 +1337,15 @@ class ContinuousBatchingEngine:
             view = _RaggedView(
                 self.cache,
                 Tensor(jnp.asarray(slot_vec)),
-                Tensor(jnp.asarray(self.cache.block_tables, jnp.int32)),
+                # a snapshot: a commit may clear a row of the table
+                # (`_release_slot`) before this launch has read it, and an
+                # upload on the CPU may alias the host's memory
+                Tensor(jnp.asarray(
+                    self.cache.block_tables.astype(np.int32))),
                 Tensor(jnp.asarray(lens, jnp.int32)),
                 Tensor(jnp.asarray(cu, jnp.int32)),
-                program=self._program)
+                program=self._program,
+                prev=Tensor(self._prev), src=Tensor(jnp.asarray(src)))
             with no_grad():
                 logits = self.model(
                     Tensor(jnp.asarray(ids[None])), cache=view,
@@ -1170,86 +1365,46 @@ class ContinuousBatchingEngine:
                             self._sample(Tensor(jnp.zeros(
                                 (1, n) + tuple(logits.shape[2:]),
                                 logits._data.dtype)), *tail)
+            self._prev = nxt._data
             self.steps += 1
             _M_STEPS.inc()
             _M_STEP_TOKENS.inc(t)
             _M_STEP_SLOTS.inc(T)
+        # the phase's own edges: dispatch began, its last async launch
+        # returned (none with FLAGS_tracing off: the ledger row then counts
+        # its calls only)
+        t0_ns, td_ns = sp_dispatch.t0_ns, sp_dispatch.t1_ns
+        if td_ns is not None:
+            # retroactive, on the thread timeline, in the call that
+            # launched the step: the model call and its sampling, enqueued
+            _tracing.record_span(
+                "serving.step", t0_ns, td_ns,
+                attrs={"tokens": t, "slots": T,
+                       "decode_rows": len(decode_rows),
+                       "prefill_rows": len(prefill_rows),
+                       "launches": launches,
+                       "kv_tile_blocks": kv_tile_blocks,
+                       "kv_table_blocks": kv_table_blocks,
+                       **step_attrs})
+        return _Launched(nxt, post, drafts, dequant_blocks, t0_ns, td_ns,
+                         _pe, _p_sample)
+
+    def _settle(self, due: List["_Launched"], n_step: int) -> None:
+        """Sync and commit the launched steps ``due``, oldest first (none:
+        the call that starts a pipeline has nothing to commit, and records
+        both spans all the same)."""
         with _tracing.start_span("serving.step.sync",
                                  trace=_tracing.UNTRACED,
-                                 attrs={"step": n_step}) as sp_sync:
-            sampled = np.asarray(nxt._data).reshape(-1)
-
+                                 attrs={"step": n_step}):
+            # each step's tokens on the host, and when they were
+            sampled = [(np.asarray(s.nxt._data).reshape(-1),
+                        _tracing.now_ns()) for s in due]
         with _tracing.start_span("serving.step.commit",
                                  trace=_tracing.UNTRACED,
                                  attrs={"step": n_step}):
-            # the phases' own edges: dispatch began, its last async launch
-            # returned, the tokens were on the host (none with FLAGS_tracing
-            # off: the ledger row then counts its calls only)
-            t0_ns, td_ns = sp_dispatch.t0_ns, sp_dispatch.t1_ns
-            t1_ns = sp_sync.t1_ns
-            if td_ns is not None and t1_ns is not None:
-                if _pe is not None:
-                    _perf_mod.ledger().commit(
-                        _pe, (td_ns - t0_ns) / 1e9,
-                        (t1_ns - t0_ns) / 1e9 if _p_sample else None)
-                # retroactive, on the thread timeline: the model call
-                # through the host sync (dispatch + sync above)
-                _tracing.record_span(
-                    "serving.step", t0_ns, t1_ns,
-                    attrs={"tokens": t, "slots": T,
-                           "decode_rows": len(decode_rows),
-                           "prefill_rows": len(prefill_rows),
-                           "launches": launches,
-                           "kv_tile_blocks": kv_tile_blocks,
-                           "kv_table_blocks": kv_table_blocks,
-                           **step_attrs})
-            if self.cache.quantized:
-                # every attended block is dequantized in-tile each step:
-                # bandwidth accounting for the int8 pool (per layer, per row)
-                _M_KV_DEQ.inc(sum((int(lens[i]) + bs - 1) // bs
-                                  for i, _, _ in post)
-                              * self.cache.num_layers)
-            now = time.time()
             finished: List[Request] = []
-            for i, is_decode, n in post:
-                req = self.slots[i]
-                if is_decode:
-                    # exact-match verify: draft j is accepted iff it equals
-                    # the keyed sample at its stream position — so spec-on
-                    # output is byte-identical to spec-off at ANY temperature
-                    # (the samples themselves are the ground truth). Accepted
-                    # drafts validate the NEXT lane's logits; the first
-                    # mismatch invalidates everything after it.
-                    d = drafts.get(i)
-                    nd = n - 1
-                    base = i * L
-                    a = 0
-                    while a < nd and int(sampled[base + a]) == int(d[a]):
-                        a += 1
-                    if nd:
-                        _M_SPEC_PROP.inc(nd)
-                        _M_SPEC_ACC.inc(a)
-                        _M_SPEC_REJ.inc(nd - a)
-                        _M_SPEC_ROWS.inc()
-                    # rejected-draft KV rows (positions ctx+1+a..ctx+n-1) are
-                    # garbage: the row's length hides them and the next step
-                    # overwrites those slots in place
-                    req.ctx += 1 + a
-                    for j in range(a + 1):
-                        self._append_token(req, i, int(sampled[base + j]),
-                                           now, finished)
-                        if req.done:
-                            break
-                else:
-                    req.ctx += n
-                    _M_PREFILL_TOKENS.inc(n)
-                    self._register_blocks(req, i, req.ctx)
-                    if req.ctx == req.target:
-                        if req.out_tokens:  # resumed: next input pre-sampled
-                            self.tok[i] = req.out_tokens[-1]
-                        else:
-                            self._append_token(req, i, int(sampled[i * L]),
-                                               now, finished)
+            for launched, (tokens, ready_ns) in zip(due, sampled):
+                self._commit(launched, tokens, ready_ns, finished)
             if self.on_finish is not None:
                 for req in finished:
                     self.results.pop(req.rid, None)
@@ -1257,7 +1412,68 @@ class ContinuousBatchingEngine:
             if finished:
                 with self.finish_cv:
                     self.finish_cv.notify_all()
-        return finished
+            self._finished += finished
+
+    def _commit(self, launched: "_Launched", sampled: np.ndarray,
+                ready_ns: int, finished: List[Request]) -> None:
+        """Hand a launched step's sampled tokens to their requests."""
+        if launched.perf_entry is not None and launched.td_ns is not None:
+            # launch to tokens-on-host, less the time the step waited
+            # behind the one before it: the device-time estimate
+            began = max(launched.t0_ns, self._ready_ns)
+            _perf_mod.ledger().commit(
+                launched.perf_entry,
+                (launched.td_ns - launched.t0_ns) / 1e9,
+                (ready_ns - began) / 1e9 if launched.perf_sample else None)
+        self._ready_ns = ready_ns
+        if self.cache.quantized:
+            # every attended block is dequantized in-tile each step:
+            # bandwidth accounting for the int8 pool (per layer, per row)
+            _M_KV_DEQ.inc(launched.dequant_blocks * self.cache.num_layers)
+        L = self.spec_k + 1
+        now = time.time()
+        for i, req, n, ctx, kind in launched.post:
+            if req.done or self.slots[i] is not req:
+                # the request ended (an EOS) while this step was launched:
+                # its row was packed once more, and the token goes nowhere
+                if kind != _CHUNK:
+                    _M_DISCARDED.inc()
+                continue
+            if kind == _DECODE:
+                # exact-match verify: draft j is accepted iff it equals
+                # the keyed sample at its stream position — so spec-on
+                # output is byte-identical to spec-off at ANY temperature
+                # (the samples themselves are the ground truth). Accepted
+                # drafts validate the NEXT lane's logits; the first
+                # mismatch invalidates everything after it.
+                d = launched.drafts.get(i)
+                nd = n - 1
+                base = i * L
+                a = 0
+                while a < nd and int(sampled[base + a]) == int(d[a]):
+                    a += 1
+                if nd:
+                    _M_SPEC_PROP.inc(nd)
+                    _M_SPEC_ACC.inc(a)
+                    _M_SPEC_REJ.inc(nd - a)
+                    _M_SPEC_ROWS.inc()
+                # rejected-draft KV rows (positions ctx+a..ctx+n-2) are
+                # garbage: the row's length hides them and the next step
+                # overwrites those slots in place
+                req.ctx += a
+                req.in_flight -= 1
+                for j in range(a + 1):
+                    self._append_token(req, i, int(sampled[base + j]),
+                                       now, finished)
+                    if req.done:
+                        break
+            else:
+                _M_PREFILL_TOKENS.inc(n)
+                self._register_blocks(req, i, ctx)
+                if kind == _FIRST:
+                    req.in_flight -= 1
+                    self._append_token(req, i, int(sampled[i * L]),
+                                       now, finished)
 
     def _sample(self, logits: Tensor, sample_idx: Tensor, keys: Tensor,
                 stream_pos: Tensor) -> Tensor:

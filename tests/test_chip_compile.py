@@ -233,7 +233,8 @@ def _step_texts(one_chip, make_model, rows, blocks, width):
     program = _StepProgram(cache, geometries)
     texts = {n: program.lower(model, (
         state, pools, i32(1, n), i32(1, n), i32(n),
-        i32(rows, width), i32(rows), i32(rows + 1))).compile().as_text()
+        i32(rows, width), i32(rows), i32(rows + 1),
+        i32(rows), i32(n))).compile().as_text()    # prev, src (ISSUE 35)
         for n in geometries}
     return texts, len(state), len(pools)
 

@@ -215,15 +215,17 @@ def test_logits_at_the_tap_match_the_uncached_forward(model, kv_dtype, tol):
     rows, slots, ctx_before = [], [], 0
     while not req.done:
         eng.step()
-        n = req.ctx - ctx_before
+        n = req.ctx - ctx_before        # what this call's launch packed
         rows.append(np.asarray(tap.logits[-1][0, :n], np.float32))
         slots.append(tap.logits[-1].shape[1])
         ctx_before = req.ctx
     # three prefill chunks (the later two beside the other row's decode
     # token), then one token a step: the first two in the 12-slot program,
-    # every later step in the 6-slot one
-    assert [len(r) for r in rows] == [8, 11, 2, 1, 1, 1, 1, 1]
-    assert slots == [12, 12, 6, 6, 6, 6, 6, 6]
+    # every later step in the 6-slot one. The other row decodes on, so the
+    # request's last token is committed by the call after the one that
+    # launched it (ISSUE 35), which packs nothing of the request's
+    assert [len(r) for r in rows] == [8, 11, 2, 1, 1, 1, 1, 1, 0]
+    assert slots == [12, 12, 6, 6, 6, 6, 6, 6, 6]
     got = np.concatenate(rows)
     out = list(req.out_tokens)
     ids = np.asarray(prompt + out[:-1], np.int32)[None]
@@ -393,6 +395,121 @@ def test_a_stream_that_alternates_geometries_gives_the_oracles_tokens(
     assert [len(t) for t in got] == list(n_new)
     if kv_dtype == "auto":
         assert got == [_generate(model, p, n) for p, n in zip(prompts, n_new)]
+
+
+# -- one step in flight (ISSUE 35) ---------------------------------------------
+
+class _ViewTap(_Tap):
+    """A tap that also keeps the view each call was handed."""
+
+    def __init__(self, model):
+        super().__init__(model)
+        self.views = []
+
+    def __call__(self, *args, cache=None, **kwargs):
+        self.views.append(cache)
+        return super().__call__(*args, cache=cache, **kwargs)
+
+
+def test_a_request_alone_sees_one_model_call_a_step_and_ends_in_its_last(
+        model):
+    # what chipbench's warm-up leans on (`drivers/serve.py:_warm_up`): while
+    # a lone request has work every call makes exactly one model call,
+    # `req.ctx` grows by what that call packed, and the call that launches
+    # the last step returns the request finished
+    prompt, = _prompts(51, (21,))
+    tracing.clear()
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=12, prefill_chunk=8)
+    tap = _Tap(model)
+    eng.model = tap
+    rid = eng.add_request(prompt, max_new_tokens=4)
+    req = eng.results[rid]
+    packed, ctx_before, finished = [], 0, []
+    while not req.done:
+        assert finished == []
+        calls = len(tap.logits)
+        finished = eng.step()
+        assert len(tap.logits) == calls + 1
+        packed.append(req.ctx - ctx_before)
+        assert packed[-1] == _step_spans()[-1]["tokens"]
+        ctx_before = req.ctx
+    assert finished == [req] and eng._inflight is None
+    # the prompt in a 12-token step and a 9-token one (a lone row takes
+    # chunks until the budget is spent), then the three tokens fed back
+    assert packed == [12, 9, 1, 1, 1]
+    assert eng.steps == len(packed) == len(tap.logits)
+    assert req.out_tokens == _generate(model, prompt, 4)
+    assert eng.step() == []
+
+
+def test_a_launched_step_keeps_the_block_table_it_was_packed_with(model):
+    # commit clears a finished row's line of the long-lived host table while
+    # the next launch may not have read its upload yet, and an upload on the
+    # CPU may alias host memory: the launch takes a snapshot
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0)
+    # the CPU backend aliases a host array that starts on a 64-byte line,
+    # which numpy's own need not: give the table such a start
+    table = eng.cache.block_tables
+    raw = np.zeros(table.size + 16, table.dtype)
+    off = -raw.ctypes.data % 64 // table.itemsize
+    eng.cache.block_tables = raw[off:off + table.size].reshape(table.shape)
+    tap = _ViewTap(model)
+    eng.model = tap
+    eng.add_request(_prompts(52, (20,))[0], max_new_tokens=6)
+    eng.step()
+    assert eng._inflight is not None
+    packed_with = eng.cache.block_tables.copy()
+    assert packed_with.any()
+    eng.cache.block_tables[:] = 0
+    np.testing.assert_array_equal(
+        np.asarray(tap.views[-1]._tables._data), packed_with)
+    eng.cache.block_tables[:] = packed_with
+
+
+@SPEC
+def test_no_step_compiles_once_the_first_call_returned(model, compiles,
+                                                       spec_k):
+    # the program's two new inputs follow the geometry (`src`) or nothing
+    # (`prev`: zeros of the sampled tokens' shape before any launch, the
+    # last launch's device array after): both executables are the first
+    # call's, whichever `prev` a later step is handed
+    eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=64,
+                                   block_size=16, temperature=0.0,
+                                   token_budget=16, prefill_chunk=16,
+                                   speculative_k=spec_k)
+    tap = _ViewTap(model)
+    eng.model = tap
+    short, long_ = _prompts(53, (5, 14))
+    over0 = _metric("serving.pipeline.overlapped")
+    eng.add_request(short, max_new_tokens=6)
+    zeros = eng._prev
+    eng.step()                          # 8 slots, `prev` the zeros
+    assert tap.views[-1]._prev._data is zeros
+    compiles.count, compiles.armed = 0, True
+    try:
+        eng.step()                      # 8 slots, `prev` a real sample
+        eng.add_request(long_, max_new_tokens=6)
+        eng.step()                      # 16 slots, ids from both sources
+        eng.run()
+        eng.add_request(long_, max_new_tokens=3)
+        eng.run()                       # a pipeline that starts again
+    finally:
+        compiles.armed = False
+    assert compiles.count == 0, "a step after the first lowered or compiled"
+    assert {v._slots._data.shape[0] for v in tap.views} == {8, 16}
+    # every later launch was handed the tokens the one before it sampled
+    assert all(v._prev._data is not zeros for v in tap.views[1:])
+    assert len({id(v._prev._data) for v in tap.views}) == len(tap.views)
+    took = [int((np.asarray(v._src._data) >= 0).sum()) for v in tap.views]
+    if spec_k:
+        assert _metric("serving.pipeline.overlapped") == over0
+        assert set(took) == {0}         # depth 0: every id from the host
+    else:
+        assert _metric("serving.pipeline.overlapped") > over0
+        assert took[0] == 0 and max(took) == 2
 
 
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"],

@@ -278,7 +278,9 @@ class TestPrefixCache:
             h0 = _metric("serving.prefix_cache.hit_blocks")
             r0 = eng.add_request(head + tails[0], max_new_tokens=5)
             eng.step()
-            eng.step()          # head blocks written + published
+            eng.step()          # head blocks written ...
+            eng.step()          # ... and published, when the call after
+            #                     the one that launched them commits them
             r1 = eng.add_request(head + tails[1], max_new_tokens=5)
             res = eng.run()
             outs[cached] = [res[r0], res[r1]]
@@ -361,6 +363,22 @@ class TestScheduleIndependentSampling:
         got = tight.run()
         assert tight.preempt_count >= 1, "pool pressure should preempt"
         assert got[t1] == want[r1] and got[t2] == want[r2]
+
+    @pytest.mark.parametrize("seed", [0, 123, 2 ** 31 - 1])
+    def test_a_requests_stream_is_jax_fold_in_of_its_rid(self, model, seed):
+        # ISSUE 35: the key is folded on the host (the device's fold is a
+        # program and a transfer, which from `add_request` would wait for
+        # the step in flight); word for word what jax.random gives
+        import jax
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=32,
+                                       block_size=16, temperature=1.0,
+                                       seed=seed)
+        base = jax.random.key(seed, impl="threefry2x32")
+        for rid in (0, 1, 63, 70001, 2 ** 31 - 1):
+            eng.add_request([1, 2, 3], max_new_tokens=2, rid=rid)
+            want = np.asarray(jax.random.key_data(
+                jax.random.fold_in(base, rid)))
+            np.testing.assert_array_equal(eng.results[rid].key_data, want)
 
     def test_same_seed_reproducible_distinct_rows(self, model):
         eng1 = ContinuousBatchingEngine(model, max_batch=2, num_blocks=32,
@@ -581,7 +599,11 @@ class TestStepPhases:
         # 38 prompt tokens, none shared: every one was granted once
         assert sum(s.attrs["granted"] for s in sched) == 21 + 6 + 11
 
-    def test_step_span_is_dispatch_plus_sync_and_counts_launches(self, model):
+    def test_step_span_is_the_launching_calls_dispatch_and_counts_launches(
+            self, model):
+        # ISSUE 35: a step's span lies in the call that launched it, from
+        # dispatch begin to its last launch's return; its tokens are read a
+        # call later, under that call's sync
         from paddle_tpu.observability import tracing
         eng, _, launches = self._run(model)
         steps = [s for s in tracing.finished_spans("serving.step")
@@ -592,7 +614,8 @@ class TestStepPhases:
             disp, sync = [p for p in phases if p.attrs["step"] == k
                           and p.name in ("serving.step.dispatch",
                                          "serving.step.sync")]
-            assert sp.t0_ns == disp.t0_ns and sp.t1_ns == sync.t1_ns
+            assert sp.t0_ns == disp.t0_ns and sp.t1_ns == disp.t1_ns
+            assert sp.t1_ns <= sync.t0_ns
             # ISSUE 30: the step program, the logits' reshape, gather and
             # sampling. The first step may also trace the program, and the
             # ops that ran on tracers count as dispatches, not launches
@@ -653,3 +676,156 @@ class TestStepPhases:
         finally:
             paddle.set_flags({"FLAGS_tracing": True})
         assert on == off and all(len(t) == 5 for t in on)
+
+
+def _drive(eng, late=(), serial=False, max_new=7):
+    """Step ``eng`` until it is empty, adding the ``late`` prompts before the
+    fourth call. ``serial`` commits each step in the call that launched it
+    (the pipeline drained after every call): the same launch and commit at
+    depth 0, which is the step as it was before ISSUE 35."""
+    rids, calls = [], 0
+    while eng.pending or eng.num_active or calls < 4:
+        if calls == 3:
+            rids = [eng.add_request(p, max_new_tokens=max_new) for p in late]
+        eng.step()
+        if serial:
+            eng._drain()
+        calls += 1
+    return rids
+
+
+class TestOneStepInFlight:
+    """ISSUE 35: a call launches step N+1 and then commits step N."""
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.9],
+                             ids=["greedy", "keyed"])
+    def test_overlapped_tokens_are_the_serial_ones(self, model, temperature):
+        # a 37-token prompt in chunks of 8 beside decoding rows, two more
+        # requests arriving mid-run: every token is the serial engine's,
+        # and greedy ones are the dense generate()'s
+        rng = np.random.RandomState(21)
+        first = [rng.randint(0, 128, n).tolist() for n in (5, 37, 9)]
+        late = [rng.randint(0, 128, n).tolist() for n in (21, 3)]
+        got = {}
+        for serial in (False, True):
+            eng = ContinuousBatchingEngine(
+                model, max_batch=3, num_blocks=64, block_size=16,
+                temperature=temperature, seed=5, token_budget=12,
+                prefill_chunk=8)
+            over0 = _metric("serving.pipeline.overlapped")
+            rids = [eng.add_request(p, max_new_tokens=7) for p in first]
+            rids += _drive(eng, late, serial=serial)
+            got[serial] = [eng.results[r].out_tokens for r in rids]
+            over = _metric("serving.pipeline.overlapped") - over0
+            if serial:
+                assert over == 0
+            else:
+                # every step but the first of a pipeline had one before it
+                assert over >= eng.steps - 2 and eng.steps > 15
+        assert got[False] == got[True]
+        assert all(len(t) == 7 for t in got[False])
+        if temperature == 0.0:
+            assert got[False] == [_greedy_reference(model, p, 7)
+                                  for p in first + late]
+
+    def test_unforeseen_eos_drops_the_token_launched_after_it(self, model):
+        # the EOS is a token the first request's greedy stream reaches
+        # after a few places, with tokens left to ask for: by the commit
+        # that finds it the row was launched once more. That token is
+        # returned to nobody, it is counted, and the row and the blocks it
+        # gives back serve the request that waited
+        rng = np.random.RandomState(20)
+        prompts = [rng.randint(0, 128, n).tolist() for n in (6, 11, 4)]
+        full = [_greedy_reference(model, p, 12) for p in prompts]
+        eos = next(t for t in full[0][3:] if t not in full[0][:3])
+
+        def cut(tokens):
+            return (tokens[:tokens.index(eos) + 1] if eos in tokens
+                    else tokens)
+
+        assert 4 <= len(cut(full[0])) < 12
+        # two rows and 4 usable blocks, 2 a request: the third waits for the
+        # first to end and can only be given blocks that one held
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=5,
+                                       block_size=16, temperature=0.0,
+                                       eos_token_id=int(eos))
+        dropped0 = _metric("serving.pipeline.discarded_tokens")
+        generated0 = _metric("serving.generated_tokens")
+        rids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
+        res = eng.run()
+        assert [res[r] for r in rids] == [cut(t) for t in full]
+        assert _metric("serving.pipeline.discarded_tokens") - dropped0 >= 1
+        assert _metric("serving.generated_tokens") - generated0 \
+            == sum(len(cut(t)) for t in full)
+        assert eng._inflight is None and eng.num_active == 0
+        assert len(eng.cache._free) + eng._pc.evictable == eng._total_blocks
+
+    def test_preemption_commits_the_step_in_flight_first(self, model):
+        want = _greedy_reference(model, [3, 4, 5], 10)
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=16,
+                                       block_size=16, temperature=0.0)
+        rid = eng.add_request([3, 4, 5], max_new_tokens=10)
+        for _ in range(4):
+            eng.step()
+        req = eng.results[rid]
+        # four steps launched (the prompt and three tokens), three committed
+        assert eng._inflight is not None
+        assert (len(req.out_tokens), req.in_flight) == (3, 1)
+        drains0 = _metric("serving.pipeline.drains")
+        eng._preempt_lifo()
+        assert eng._inflight is None and req.in_flight == 0
+        assert _metric("serving.pipeline.drains") - drains0 == 1
+        # the victim resumes from all four tokens, as a serial engine's would
+        assert req.out_tokens == want[:4] and req.ctx == 0
+        assert eng.run()[rid] == want
+
+    def test_copy_on_write_commits_the_step_in_flight_first(self, model):
+        want = _greedy_reference(model, [7, 8, 9], 6)
+        eng = ContinuousBatchingEngine(model, max_batch=1, num_blocks=16,
+                                       block_size=16, temperature=0.0)
+        rid = eng.add_request([7, 8, 9], max_new_tokens=6)
+        eng.step()
+        eng.step()
+        req = eng.results[rid]
+        assert eng._inflight is not None
+        blk = int(eng.cache.block_tables[req.slot, req.ctx // 16])
+        eng._pc.register(b"held-elsewhere", blk)
+        eng._pc.acquire(blk)
+        seen = []
+        copy = eng._ensure_writable
+
+        def watched(i, blk_idx):
+            seen.append(eng._inflight)
+            return copy(i, blk_idx)
+
+        eng._ensure_writable = watched
+        drains0, cow0 = (_metric("serving.pipeline.drains"),
+                         _metric("serving.cow_copies"))
+        eng.step()
+        assert seen == [None], "the block was copied under a step in flight"
+        assert _metric("serving.cow_copies") - cow0 == 1
+        assert _metric("serving.pipeline.drains") - drains0 == 1
+        assert eng.run()[rid] == want
+
+    def test_speculation_runs_at_depth_zero(self, model):
+        # the drafts need the committed tokens: every step is committed in
+        # the call that launched it, through the same two functions
+        prompts = [[7, 8, 9] * 4, [5, 6] * 5]
+        outs = {}
+        for k in (0, 3):
+            eng = ContinuousBatchingEngine(
+                model, max_batch=2, num_blocks=64, block_size=16,
+                temperature=0.0, speculative_k=k)
+            over0, drains0 = (_metric("serving.pipeline.overlapped"),
+                              _metric("serving.pipeline.drains"))
+            rids = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+            while eng.pending or eng.num_active:
+                eng.step()
+                assert k == 0 or eng._inflight is None
+            outs[k] = [eng.results[r].out_tokens for r in rids]
+            over = _metric("serving.pipeline.overlapped") - over0
+            drains = _metric("serving.pipeline.drains") - drains0
+            assert (over, drains) == ((0, eng.steps) if k
+                                      else (eng.steps - 1, 1))
+        assert outs[0] == outs[3] == [_greedy_reference(model, p, 9)
+                                      for p in prompts]

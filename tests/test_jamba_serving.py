@@ -188,6 +188,42 @@ def test_step_that_is_all_padding_but_one_row(built, width):
     _check(tap, weights, rid, prompt, out)
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "keyed"])
+def test_one_step_in_flight_gives_the_serial_tokens(built, temperature):
+    # ISSUE 35: step N+1 is launched before step N's tokens are read, its
+    # decode ids taken on the device, and the in-place state updates follow
+    # launch order like the pool writes. Prompts over a chunk, a request
+    # that arrives mid-run into a row another one left: token for token
+    # what the same engine gives when every step is committed in the call
+    # that launched it, and (greedy) the reference's logits
+    model, weights = built
+    prompts = [_prompt(21, 40), _prompt(22, 7), _prompt(23, 19)]
+    late = _prompt(24, 26)
+    got = {}
+    for serial in (False, True):
+        eng, tap = _engine(model, "both", max_batch=3, token_budget=24,
+                           temperature=temperature, seed=11)
+        over0 = _counter("serving.pipeline.overlapped")
+        rids = [eng.add_request(p, max_new_tokens=n)
+                for p, n in zip(prompts, (6, 3, 9))]
+        calls = 0
+        while eng.pending or eng.num_active:
+            if calls == 4:
+                rids.append(eng.add_request(late, max_new_tokens=5))
+            eng.step()
+            if serial:
+                eng._drain()
+            calls += 1
+        got[serial] = [list(eng.results[r].out_tokens) for r in rids]
+        over = _counter("serving.pipeline.overlapped") - over0
+        assert over == 0 if serial else over >= eng.steps - 2
+        if temperature == 0.0 and not serial:
+            for r, p in zip(rids, prompts + [late]):
+                _check(tap, weights, r, p, eng.results[r].out_tokens)
+    assert got[False] == got[True]
+    assert [len(t) for t in got[False]] == [6, 3, 9, 5]
+
+
 # -- the two ragged ops -------------------------------------------------------
 
 # rows' token counts and first positions: decode rows alone; a chunk that
