@@ -1309,8 +1309,10 @@ class ContinuousBatchingEngine:
                 _M_STATE_RESETS.inc(int((live & (lens == qlen)).sum()))
                 step_attrs = {"state_rows": int(live.sum()),
                               "scan_tokens": t}
-            # what one layer's attention call walks: its tiles' live kv
-            # blocks, beside the tile x table-column pairs of the whole grid
+            # what one layer's attention call walks: its live tiles and
+            # their live kv blocks (the pairs its ring streams), beside the
+            # tile x table-column pairs of the whole grid
+            kv_live_tiles = _rpa.live_tiles(qlen)
             kv_tile_blocks = _rpa.live_tile_blocks(qlen, lens, bs)
             kv_table_blocks = (_rpa.num_tiles(R, T)
                                * self.cache.block_tables.shape[1])
@@ -1383,6 +1385,7 @@ class ContinuousBatchingEngine:
                        "decode_rows": len(decode_rows),
                        "prefill_rows": len(prefill_rows),
                        "launches": launches,
+                       "kv_live_tiles": kv_live_tiles,
                        "kv_tile_blocks": kv_tile_blocks,
                        "kv_table_blocks": kv_table_blocks,
                        **step_attrs})

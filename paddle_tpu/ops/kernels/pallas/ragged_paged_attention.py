@@ -23,17 +23,34 @@ table scalar-prefetched. The pools stay in HBM; the kv axis is a loop
 INSIDE the kernel whose trip count is the tile's live block count
 ``nblk = ceil((qpos0 + qcount) / BS)``: the blocks up to the causal
 horizon of the tile's last token, read from ``context_lens``, whatever
-the table's width. The loop is double-buffered: two VMEM slots per pool
-operand and a DMA semaphore per slot; iteration ``j`` starts the copy of
-block ``j + 1`` (``block_tables[row, j + 1]``, one ``[BS, KV, D]``
-block) into the other slot, waits for its own, and attends the whole
-tile against it, online-softmax state ``(m, l, acc)`` living in VMEM
-scratch across the loop; the output tile is written once after it. So
-both the arithmetic and the grid scale with
+the table's width.
+
+The walk over a call's live (tile, block) pairs is ONE stream (ISSUE 38).
+Number the pairs ``g = 0 .. P-1`` in the order the grid visits them, tile
+by tile and block by block. Each pool operand has a ring of ``SLOTS`` VMEM
+slots with a DMA semaphore a slot; visit ``g`` works in slot ``g % SLOTS``
+and, before it waits for its own block, starts the copy of visit
+``g + AHEAD`` (``block_tables[row, j]`` of that pair, one ``[BS, KV, D]``
+block an operand), which may belong to the next tile or the one after: the
+ring, its semaphores and the fetch cursor ``(tile, j)`` are scratch that
+outlives a grid step, and the grid is sequential. Grid step 0 primes the
+first ``AHEAD`` copies; every copy started is waited for exactly once, by
+the visit that consumes it. So a visit costs its block's bytes, not a
+copy's issue-to-landing time, and a decode row's first block is on its way
+while the tiles before it are computed. ``SLOTS`` and ``AHEAD`` follow
+from the bytes of one visit's slots (``_ring_depth``). A visit attends the
+whole tile against its block, online-softmax state ``(m, l, acc)`` living
+in VMEM scratch across the tile's visits; the output tile is written once
+after them. So both the arithmetic and the copies scale with
 ``sum_tiles(nblk) ~ sum(q_len_r * context_len_r) / (TQ * BS)``, not with
-the padded ``NT x MB`` rectangle of tiles and table columns. A padding
-tile (``nblk = 0``) costs its grid step: the q tile's pipelined copy in
-and a tile of zeros out, no pool traffic.
+the padded ``NT x MB`` rectangle of tiles and table columns.
+
+The live tiles are the first ``tile_cu[R]`` of the grid and the stream
+ends with the last of them: a padding tile's grid step starts no copy,
+computes nothing, and its q and output block indices are those of the last
+live tile, so the pipeline moves nothing in or out for it. The rows of the
+kernel's output past the live tiles are never written; the unpack gather
+reads live tiles and the appended zero row only.
 
 ``NT = R + ceil(T/TQ)`` (``num_tiles``) is a static upper bound on the
 tile count (each row wastes at most one partial tile), so an engine with a fixed
@@ -51,11 +68,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ....jax_compat import tpu_compiler_params
 from .flash_attention import _interpret  # shared interpret override
 
 _NEG = -1e30
 
 TQ = 8  # query tokens per tile (f32 sublane)
+
+# what the rings of one call may hold in VMEM, and the kernel's VMEM limit:
+# the rings, the pipelined q and output tiles, (m, l, acc) and the float32
+# copies of one visit's K and V (4 MiB at 32 KV heads)
+_RING_BYTES = 8 * 1024 * 1024
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def supported(q_shape, pool_shape) -> bool:
@@ -71,43 +95,74 @@ def num_tiles(rows: int, tokens: int) -> int:
     return rows + -(-tokens // TQ)
 
 
-def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, tbl_ref, q_ref, *rest,
-            bs, g, scale, quantized):
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of ``shape`` as VMEM lays it out: the last axis in 128 lanes,
+    the one before in as many sublanes as a 32-bit row packs values."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, sub, lane = shape
+    pack = 8 * (4 // item)
+    return (int(np.prod(lead)) * (sub + -sub % pack) * (lane + -lane % 128)
+            * item)
+
+
+def _ring_depth(visit_bytes: int):
+    """``(SLOTS, AHEAD)`` of the ring from the VMEM bytes of one visit's
+    slots (a K and a V block, and the int8 pool's two scale tiles): as many
+    slots as ``_RING_BYTES`` hold, 3 to 8, and every slot but the one being
+    computed on in flight. The copies are read-only, so a slot is free the
+    moment its visit is over."""
+    slots = max(3, min(8, _RING_BYTES // visit_bytes))
+    return slots, slots - 1
+
+
+def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
+            q_ref, *rest, bs, g, scale, quantized, slots, ahead):
     n_pool = 4 if quantized else 2           # k, v (+ their scale tiles)
     pools, o_ref = rest[:n_pool], rest[n_pool]
     bufs = rest[n_pool + 1:2 * n_pool + 1]
-    sem, m_scr, l_scr, acc_scr = rest[2 * n_pool + 1:]
+    sem, cur, m_scr, l_scr, acc_scr = rest[2 * n_pool + 1:]
     t = pl.program_id(0)
     row, qp0, qc, nblk = row_ref[t], qp0_ref[t], qc_ref[t], nblk_ref[t]
+    pairs = pair0_ref[pl.num_programs(0)]    # P: the call's live pairs
 
-    def copies(j, slot):
-        # block j of this tile's row: one DMA per pool operand, HBM -> the
-        # slot's VMEM buffer, all riding the same block-table entry
-        b = tbl_ref[row, j]
+    def copies(r, j, slot):
+        # block j of row r: one DMA per pool operand, HBM -> the slot's
+        # VMEM buffer, all riding the same block-table entry
+        b = tbl_ref[r, j]
         return [pltpu.make_async_copy(pool.at[b], buf.at[slot],
                                       sem.at[i, slot])
                 for i, (pool, buf) in enumerate(zip(pools, bufs))]
 
-    m_scr[...] = jnp.full_like(m_scr, _NEG)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(nblk > 0)
-    def _():
-        for c in copies(0, 0):
+    def fetch(f):
+        # start the copies of pair f, the one the cursor stands on, and
+        # move the cursor to pair f + 1 (a live tile has a block or more)
+        ft, fj = cur[0], cur[1]
+        for c in copies(row_ref[ft], fj, f % slots):
             c.start()
+        last = fj + 1 >= nblk_ref[ft]
+        cur[0] = jnp.where(last, ft + 1, ft)
+        cur[1] = jnp.where(last, 0, fj + 1)
+
+    @pl.when(t == 0)
+    def _():
+        cur[0] = 0
+        cur[1] = 0
+        for f in range(ahead):
+            @pl.when(f < pairs)
+            def _():
+                fetch(f)
 
     def body(j, carry):
-        slot = j % 2
+        f = pair0_ref[t] + j
+        slot = f % slots
 
-        # the other slot was consumed by iteration j - 1: refill it while
-        # this iteration waits for and works on its own
-        @pl.when(j + 1 < nblk)
+        # the slot of pair f + AHEAD held a pair before f, consumed by now:
+        # fill it while this visit waits for and works on its own
+        @pl.when(f + ahead < pairs)
         def _():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
+            fetch(f + ahead)
 
-        for c in copies(j, slot):
+        for c in copies(row, j, slot):
             c.wait()
         q = q_ref[0].astype(jnp.float32)                       # [KV, TG, D]
         kf = bufs[0][slot].astype(jnp.float32)                 # [BS, KV, D]
@@ -141,21 +196,29 @@ def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, tbl_ref, q_ref, *rest,
         l_scr[...] = l_new
         return carry
 
-    # the tile's LIVE blocks only: the causal horizon of its last token
-    # bounds every kv position any of its tokens may see (nblk), and a
-    # padding tile (nblk = 0) falls through to the zero write below
-    jax.lax.fori_loop(0, nblk, body, 0)
-    l = l_scr[...]
-    l_safe = jnp.where(l == 0.0, 1.0, l)   # fully-masked padding lanes
-    o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+    # a live tile: its LIVE blocks only, the causal horizon of its last
+    # token bounds every kv position any of its tokens may see (nblk). A
+    # padding tile (the tail of the grid) is past the stream's end
+    @pl.when(t < live_ref[0])
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        jax.lax.fori_loop(0, nblk, body, 0)
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)   # fully-masked padding lanes
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 def _tile_metadata(cu, ctx, nt, bs, mb):
     """Per tile of the ``nt``-tile grid: the row's first tile
-    (``tile_cu[R+1]``), owning row, first packed token, valid token
-    count, absolute position of the first token, and the live kv block
-    count — blocks up to the causal horizon of the tile's last token,
-    0 for a padding tile."""
+    (``tile_cu[R+1]``; ``tile_cu[R]`` is the count of live tiles, which are
+    the grid's first), owning row, first packed token, valid token count,
+    absolute position of the first token, the live kv block count — blocks
+    up to the causal horizon of the tile's last token, at least the one of
+    its own first token, 0 for a padding tile — and the index of the tile's
+    first (tile, block) pair in the order the grid walks them (``[nt + 1]``:
+    the last is the call's pair count)."""
     R = ctx.shape[0]
     qlen = cu[1:] - cu[:-1]                                    # [R]
     tile_cu = jnp.concatenate(
@@ -170,8 +233,36 @@ def _tile_metadata(cu, ctx, nt, bs, mb):
     qcount = jnp.clip(qlen[row_of] - local * TQ, 0, TQ)
     qpos0 = ctx[row_of] - qlen[row_of] + local * TQ
     nblk = jnp.where(qcount > 0,
-                     jnp.minimum((qpos0 + qcount + bs - 1) // bs, mb), 0)
-    return tile_cu, row_of, tok0, qcount, qpos0, nblk
+                     jnp.clip((qpos0 + qcount + bs - 1) // bs, 1, mb), 0)
+    pair0 = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(nblk, dtype=jnp.int32)])
+    return tile_cu, row_of, tok0, qcount, qpos0, nblk, pair0
+
+
+def _unpack_index(cu, tile_cu, tokens, nt):
+    """For each packed token, its row of the kernel's output tiles laid out
+    flat ``[nt * TQ + 1]``: a row of a LIVE tile, or for the tokens past
+    ``cu[R]`` (step padding) the appended zero row ``nt * TQ``. Never a row
+    of a padding tile, which the kernel does not write."""
+    R = cu.shape[0] - 1
+    tok = jnp.arange(tokens, dtype=jnp.int32)
+    trow = jnp.clip(
+        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1,
+        0, R - 1)
+    tlocal = tok - cu[trow]
+    src = (tile_cu[trow] + tlocal // TQ) * TQ + tlocal % TQ
+    return jnp.where(tok < cu[R], src, nt * TQ)
+
+
+def _tiles_of(q_lens):
+    return (np.asarray(q_lens, np.int64) + TQ - 1) // TQ
+
+
+def live_tiles(q_lens) -> int:
+    """The q tiles one call computes, counted on the host as
+    ``live_tile_blocks`` counts their blocks: ``_tile_metadata``'s
+    ``tile_cu[R]``."""
+    return int(_tiles_of(q_lens).sum())
 
 
 def live_tile_blocks(q_lens, context_lens, block_size) -> int:
@@ -181,7 +272,7 @@ def live_tile_blocks(q_lens, context_lens, block_size) -> int:
     beside the ``NT x MB`` pairs of the whole table."""
     qlen = np.asarray(q_lens, np.int64)
     ctx = np.asarray(context_lens, np.int64)
-    ntiles = (qlen + TQ - 1) // TQ
+    ntiles = _tiles_of(qlen)
     row = np.repeat(np.arange(len(qlen)), ntiles)
     local = np.arange(len(row)) - np.repeat(np.cumsum(ntiles) - ntiles,
                                             ntiles)
@@ -213,7 +304,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     NT = num_tiles(R, T)
 
     cu = cu_q_lens.astype(jnp.int32)
-    tile_cu, row_of, tok0, qcount, qpos0, nblk = _tile_metadata(
+    tile_cu, row_of, tok0, qcount, qpos0, nblk, pair0 = _tile_metadata(
         cu, context_lens.astype(jnp.int32), NT, BS, MB)
 
     # pack q into tiles: [T, H, D] -> [NT, KV, TQ*G, D] (zero-padded)
@@ -228,8 +319,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 
     quantized = k_scale is not None
     operands = [k_pool, v_pool]
-    bufs = [pltpu.VMEM((2, BS, KV, D), k_pool.dtype),
-            pltpu.VMEM((2, BS, KV, D), v_pool.dtype)]
+    blocks = [((BS, KV, D), k_pool.dtype), ((BS, KV, D), v_pool.dtype)]
     if quantized:
         # a DMA out of HBM cannot slice a minor axis narrower than the
         # 128 lanes, so the scales go in as [NB, KV, BS padded to 128]
@@ -238,17 +328,24 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
             jnp.pad(jnp.swapaxes(s.astype(jnp.float32), 1, 2),
                     ((0, 0), (0, 0), (0, bsp - BS)))
             for s in (k_scale, v_scale)]
-        bufs += [pltpu.VMEM((2, KV, bsp), jnp.float32)] * 2
-    tile_spec = pl.BlockSpec((1, KV, TG, D), lambda t, *_: (t, 0, 0, 0))
+        blocks += [((KV, bsp), jnp.float32)] * 2
+    slots, ahead = _ring_depth(sum(_vmem_bytes(*b) for b in blocks))
+    # a padding tile re-uses the last live tile's q and output blocks
+    tile_spec = pl.BlockSpec(
+        (1, KV, TG, D),
+        lambda t, row, qp0, qc, nblk, pair0, live, tbl: (
+            jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=7,
         grid=(NT,),
         # the pools stay in HBM: the kernel DMAs the blocks it walks
         in_specs=[tile_spec] + [pl.BlockSpec(memory_space=pl.ANY)
                                 for _ in operands],
         out_specs=tile_spec,
-        scratch_shapes=bufs + [
-            pltpu.SemaphoreType.DMA((len(operands), 2)),
+        scratch_shapes=[pltpu.VMEM((slots,) + shape, dtype)
+                        for shape, dtype in blocks] + [
+            pltpu.SemaphoreType.DMA((len(operands), slots)),
+            pltpu.SMEM((2,), jnp.int32),      # the fetch cursor (tile, j)
             pltpu.VMEM((KV, TG, 1), jnp.float32),
             pltpu.VMEM((KV, TG, 1), jnp.float32),
             pltpu.VMEM((KV, TG, D), jnp.float32)],
@@ -256,26 +353,22 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     out_dtype = q.dtype
     out = pl.pallas_call(
         functools.partial(_kernel, bs=BS, g=G, scale=float(scale),
-                          quantized=quantized),
+                          quantized=quantized, slots=slots, ahead=ahead),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NT, KV, TG, D), out_dtype),
+        # the ring and its cursor carry from one grid step to the next
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
         name="ragged_paged_attention",
         interpret=_interpret(),
-    )(row_of, qpos0, qcount, nblk,
+    )(row_of, qpos0, qcount, nblk, pair0, tile_cu[R:],
       jnp.clip(block_tables.astype(jnp.int32), 0, NB - 1),
       q_tiles, *operands)
 
-    # unpack tiles back to the packed token axis; tokens past cu[R]
-    # (step padding) read the appended zero row
-    tok = jnp.arange(T, dtype=jnp.int32)
-    trow = jnp.clip(
-        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1,
-        0, R - 1)
-    tlocal = tok - cu[trow]
-    src = (tile_cu[trow] + tlocal // TQ) * TQ + tlocal % TQ
-    src = jnp.where(tok < cu[R], src, NT * TQ)
+    # unpack tiles back to the packed token axis
     out_flat = (out.reshape(NT, KV, TQ, G, D)
                 .transpose(0, 2, 1, 3, 4)
                 .reshape(NT * TQ, H, D))
     out_flat = jnp.concatenate([out_flat, jnp.zeros((1, H, D), out.dtype)])
-    return out_flat[src]
+    return out_flat[_unpack_index(cu, tile_cu, T, NT)]

@@ -126,12 +126,24 @@ def test_sharded_flash_on_the_hybrid_mesh(mosaic, topo):
 # blocks, table width), 64-token blocks, head_dim 128
 RAGGED_SHAPES = {
     # chip_smoke's serve phase: Llama-2-7B, 8 rows, tables as wide as
-    # max_position_embeddings / block_size
+    # max_position_embeddings / block_size. 32 KV heads: the ring's largest
+    # slots, 1 MiB a visit in bf16 (ISSUE 38)
     "llama2-7b": (H, KV, 512, 8, 130, 64),
-    # chipbench's serve-chat-steady: Mistral-7B-v0.3, 64 rows, the 8.6 GB
-    # pool, 32768 / 64 = 512 table columns in scalar memory
+    # chipbench's serve-chat-steady and serve-decode-batch: Mistral-7B-v0.3,
+    # 64 rows, the 8.6 GB pool, 32768 / 64 = 512 table columns in scalar
+    # memory; the budget's geometry and the half-width one
     "mistral-7b-cell": (32, 8, 512, 64, 4096, 512),
+    "mistral-7b-cell-half": (32, 8, 256, 64, 4096, 512),
+    # chipbench's serve-reason-batch: AI21-Jamba2-3B's attention layers, 20
+    # query heads on 1 KV head pooled in float32, 256 rows
+    "jamba2-3b-cell": (20, 1, 512, 256, 8448, 32),
+    "jamba2-3b-cell-half": (20, 1, 256, 256, 8448, 32),
 }
+RAGGED_CASES = [
+    (shapes, dtype)
+    for shapes in sorted(RAGGED_SHAPES)
+    for dtype in ((jnp.float32,) if shapes.startswith("jamba")
+                  else (jnp.bfloat16, jnp.int8))]
 
 
 def _ragged_args(shapes, pool_dtype, sharding):
@@ -160,13 +172,16 @@ def _ragged_text(one_chip, pool_dtype, shapes="llama2-7b"):
         .compile().as_text())
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-@pytest.mark.parametrize("shapes", sorted(RAGGED_SHAPES))
+@pytest.mark.parametrize(
+    "shapes, pool_dtype", RAGGED_CASES,
+    ids=[f"{s}-{jnp.dtype(d).name}" for s, d in RAGGED_CASES])
 def test_ragged_paged_attention(mosaic, one_chip, shapes, pool_dtype):
     # the kv loop's dynamic trip count, the pool left in HBM and the block
     # DMAs out of it, the table in scalar memory: interpret mode takes all
-    # of them, Mosaic has to (ISSUE 27)
+    # of them, Mosaic has to (ISSUE 27). ISSUE 38: the ring of VMEM slots
+    # that outlives a grid step, its cursor in scalar memory, the q and
+    # output blocks indexed through the count of live tiles, and the rings
+    # of every pool within the kernel's stated VMEM limit
     assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype, shapes)
 
 

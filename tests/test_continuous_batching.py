@@ -649,6 +649,12 @@ class TestStepPhases:
                 for s in steps] == [(0, 1), (1, 1), (1, 1)]
         assert [s.attrs["kv_tile_blocks"] for s in steps] == [
             2, 2 + 2 + 4 + 6, 2 + 8 + 10]
+        # ISSUE 38: the live tiles those pairs belong to (a decode row is
+        # one, a chunk one an 8 tokens, the idle slot none). The visits the
+        # kernel's ring does NOT fetch ahead of their tile's grid step were
+        # these in the parent and are min(AHEAD, pairs) a call now
+        assert [s.attrs["kv_live_tiles"] for s in steps] == [
+            1, 1 + 3, 1 + 2]
         # 3 rows + ceil(slots / 8) tiles, each against every table column:
         # the table follows the geometry the step ran (ISSUE 32), 12 slots
         # for step 1's 6 tokens, the budget's 25 for 25 and 17 tokens

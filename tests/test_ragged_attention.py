@@ -221,6 +221,17 @@ class TestRaggedKernel:
                                    atol=2e-5, rtol=2e-5)
 
 
+def _garbage_in_dead_columns(rng, tbl, ctx, bs, widen):
+    """``tbl`` made ``widen`` times wider, every column past a row's live
+    blocks out-of-range garbage."""
+    R, mb = tbl.shape
+    live = np.arange(mb)[None, :] < -(-np.asarray(ctx)[:, None] // bs)
+    junk = rng.randint(-2 ** 30, 2 ** 30, (R, widen * mb)).astype(np.int32)
+    wide = junk.copy()
+    wide[:, :mb] = np.where(live, np.asarray(tbl), junk[:, :mb])
+    return jnp.asarray(wide)
+
+
 # (q_lens, context_lens) at block_size 16: what the in-kernel loop over a
 # tile's live blocks must get right at its edges
 _LOOP_CASES = {
@@ -259,13 +270,10 @@ class TestLiveBlockLoop:
             np.asarray(got, np.float32)[:cu[-1]], ref[:cu[-1]],
             atol=tol, rtol=tol)
         # a table 32 times wider, its dead columns out-of-range garbage
-        R, mb = tbl.shape
-        live = np.arange(mb)[None, :] < -(-np.asarray(ctx)[:, None] // 16)
-        junk = rng.randint(-2 ** 30, 2 ** 30, (R, 32 * mb)).astype(np.int32)
-        wide = junk.copy()
-        wide[:, :mb] = np.where(live, np.asarray(tbl), junk[:, :mb])
+        wide = _garbage_in_dead_columns(rng, np.asarray(tbl), ctx, 16,
+                                        widen=32)
         got_wide = rpa.ragged_paged_attention(
-            q, kp, vp, jnp.asarray(wide), ctx, cu, **scales)
+            q, kp, vp, wide, ctx, cu, **scales)
         np.testing.assert_array_equal(np.asarray(got_wide, np.float32),
                                       np.asarray(got, np.float32))
 
@@ -277,12 +285,189 @@ class TestLiveBlockLoop:
         cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
         nt = rpa.num_tiles(len(qlens), 48)
         for bs in (4, 16, 64):
-            *_, qcount, _, nblk = rpa._tile_metadata(
+            *_, qcount, _, nblk, pair0 = rpa._tile_metadata(
                 jnp.asarray(cu), jnp.asarray(ctxs, jnp.int32), nt, bs, 512)
             assert int((np.asarray(qcount) > 0).sum()) == sum(
                 -(-n // rpa.TQ) for n in qlens)
             assert rpa.live_tile_blocks(qlens, ctxs, bs) == int(
                 np.asarray(nblk).sum())
+
+
+# name -> (q_lens, context_lens, packed tokens, `_layout` keywords): what
+# the stream over a call's live (tile, block) pairs must get right where
+# it crosses from tile to tile, starts and ends
+_STREAM_CASES = {
+    # every row a single block: the ring runs wholly across tiles
+    "single_block_rows": ([1] * 9, [3, 16, 9, 1, 12, 5, 16, 2, 7], 16, {}),
+    # rows of 2 and 3 blocks where 7 copies are in flight: a visit's fetch
+    # lands two and three tiles on
+    "fewer_blocks_than_ahead": ([1, 1, 1, 1], [20, 40, 33, 17], 8, {}),
+    # 11 pairs through 8 slots
+    "pairs_not_a_multiple_of_slots": ([1, 1, 1], [80, 70, 16], 8, {}),
+    "one_live_tile": ([0, 1, 0], [0, 50, 0], 8, {}),
+    # every row empty: zeros come back and no copy is started
+    "no_live_tile": ([0, 0, 0, 0], [0, 0, 0, 0], 16, {}),
+    "empty_rows_between": ([1, 0, 0, 9, 0, 1], [60, 0, 7, 25, 0, 33], 24,
+                           {"mb": 4}),
+    # a chunk of 256 tokens at context 3,072 beside decode rows, as
+    # serve-chat-steady packs them: 32 tiles of 45 to 48 blocks of 64
+    "chunk_beside_decode_rows": (
+        [1, 256, 1, 1], [700, 3072, 64, 130], 264,
+        {"bs": 64, "mb": 48, "nb": 80}),
+    # decode rows whose context the table cannot hold: the walk stops at
+    # its last column, as if the context ended there
+    "nblk_capped_at_the_tables_width": ([1, 1, 1, 9], [40, 20, 60, 30], 16,
+                                        {"mb": 2, "capped": True}),
+    # Jamba's attention layers: 20 query heads on 1 KV head
+    "mqa_20_on_1": ([1, 9, 1, 0, 1], [40, 9, 70, 0, 16], 16,
+                    {"h": 20, "kv": 1, "mb": 5}),
+}
+
+
+def _stream_layout(case, dtype=jnp.float32):
+    qlens, ctxs, tokens, kw = _STREAM_CASES[case]
+    kw = dict(kw)
+    capped = kw.pop("capped", False)
+    bs, mb = kw.get("bs", 16), kw.get("mb", 6)
+    rng = np.random.RandomState(sorted(_STREAM_CASES).index(case))
+    # a capped row is laid out, and judged, at the context its table holds
+    held = [min(c, mb * bs) for c in ctxs]
+    q, kp, vp, tbl, _, cu = _layout(rng, qlens, held, tokens, dtype=dtype,
+                                    **kw)
+    return rng, bs, capped, (q, kp, vp, tbl, jnp.asarray(ctxs, jnp.int32),
+                             cu), jnp.asarray(held, jnp.int32)
+
+
+class TestStream:
+    """ISSUE 38: a call's live (tile, block) pairs are one stream through a
+    ring of VMEM slots, fetched ahead across blocks and across tiles."""
+
+    @pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("case", sorted(_STREAM_CASES))
+    def test_matches_reference_and_ignores_dead_columns(self, case, pool):
+        dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+        rng, bs, capped, args, held = _stream_layout(case, dtype)
+        q, kp, vp, tbl, ctx, cu = args
+        ref = _reference(q, kp, vp, tbl, held, cu, bs=bs)
+        scales = {}
+        if pool == "int8":
+            kp, vp, ks, vs = _quantize_pools(kp, vp)
+            scales = dict(k_scale=ks, v_scale=vs)
+        got = rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, cu, **scales)
+        assert got.dtype == q.dtype
+        got = np.asarray(got, np.float32)
+        tol = 2e-5 if pool == "f32" else 5e-2
+        np.testing.assert_allclose(got[:cu[-1]], ref[:cu[-1]],
+                                   atol=tol, rtol=tol)
+        assert np.abs(got[cu[-1]:]).max(initial=0.0) == 0.0
+        wide = _garbage_in_dead_columns(rng, np.asarray(tbl), held, bs,
+                                        widen=1 if capped else 8)
+        got_wide = rpa.ragged_paged_attention(q, kp, vp, wide, ctx, cu,
+                                              **scales)
+        np.testing.assert_array_equal(np.asarray(got_wide, np.float32), got)
+
+    @pytest.mark.parametrize("depth", [(3, 2), (8, 4), (8, 7)],
+                             ids=["3-2", "8-4", "8-7"])
+    @pytest.mark.parametrize("case", sorted(_STREAM_CASES))
+    def test_walks_schedule(self, case, depth):
+        # a model of the kernel's walk on the host, step for step (grid
+        # step, prime, fetch ahead, wait, compute; the cursor's move), with
+        # what interpret mode cannot assert: its copies complete at once
+        slots, ahead = depth
+        qlens, ctxs, tokens, kw = _STREAM_CASES[case]
+        bs, mb = kw.get("bs", 16), kw.get("mb", 6)
+        cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
+        nt = rpa.num_tiles(len(qlens), tokens)
+        tile_cu, row_of, _, _, _, nblk, pair0 = (
+            np.asarray(a) for a in rpa._tile_metadata(
+                jnp.asarray(cu), jnp.asarray(ctxs, jnp.int32), nt, bs, mb))
+        n_live, pairs = int(tile_cu[-1]), int(pair0[nt])
+        assert n_live == rpa.live_tiles(qlens)
+        assert (nblk[:n_live] >= 1).all() and (nblk[n_live:] == 0).all()
+        if not kw.get("capped"):
+            assert pairs == rpa.live_tile_blocks(qlens, ctxs, bs)
+
+        cursor = [0, 0]
+        holds = {}              # slot -> (pair, landed and consumed?)
+        started, waited, in_flight = [], [], 0
+
+        def fetch(f):
+            nonlocal in_flight
+            slot, pair = f % slots, tuple(cursor)
+            # the slot's last pair has been consumed before it is refilled
+            assert holds.get(slot, (None, True))[1], (f, slot)
+            holds[slot] = (pair, False)
+            started.append((f, pair))
+            in_flight += 1
+            # the visit's own copy and AHEAD beyond it, a slot each
+            assert in_flight <= ahead + 1 <= slots
+            last = cursor[1] + 1 >= nblk[cursor[0]]
+            cursor[:] = [cursor[0] + 1, 0] if last else [cursor[0],
+                                                         cursor[1] + 1]
+
+        for t in range(nt):
+            if t == 0:
+                for f in range(ahead):
+                    if f < pairs:
+                        fetch(f)
+                assert len(started) == min(ahead, pairs)
+            if t >= n_live:
+                continue        # a padding tile: no copy, no wait
+            for j in range(nblk[t]):
+                f = int(pair0[t]) + j
+                if f + ahead < pairs:
+                    fetch(f + ahead)
+                # the visit waits for the copy the cursor started for it
+                assert holds[f % slots] == ((t, j), False)
+                waited.append((f, (t, j)))
+                in_flight -= 1
+                assert in_flight <= ahead
+                holds[f % slots] = ((t, j), True)
+        # every copy started is waited for exactly once, by its own visit,
+        # and each live pair was fetched, in the grid's order
+        assert started == waited and in_flight == 0
+        assert [f for f, _ in started] == list(range(pairs))
+        assert [p for _, p in started] == [
+            (t, j) for t in range(n_live) for j in range(nblk[t])]
+
+    @pytest.mark.parametrize("case", sorted(_STREAM_CASES))
+    def test_unpack_never_reads_a_padding_tile(self, case):
+        # the rows of the kernel's output past the live tiles are never
+        # written: every token reads a live tile or the appended zero row
+        qlens, _, tokens, _ = _STREAM_CASES[case]
+        cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
+        nt = rpa.num_tiles(len(qlens), tokens)
+        tile_cu = np.concatenate(
+            [[0], np.cumsum([-(-n // rpa.TQ) for n in qlens])])
+        src = np.asarray(rpa._unpack_index(
+            jnp.asarray(cu), jnp.asarray(tile_cu, jnp.int32), tokens, nt))
+        live_rows = rpa.live_tiles(qlens) * rpa.TQ
+        assert live_rows <= nt * rpa.TQ
+        assert ((src < live_rows) | (src == nt * rpa.TQ)).all()
+        assert (src[:cu[-1]] < live_rows).all()
+        assert (src[cu[-1]:] == nt * rpa.TQ).all()
+
+    def test_depth_follows_the_visits_bytes(self):
+        # one rule for every pool: 8 KV heads in bf16, Jamba's one float32
+        # head, 32 heads (a visit of 1 MiB), the int8 pool with its scales
+        def depth(kv, dtype, scales=False):
+            blocks = [((64, kv, 128), dtype)] * 2
+            blocks += [((kv, 128), jnp.float32)] * (2 if scales else 0)
+            return rpa._ring_depth(sum(rpa._vmem_bytes(*b) for b in blocks))
+
+        assert rpa._vmem_bytes((64, 8, 128), jnp.bfloat16) == 64 * 16 * 256
+        assert rpa._vmem_bytes((64, 1, 128), jnp.float32) == 64 * 8 * 512
+        for kv, dtype, scales in ((8, jnp.bfloat16, False),
+                                  (1, jnp.float32, False),
+                                  (32, jnp.bfloat16, False),
+                                  (8, jnp.int8, True),
+                                  (32, jnp.int8, True)):
+            slots, ahead = depth(kv, dtype, scales)
+            assert 3 <= slots <= 8 and 1 <= ahead < slots
+        assert depth(8, jnp.bfloat16) == (8, 7)
+        # slots too large for the budget still leave a ring of three
+        assert rpa._ring_depth(rpa._RING_BYTES) == (3, 2)
+        assert rpa._ring_depth(rpa._RING_BYTES // 5) == (5, 4)
 
 
 @pytest.mark.skipif(jax.device_count() < 8,
