@@ -564,8 +564,7 @@ class ResilientTrainer:
                     continue
                 raise
             if _perf_mod.step_seq() == seq0:
-                _perf_mod.record_step(time.perf_counter() - t0,
-                                      steps=block.size)
+                _perf_mod.record_step(time.perf_counter() - t0)
             self.data_loader._commit_stream_state(block.stream_state)
             if self.anomaly is not None:
                 outs = list(out) if isinstance(out, (list, tuple)) else [out]

@@ -463,9 +463,8 @@ class Model:
         t1 = time.perf_counter()
         losses = [float(v) for v in np.asarray(loss._data)]
         t2 = time.perf_counter()
-        # one observation per block, normalized over its K device steps
-        _perf_mod.record_step(t2 - t0, host_s=t1 - t0, device_s=t2 - t1,
-                              steps=k)
+        # one observation per block
+        _perf_mod.record_step(t2 - t0, host_s=t1 - t0, device_s=t2 - t1)
         return losses, _to_list(outputs), lbs
 
     def _run_eval(self, eval_loader, cbks, n_labels):

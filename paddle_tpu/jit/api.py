@@ -14,6 +14,7 @@ from ..autograd import engine
 from ..core import generator
 from ..core.tensor import Tensor
 from ..nn.layer_base import Layer
+from ..observability import tracing as _tracing
 
 
 # Held while tensors are swapped: a model shared by threads (replicas of one
@@ -235,6 +236,13 @@ def _to_array(t):
     return t._data if isinstance(t, Tensor) else jnp.asarray(t)
 
 
+def _aval(a) -> jax.ShapeDtypeStruct:
+    """An argument's shape for lowering; an array that was placed keeps its
+    placement, as a call of the jitted function would have kept it."""
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=a.sharding if a.committed else None)
+
+
 class TrainStep:
     """Whole-training-step compilation: loss fwd + grads + optimizer update
     in one donated XLA program.
@@ -366,28 +374,34 @@ class TrainStep:
                                              buffer_arrays, rng, inputs, labels)
             if n_accum > 1:
                 grads = tuple((a + g) / n_accum for a, g in zip(accum, grads))
+            # the scopes name the device's work in a profile (every
+            # instruction's op_name; tracing.device_ops): forward and
+            # backward are JAX's own jvp(...) and transpose(jvp(...))
             if grad_clip is not None:
-                grads = clip_mod.pure_clip(grad_clip, grads)
+                with jax.named_scope("optimizer.grad_clip"):
+                    grads = clip_mod.pure_clip(grad_clip, grads)
             new_params, new_masters, new_states = [], [], []
-            for p, m, s, g, w, psh, msh, ssh in zip(
-                    param_arrays, master_arrays, opt_states, grads, wd,
-                    param_sh, master_sh, state_sh):
-                target = m if m is not None else p
-                g = g.astype(target.dtype)
-                np_, ns_ = opt._update(target, g, s, lr, stepno, w)
-                ns_ = {k: _pin(v, ssh.get(k)) for k, v in ns_.items()}
-                if m is not None:
-                    np_ = _pin(np_, msh)
-                    new_masters.append(np_)
-                    new_params.append(_pin(np_.astype(p.dtype), psh))
-                else:
-                    new_masters.append(None)
-                    new_params.append(_pin(np_, psh))
-                new_states.append(ns_)
+            with jax.named_scope("optimizer.update"):
+                for p, m, s, g, w, psh, msh, ssh in zip(
+                        param_arrays, master_arrays, opt_states, grads, wd,
+                        param_sh, master_sh, state_sh):
+                    target = m if m is not None else p
+                    g = g.astype(target.dtype)
+                    np_, ns_ = opt._update(target, g, s, lr, stepno, w)
+                    ns_ = {k: _pin(v, ssh.get(k)) for k, v in ns_.items()}
+                    if m is not None:
+                        np_ = _pin(np_, msh)
+                        new_masters.append(np_)
+                        new_params.append(_pin(np_.astype(p.dtype), psh))
+                    else:
+                        new_masters.append(None)
+                        new_params.append(_pin(np_, psh))
+                    new_states.append(ns_)
             return (tuple(new_params), tuple(new_masters), tuple(new_states),
                     new_buf, loss, key, stepno)
 
         self._compiled = jax.jit(step, donate_argnums=(0, 1, 2, 3, 4, 6, 10))
+        self._noted = False     # until a call hands the program to tracing
         self._params, self._buffers, self._frozen = params, buffers, frozen
         # device-resident step chain state (re-seeded on rebuild/resume)
         self._dev_key = generator.next_key()
@@ -449,6 +463,11 @@ class TrainStep:
         return self._compiled.lower(*self._step_args(inputs, labels))
 
     def _call_impl(self, inputs, labels):
+        # the host's side of a call: `train.step.args` (build, re-sync, the
+        # argument tuple), then `train.step.launch`, and `train.step` over
+        # both, recorded once the launch has returned (`_launch`)
+        sp_args = _tracing.start_span("train.step.args",
+                                      trace=_tracing.UNTRACED)
         opt = self.optimizer
         if self._compiled is not None and \
                 getattr(opt, "_sharding_version", 0) \
@@ -473,11 +492,12 @@ class TrainStep:
 
         if self.grad_accum > 1 and self._micro < self.grad_accum - 1:
             # accumulation-only micro-step: no optimizer update
-            self._accum, new_buf, loss = self._accum_fn(
-                self._accum, tuple(p._data for p in params),
-                tuple(f._data for f in self._frozen),
-                tuple(b._data for b in buffers),
-                generator.next_key(), inputs, labels)
+            self._accum, new_buf, loss = self._launch(
+                self._accum_fn,
+                (self._accum, tuple(p._data for p in params),
+                 tuple(f._data for f in self._frozen),
+                 tuple(b._data for b in buffers),
+                 generator.next_key(), inputs, labels), inputs, sp_args)
             for b, nb in zip(buffers, new_buf):
                 b._set_data(nb)
             self._micro += 1
@@ -485,8 +505,19 @@ class TrainStep:
 
         self._step += 1
         opt._step_count = self._step
+        args = self._step_args(inputs, labels)
+        if not self._noted:
+            # once a build. The arguments are donated, so their shapes and
+            # placements are kept: a reader of tracing.device_ops() lowers
+            # and compiles from them, and the compile cache has the program.
+            # Until then (or tracing.clear(), or 16 newer programs) the
+            # registry holds the jit and with it the model
+            self._noted = True
+            jit, avals = self._compiled, jax.tree.map(_aval, args)
+            _tracing.note_program(
+                "train_step", lambda: jit.lower(*avals).compile())
         new_p, new_m, new_s, new_buf, loss, self._dev_key, self._dev_step = \
-            self._compiled(*self._step_args(inputs, labels))
+            self._launch(self._compiled, args, inputs, sp_args)
         for i, p in enumerate(params):
             p._set_data(new_p[i])
             opt._masters[i] = new_m[i]
@@ -496,6 +527,26 @@ class TrainStep:
         self._accum = None
         self._micro = 0
         return Tensor(loss)
+
+    @staticmethod
+    def _launch(jit, args, inputs, sp_args):
+        """``jit(*args)`` under the `train.step.launch` span, which follows
+        ``sp_args``; then the call's `train.step` over both. ``tokens`` are
+        the first input's elements (an LM's ids), ``compiled`` says that
+        this call traced or compiled: the jit's cache grew."""
+        sp_args.end()
+        known = jit._cache_size()
+        with _tracing.start_span("train.step.launch",
+                                 trace=_tracing.UNTRACED) as sp:
+            out = jit(*args)
+        if sp.t1_ns is not None:
+            first = jax.tree.leaves(inputs)[:1]
+            _tracing.record_span(
+                "train.step", sp_args.t0_ns, sp.t1_ns,
+                trace=_tracing.UNTRACED,
+                attrs={"tokens": sum(int(a.size) for a in first),
+                       "compiled": int(jit._cache_size() > known)})
+        return out
 
 
 # -- jit.save / jit.load ------------------------------------------------------

@@ -66,7 +66,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..jit import exec_store as _exec_store
-from ..jit.api import _SWAP_LOCK, _collect_state, _swap_state
+from ..jit.api import _SWAP_LOCK, _aval, _collect_state, _swap_state
 from ..observability import metrics as _metrics_mod
 from ..observability import perf as _perf_mod
 from ..observability import tracing as _tracing
@@ -377,7 +377,9 @@ class _RaggedView:
         self._segments = None
 
     def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
-        return self._c.write(layer, k_new, v_new, self._slots)
+        # named under the module that called: .../self_attn/serving.cache_write
+        with jax.named_scope("serving.cache_write"):
+            return self._c.write(layer, k_new, v_new, self._slots)
 
     def attend(self, layer: int, q: Tensor, pos=None, attn_mask=None):
         b, s, h, d = q.shape
@@ -457,6 +459,10 @@ class _ModelProgram:
                        if isinstance(self.jit, _exec_store.PersistentJit)
                        else self.jit.lower(*avals).compile())
                 self._executables[key] = exe
+                if hasattr(exe, "as_text"):
+                    # once an executable; one an exec store loaded without
+                    # its text names nothing (tracing.device_ops)
+                    _tracing.note_program("serving_step", exe)
             return exe
 
 
@@ -488,7 +494,8 @@ def _step_program(model, spec: Tuple) -> _ModelProgram:
                      prev, src):
         _M_TRACES.inc()
         before = _M_LAUNCHES.value
-        ids = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], ids)
+        with jax.named_scope("serving.gather_ids"):
+            ids = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], ids)
         over = PagedKVCache.over(spec, pools)
         view = _RaggedView(over, Tensor(slots), Tensor(tables),
                            Tensor(lens), Tensor(cu))
@@ -505,13 +512,6 @@ def _step_program(model, spec: Tuple) -> _ModelProgram:
         label="serving_step")
     programs[key] = _ModelProgram(jit, state)
     return programs[key]
-
-
-def _aval(a) -> jax.ShapeDtypeStruct:
-    """An argument's shape for lowering; an array that was placed keeps its
-    placement, as a call of the jitted function would have kept it."""
-    return jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=a.sharding if a.committed else None)
 
 
 class _StepProgram:
@@ -1382,6 +1382,8 @@ class ContinuousBatchingEngine:
             _tracing.record_span(
                 "serving.step", t0_ns, td_ns,
                 attrs={"tokens": t, "slots": T,
+                       # launched behind a step still in flight
+                       "overlapped": int(self._inflight is not None),
                        "decode_rows": len(decode_rows),
                        "prefill_rows": len(prefill_rows),
                        "launches": launches,

@@ -12,6 +12,7 @@ from __future__ import annotations
 import collections
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..core import dtype as dtype_mod
@@ -92,6 +93,10 @@ class Layer:
         self._forward_pre_hooks: Dict[int, Callable] = collections.OrderedDict()
         self._forward_post_hooks: Dict[int, Callable] = collections.OrderedDict()
         self._name_scope = name_scope or self.__class__.__name__.lower()
+        # the name `__call__` runs `forward` under (`jax.named_scope`): the
+        # key a parent registered this layer under (`add_sublayer`), the
+        # class's until then
+        self._scope_key = self._name_scope
 
     # -- registry ------------------------------------------------------------
     def __setattr__(self, name, value):
@@ -99,7 +104,7 @@ class Layer:
             self._parameters[name] = value
             self.__dict__.pop(name, None)
         elif isinstance(value, Layer):
-            self._sub_layers[name] = value
+            self.add_sublayer(name, value)
             self.__dict__.pop(name, None)
         elif name in self._buffers and isinstance(value, Tensor):
             self._buffers[name] = value  # rebinding a registered buffer
@@ -131,7 +136,22 @@ class Layer:
 
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[name] = sublayer
+        if sublayer is not None:
+            sublayer._set_scope_key(f"{self._scope_key}/{name}"
+                                    if self._only_holds() else str(name))
         return sublayer
+
+    def _only_holds(self) -> bool:
+        """No forward of its own (a LayerList): never called, so it opens
+        no scope, and what it holds carries its key (``layers/3``)."""
+        return type(self).forward is Layer.forward
+
+    def _set_scope_key(self, key: str) -> None:
+        object.__setattr__(self, "_scope_key", key)
+        if self._only_holds():
+            for name, sub in self._sub_layers.items():
+                if sub is not None:
+                    sub._set_scope_key(f"{key}/{name}")
 
     def register_buffer(self, name: str, tensor: Optional[Tensor],
                         persistable: bool = True):
@@ -156,7 +176,6 @@ class Layer:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
         if _lazy_depth > 0:
             # LazyGuard active: host-RAM zeros placeholder, init deferred
-            import jax
             import jax.numpy as jnp
             cpu = jax.local_devices(backend="cpu")[0]
             with jax.default_device(cpu):
@@ -250,7 +269,6 @@ class Layer:
             for layer in self.sublayers(include_self=True):
                 layer._dtype = dtype
         if device is not None:
-            import jax
             from ..core.device import Place, _parse_place
             place = device if isinstance(device, Place) else _parse_place(str(device))
             for t in list(self.parameters()) + [b for _, b in self.named_buffers()]:
@@ -326,7 +344,12 @@ class Layer:
             res = hook(self, inputs)
             if res is not None:
                 inputs = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*inputs, **kwargs)
+        # under a trace every instruction's op_name gets the path of keys
+        # (layers/3/self_attn/q_proj: the prefix of a parameter's structured
+        # name), which observability.tracing.device_ops() hands to a reader
+        # of a device profile; eager it names nothing
+        with jax.named_scope(self._scope_key):
+            out = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             res = hook(self, inputs, out)
             if res is not None:

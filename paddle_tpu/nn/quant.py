@@ -118,8 +118,8 @@ def quantize_for_inference(model: Layer, algo: str = "weight_only_int8",
     def visit(layer: Layer):
         for name, sub in list(layer._sub_layers.items()):
             if isinstance(sub, Linear) and (not skip or name not in skip):
-                layer._sub_layers[name] = WeightOnlyLinear.from_linear(
-                    sub, weight_dtype=wdt, group_size=group_size)
+                layer.add_sublayer(name, WeightOnlyLinear.from_linear(
+                    sub, weight_dtype=wdt, group_size=group_size))
             else:
                 visit(sub)
 

@@ -53,7 +53,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from .. import flags as _flags
 from . import flight_recorder as _flight
 from . import metrics as _metrics
-from . import tracing as _tracing
 
 __all__ = [
     "ExecutableLedger", "ledger", "enabled", "clock", "note_data_wait",
@@ -611,13 +610,12 @@ def timed_iter(iterable):
 
 
 def record_step(total_s: float, host_s: float = 0.0,
-                device_s: float = 0.0, steps: int = 1) -> None:
+                device_s: float = 0.0) -> None:
     """Decompose one step (or one K-step block) of wall time.
 
     ``other = total - data_wait - host - device`` by construction, so
     the four components sum to the step wall exactly. Emits the
-    ``perf.step.*`` histograms and, when tracing is on, retroactive
-    spans laid out over the step's interval.
+    ``perf.step.*`` histograms.
     """
     global _pending_data_wait, _step_seq
     step_beat()
@@ -640,18 +638,6 @@ def record_step(total_s: float, host_s: float = 0.0,
     _H_HOST_DISPATCH.observe(host_s)
     _H_DEVICE.observe(device_s)
     _H_OTHER.observe(other)
-    if _tracing.enabled():
-        end = _tracing.now_ns()
-        t = end - int(total_s * 1e9)
-        for name, dur in (("perf.step.data_wait", data_wait),
-                          ("perf.step.host_dispatch", host_s),
-                          ("perf.step.device", device_s),
-                          ("perf.step.other", other)):
-            if dur > 0.0:
-                nxt = t + int(dur * 1e9)
-                _tracing.record_span(name, t, nxt,
-                                     attrs={"steps": steps})
-                t = nxt
 
 
 def step_summary() -> Dict[str, Any]:

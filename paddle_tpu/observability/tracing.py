@@ -30,7 +30,14 @@ always-on, near-zero-cost third leg:
   whenever a ``jax.profiler`` trace is running the program's spans are
   in the xplane on the host thread's line, on the device trace's clock
   (a no-op in C++ otherwise). Retroactive spans and instants stay
-  ring-only; :func:`finished_spans` reads the ring back.
+  ring-only; :func:`finished_spans` reads the ring back;
+* the **device's** work is named by the compiled programs themselves:
+  ``nn.Layer.__call__`` and ``TrainStep`` trace under ``jax.named_scope``,
+  so every HLO instruction's ``op_name`` carries its module path, and
+  whoever makes an executable hands it to :func:`note_program` once.
+  :func:`device_ops` reads the noted programs' text back as one record an
+  instruction, which a reader of a device profile joins to the profile's
+  events by instruction name (``chipbench/device_scopes.py`` does).
 
 The span-name taxonomy is FROZEN (:data:`SPAN_NAMES`) exactly like
 ``metrics.METRIC_NAMES``: a typo'd name would silently fork the
@@ -52,10 +59,12 @@ Span phases for one served request (TTFT = queue + compile + kernel)::
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -71,7 +80,7 @@ __all__ = [
     "event", "activate", "deactivate", "current", "current_trace_id",
     "inject", "extract", "enabled", "now_ns", "dump_trace", "to_chrome",
     "set_span_sink", "clear", "active_spans", "finished_spans",
-    "FinishedSpan", "UNTRACED",
+    "FinishedSpan", "UNTRACED", "note_program", "device_ops", "DeviceOp",
 ]
 
 # one-attribute-read disabled path, same discipline as _F_METRICS
@@ -108,7 +117,8 @@ SPAN_NAMES = frozenset({
     "serving.step_hang",       # event: watchdog fired on a wedged step
     # models/serving.py — the ragged engine's per-request phases
     "serving.step",            # span: ONE ragged mixed prefill+decode step
-    #                            (retro: dispatch + sync, attrs launches)
+    #                            (retro: dispatch + sync, attrs launches,
+    #                            overlapped: launched behind one in flight)
     "serving.step.admit",      # span: _admit, preemption check, gauges
     "serving.step.schedule",   # span: decode/prefill rows, grants, drafts
     "serving.step.pack",       # span: the step's numpy arrays and cu
@@ -122,6 +132,11 @@ SPAN_NAMES = frozenset({
     "serving.first_token",     # event: the TTFT edge
     "serving.finish",          # event: request finished
     "serving.preempt",         # event: LIFO preemption victim
+    # jit/api.py — TrainStep's host side of one call
+    "train.step",              # span (retro): args + launch (attrs tokens,
+    #                            compiled: the call traced or compiled)
+    "train.step.args",         # span: build, re-sync, lr, the argument tuple
+    "train.step.launch",       # span: the jitted call until it returns
     # jit/step_capture.py — the training step
     "step_capture.capture",    # span: trace+lower+compile of a whole step
     "step_capture.replay",     # span: one captured-executable replay
@@ -136,12 +151,6 @@ SPAN_NAMES = frozenset({
     "checkpoint.commit",       # span: background serialize+fsync+commit
     # observability/incident.py — forensic bundle assembly
     "observability.incident",  # span: one incident bundle commit
-    # observability/perf.py — retro step-decomposition segments laid
-    # over each recorded step's interval
-    "perf.step.data_wait",     # span (retro): blocked on the data pipeline
-    "perf.step.host_dispatch",  # span (retro): step call -> async launch out
-    "perf.step.device",        # span (retro): launch -> results host-visible
-    "perf.step.other",         # span (retro): remainder (callbacks, logging)
     # this module's jax.monitoring listener
     "jit.compile",             # span (retro): one XLA backend compile
     # jit/exec_store.py — the persistent executable cache
@@ -371,10 +380,12 @@ _flags.on_set("tracing_ring_size", _on_ring_size)
 
 
 def clear() -> None:
-    """Drop every recorded span and instant (test/bench hygiene)."""
+    """Drop every recorded span and instant and every noted program
+    (test/bench hygiene)."""
     if _RING is not None:
         _RING.clear()
     _ACTIVE.clear()
+    _PROGRAMS.clear()
 
 
 def active_spans() -> List[Span]:
@@ -402,6 +413,136 @@ def finished_spans(prefix: str = "", since_ns: Optional[int] = None
             for sp in _RING.entries()
             if sp.kind == "span" and sp.name.startswith(prefix)
             and (since_ns is None or sp.t0_ns >= since_ns)]
+
+
+# -- the compiled programs' own names for the device's work -------------------
+
+_PROGRAMS_MAX = 16            # the newest programs; the oldest are dropped
+
+
+class DeviceOp(NamedTuple):
+    """One instruction of a noted program's optimized HLO, as its text has
+    it. ``kernel`` is the Pallas call's ``name`` for a ``tpu_custom_call``
+    (else ""); ``has_matmul`` says that the instruction is, or its fused
+    computation contains, a ``dot`` or a ``convolution``;
+    ``fused_op_names`` are the distinct ``op_name`` s inside the
+    computations it calls (a fusion has one ``op_name`` of its own, its
+    root's, whatever else XLA fused into it)."""
+    program: str
+    instruction: str
+    result_type: str
+    opcode: str
+    op_name: str
+    kernel: str
+    has_matmul: bool
+    fused_op_names: Tuple[str, ...] = ()
+
+
+class _Program:
+    __slots__ = ("name", "source", "ops")
+
+    def __init__(self, name: str, source):
+        self.name = name
+        self.source = source       # dropped once its text has been read
+        self.ops: Optional[List[DeviceOp]] = None
+
+
+_PROGRAMS: "collections.deque[_Program]" = collections.deque(
+    maxlen=_PROGRAMS_MAX)
+
+
+def note_program(name: str, compiled_or_thunk) -> None:
+    """Remember an executable for :func:`device_ops`: a ``Compiled``
+    (anything with ``as_text()``) or a thunk that returns one. Called once
+    where an executable is made, never on a step's path; nothing is read or
+    parsed here. The newest ``_PROGRAMS_MAX`` are kept, each with whatever
+    it holds (an executable; a thunk's closure) until :func:`device_ops`
+    has read it or :func:`clear` is called."""
+    if not _F_TRACING.value:
+        return
+    _PROGRAMS.append(_Program(name, compiled_or_thunk))
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=(]+)\s+=\s+(.*)$")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r"calls=%?([^\s,}]+)")
+_MATMULS = ("dot", "convolution")
+
+
+def _parse_hlo(program: str, text: str) -> List[DeviceOp]:
+    """Every instruction of every computation of an HLO module's text."""
+    rows = []                  # name, type, opcode, op_name, kernel, calls
+    matmul_in: Dict[str, bool] = {}      # computation -> has a dot of its own
+    names_in: Dict[str, set] = {}        # computation -> its own op_names
+    calls_of: Dict[str, List[str]] = {}  # computation -> computations called
+    computation = ""
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            head = line.strip()
+            if head.endswith("{") and not head.startswith("HloModule"):
+                computation = head.split()[1 if head.startswith("ENTRY")
+                                           else 0].lstrip("%")
+            continue
+        name, rest = m.groups()
+        op = _HLO_OPCODE.search(" " + rest)
+        if op is None:
+            continue
+        result_type = (" " + rest)[:op.start()].strip()
+        opcode = op.group(1)
+        found = _HLO_OP_NAME.search(rest)
+        op_name = found.group(1) if found else ""
+        called = _HLO_CALLS.findall(rest)
+        kernel = (re.sub(r"\.\d+$", "", name)
+                  if opcode == "custom-call" and "tpu_custom_call" in rest
+                  else "")
+        rows.append((name, result_type, opcode, op_name, kernel, called))
+        if opcode in _MATMULS:
+            matmul_in[computation] = True
+        if op_name:
+            names_in.setdefault(computation, set()).add(op_name)
+        calls_of.setdefault(computation, []).extend(called)
+
+    inside: Dict[str, Tuple[bool, frozenset]] = {}
+
+    def within(comp: str) -> Tuple[bool, frozenset]:
+        """(holds a matmul, the op_names) of a computation and all it calls."""
+        if comp not in inside:
+            parts = [within(c) for c in calls_of.get(comp, ())]
+            inside[comp] = (
+                matmul_in.get(comp, False) or any(m for m, _ in parts),
+                frozenset(names_in.get(comp, ())).union(
+                    *(n for _, n in parts)))
+        return inside[comp]
+
+    out = []
+    for name, result_type, opcode, op_name, kernel, called in rows:
+        parts = [within(c) for c in called]
+        out.append(DeviceOp(
+            program, name, result_type, opcode, op_name, kernel,
+            opcode in _MATMULS or any(m for m, _ in parts),
+            tuple(sorted(frozenset().union(*(n for _, n in parts))))))
+    return out
+
+
+def device_ops() -> List[DeviceOp]:
+    """One record an instruction of every noted program, oldest program
+    first. A program's text is taken and parsed on the first read and kept;
+    one whose text cannot be had (its thunk or ``as_text`` raised) gives no
+    record. Raw facts: grouping by scope, phase or kind is the reader's."""
+    out: List[DeviceOp] = []
+    for prog in list(_PROGRAMS):
+        if prog.ops is None:
+            source, prog.source = prog.source, None
+            try:
+                if not hasattr(source, "as_text"):
+                    source = source()
+                prog.ops = _parse_hlo(prog.name, source.as_text())
+            except Exception:
+                prog.ops = []  # a reader's fault must not break the run
+        out.extend(prog.ops)
+    return out
 
 
 # -- span creation ------------------------------------------------------------

@@ -207,9 +207,9 @@ class QAT:
         for name, sub in list(model._sub_layers.items()):
             cfg = self.config.config_for(sub)
             if cfg is not None and isinstance(sub, Linear):
-                model._sub_layers[name] = QuantedLinear(sub, cfg)
+                model.add_sublayer(name, QuantedLinear(sub, cfg))
             elif cfg is not None and isinstance(sub, Conv2D):
-                model._sub_layers[name] = QuantedConv2D(sub, cfg)
+                model.add_sublayer(name, QuantedConv2D(sub, cfg))
             else:
                 self._quantize_inplace(sub)
 
