@@ -118,6 +118,8 @@ class LlamaRotaryEmbedding(Layer):
 
     def __init__(self, head_dim: int, max_pos: int, theta: float):
         super().__init__()
+        # what the tables are made from: equal keys, equal tables
+        self.key = (head_dim, max_pos, float(theta))
         inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                                / head_dim))
         t = jnp.arange(max_pos, dtype=jnp.float32)
@@ -165,20 +167,19 @@ class LlamaAttention(Layer):
     def forward(self, x, attn_mask=None, position_ids=None, cache=None,
                 start_pos=None, layer_idx=0):
         b, s, _ = x.shape
+        if getattr(cache, "packed_rows", False):
+            return self._forward_rows(x, cache, start_pos, layer_idx)
         q = self.q_proj(x).reshape([b, s, self.num_heads, self.head_dim])
         k = self.k_proj(x).reshape([b, s, self.num_kv_heads, self.head_dim])
         v = self.v_proj(x).reshape([b, s, self.num_kv_heads, self.head_dim])
         if cache is not None:
             # decode path: rope at absolute positions, write into the cache,
             # attend against everything written so far (serving kernels).
-            # start_pos may be a PER-ROW vector (continuous batching:
-            # every slot decodes at its own depth, models/serving.py) or a
-            # [b, s] PER-TOKEN matrix (ragged mixed prefill+decode: the
-            # packed token axis carries every row's chunk at its own depth)
+            # start_pos may be a PER-ROW vector (every row of the batch
+            # decodes at its own depth). A ragged step's per-token positions
+            # never come here: `_forward_rows`
             if self.rotary is not None:
-                if getattr(start_pos, "ndim", 0) == 2:
-                    pos_ids = start_pos
-                elif getattr(start_pos, "ndim", 0) == 1:
+                if getattr(start_pos, "ndim", 0) == 1:
                     pos_ids = (start_pos.reshape([b, 1])
                                + call_op("arange", end=s, dtype="int32")
                                .reshape([1, s]))
@@ -207,6 +208,35 @@ class LlamaAttention(Layer):
             out = call_op(op, q, k, v, attn_mask=attn_mask, is_causal=True)
         out = out.reshape([b, s, self.num_heads * self.head_dim])
         return self.o_proj(out)
+
+    def _forward_rows(self, x, view, pos, layer_idx):
+        """A ragged serving step (`models/serving.py: _RaggedView`): x is
+        [1, T, hidden], the step's packed tokens, pos[1, T] their
+        positions. From `q_proj` to `o_proj` the queries and the attention
+        output stay [T, H*D] rows: rope turns the rows, the ragged kernel
+        reads rows and writes rows. An axis split off on the way (a
+        [T, H, D] view for rope's tables or for a tile pack) has XLA choose
+        another order of the axes for each consumer, and pay for every
+        change backwards into a transposition of the weight (ISSUE 40)."""
+        t = x.shape[1]
+        q = self.q_proj(x).reshape([t, self.num_heads * self.head_dim])
+        k = self.k_proj(x).reshape([t, self.num_kv_heads * self.head_dim])
+        v = self.v_proj(x)
+        if self.rotary is not None:
+            # the tokens' rows of the tables, cast as `rope` casts them:
+            # the same in every layer whose tables are, so made once a step
+            cos, sin = view.once(
+                ("rope_rows", self.rotary.key, q.dtype),
+                lambda: [Tensor(jnp.take(table._data, pos._data.reshape(-1),
+                                         axis=0).astype(q.dtype))
+                         for table in self.rotary(
+                             self.config.max_position_embeddings)])
+            q = call_op("rope_rows", q, cos=cos, sin=sin)
+            k = call_op("rope_rows", k, cos=cos, sin=sin)
+        kv_shape = [1, t, self.num_kv_heads, self.head_dim]
+        view.update(layer_idx, k.reshape(kv_shape), v.reshape(kv_shape), pos)
+        out = view.attend(layer_idx, q)
+        return self.o_proj(out.reshape([1, t, -1]))
 
 
 class LlamaMLP(Layer):

@@ -362,6 +362,9 @@ class _RaggedView:
     packed token the lane of ``prev`` its id comes from (-1: the id the
     host packed stands)."""
 
+    # the model's attention hands q and takes the output as [T, H*D] rows
+    packed_rows = True
+
     def __init__(self, cache: PagedKVCache, slots: Tensor, tables: Tensor,
                  lens: Tensor, cu: Tensor,
                  program: Optional["_StepProgram"] = None,
@@ -374,20 +377,27 @@ class _RaggedView:
         self._prev = prev
         self._src = src
         self.program = program
-        self._segments = None
+        self._once = {}
 
     def update(self, layer: int, k_new: Tensor, v_new: Tensor, pos):
         # named under the module that called: .../self_attn/serving.cache_write
         with jax.named_scope("serving.cache_write"):
             return self._c.write(layer, k_new, v_new, self._slots)
 
-    def attend(self, layer: int, q: Tensor, pos=None, attn_mask=None):
-        b, s, h, d = q.shape
-        out = call_op("ragged_paged_attention", q.reshape([s, h, d]),
-                      *self._c.kv(layer),
-                      self._tables, self._lens, self._cu,
-                      **self._c.scale_kwargs(layer))
-        return out.reshape([b, s, h, d])
+    def attend(self, layer: int, q: Tensor):
+        """q[T, H*D], the step's packed rows, to the attention output in
+        the same rows."""
+        return call_op("ragged_paged_attention", q, *self._c.kv(layer),
+                       self._tables, self._lens, self._cu,
+                       **self._c.scale_kwargs(layer))
+
+    def once(self, key, make):
+        """``make()``, made for the first layer of the step that asks under
+        ``key`` and handed to every layer after it: a value of the step's
+        tokens that layers with the same ``key`` would each compute alike."""
+        if key not in self._once:
+            self._once[key] = make()
+        return self._once[key]
 
     def segments(self) -> Tuple[Tensor, Tensor, Tensor]:
         """The step's rows as a recurrent layer needs them: ``cu_q_lens``,
@@ -395,14 +405,15 @@ class _RaggedView:
         last for a row with no token in the step) and the position of each
         row's first token (0: the segment starts from a zero state). Made
         inside the program from what every step uploads already."""
-        if self._segments is None:
+        def make():
             cu, lens = self._cu._data, self._lens._data
             qlen = cu[1:] - cu[:-1]
             rows = qlen.shape[0]
             slots = jnp.where(qlen > 0, jnp.arange(rows, dtype=jnp.int32),
                               rows)
-            self._segments = (self._cu, Tensor(slots), Tensor(lens - qlen))
-        return self._segments
+            return self._cu, Tensor(slots), Tensor(lens - qlen)
+
+        return self.once("segments", make)
 
     def row_state(self, layer: int, name: str) -> Tensor:
         return self._c.row(layer, name)

@@ -720,6 +720,25 @@ def rope(q, k=None, cos=None, sin=None, position_ids=None, rotate_half_style=Tru
     return out_q
 
 
+@register_kernel("rope_rows")
+def rope_rows(x, cos=None, sin=None):
+    """`rope`'s half rotation on packed rows: x[T, N*D], the D values of
+    each of N heads side by side in a row as a projection leaves them;
+    cos/sin[T, D] each token's rows of the tables. The same products and
+    sum in the same precision as `rope` gives for [1, T, N, D]: within a
+    head, lane d < D/2 takes -x[d + D/2] and lane d >= D/2 takes
+    x[d - D/2], which on the row is a roll by D/2 either way. No axis is
+    split off the row, so nothing asks the projection for another order of
+    its axes (ISSUE 40)."""
+    d = cos.shape[1]
+    heads = x.shape[1] // d
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) % d
+    rot = jnp.where(lane < d // 2, -jnp.roll(x, -(d // 2), axis=1),
+                    jnp.roll(x, d // 2, axis=1))
+    return (x * jnp.tile(cos.astype(x.dtype), (1, heads))
+            + rot * jnp.tile(sin.astype(x.dtype), (1, heads)))
+
+
 @register_kernel("flash_attention")
 def flash_attention(query, key, value, attn_mask=None, rng_key=None,
                     dropout_p=0.0, is_causal=False, scale=None):

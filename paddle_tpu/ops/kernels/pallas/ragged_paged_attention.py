@@ -11,19 +11,37 @@ against its own block-table slice of the shared pool. It is the
 repo's only paged attention kernel; a decode-only step is this kernel
 with ``q_len = 1`` rows.
 
-Layout: packed queries ``q[T, H, D]`` segmented by ``cu_q_lens[R+1]``
-(row r owns tokens ``cu[r]:cu[r+1]`` at absolute positions
+Layout: packed queries ``q[T, H*D]``, one row a token with its heads side
+by side as ``q_proj`` leaves them, segmented by ``cu_q_lens[R+1]`` (row r
+owns tokens ``cu[r]:cu[r+1]`` at absolute positions
 ``context_lens[r] - q_len_r + i`` — the chunk is already written to the
 pool, write-then-attend order). The kernel tiles the ragged token axis
 into fixed ``TQ=8``-token q tiles (a decode row is one mostly-padded
 tile; a chunk of C tokens is ``ceil(C/8)`` tiles). The grid is ``(NT,)``,
-one step a tile, with the tile metadata (owning row, absolute position
-of the tile's first token, valid count, live block count) and the block
-table scalar-prefetched. The pools stay in HBM; the kv axis is a loop
-INSIDE the kernel whose trip count is the tile's live block count
-``nblk = ceil((qpos0 + qcount) / BS)``: the blocks up to the causal
-horizon of the tile's last token, read from ``context_lens``, whatever
-the table's width.
+one step a tile, with the tile metadata (owning row, first packed token,
+absolute position of the tile's first token, valid count, live block
+count) and the block table scalar-prefetched. The pools stay in HBM; the
+kv axis is a loop INSIDE the kernel whose trip count is the tile's live
+block count ``nblk = ceil((qpos0 + qcount) / BS)``: the blocks up to the
+causal horizon of the tile's last token, read from ``context_lens``,
+whatever the table's width.
+
+The kernel does its own tiling (ISSUE 40): the packed rows and the packed
+output are whole arrays in VMEM for the call (float32: 4 MB each at 256
+tokens x 32 heads), and nothing around the call reorders an axis. Tile
+``t`` copies its ``qcount`` valid rows ``tok0[t] + i`` into the
+``[KV, TQ*G, D]`` operand of the body, head ``h``'s 128 lanes of token ``i``
+to row ``(h % G) * TQ + i`` of kv head ``h // G`` (the ``G`` heads of a
+group stacked along sublanes, group-major); after the tile's visits it
+stores the same rows of the result back, the valid ones only. A row that
+no tile owns (step padding, past ``cu[R]``) reads zeros: grid step 0
+clears the output. A pack outside the kernel (a gather to ``NT * TQ`` rows
+and a transposition to ``[NT, KV, TQ*G, D]``, and their inverse behind it)
+cost six relayout copies and two gathers a call, and had XLA write
+``q_proj``'s product tokens-minor and transpose the weight every step to
+get there. The resident rows are XLA's allocation in VMEM beside the
+kernel's own limit, so the step's tokens are bounded (``supported()``:
+1536 at 32 heads); past that the composite serves.
 
 The walk over a call's live (tile, block) pairs is ONE stream (ISSUE 38).
 Number the pairs ``g = 0 .. P-1`` in the order the grid visits them, tile
@@ -47,10 +65,7 @@ the padded ``NT x MB`` rectangle of tiles and table columns.
 
 The live tiles are the first ``tile_cu[R]`` of the grid and the stream
 ends with the last of them: a padding tile's grid step starts no copy,
-computes nothing, and its q and output block indices are those of the last
-live tile, so the pipeline moves nothing in or out for it. The rows of the
-kernel's output past the live tiles are never written; the unpack gather
-reads live tiles and the appended zero row only.
+loads no row, computes nothing and stores nothing.
 
 ``NT = R + ceil(T/TQ)`` (``num_tiles``) is a static upper bound on the
 tile count (each row wastes at most one partial tile), so an engine with a fixed
@@ -75,18 +90,27 @@ _NEG = -1e30
 
 TQ = 8  # query tokens per tile (f32 sublane)
 
-# what the rings of one call may hold in VMEM, and the kernel's VMEM limit:
-# the rings, the pipelined q and output tiles, (m, l, acc) and the float32
-# copies of one visit's K and V (4 MiB at 32 KV heads)
+# what the rings of one call may hold in VMEM; the kernel's VMEM limit, for
+# the rings, the tile's operand, (m, l, acc) and the float32 copies of one
+# visit's K and V (4 MiB at 32 KV heads); and what the packed rows and the
+# packed output may hold there between them, XLA's allocation beside the
+# kernel's limit: 8 MiB each at 512 tokens x 32 heads, growing with the
+# step's tokens. In the step program XLA:TPU holds the output, the kernel's
+# limit and 2.5 MiB more to 64 MiB (compile-only, Mistral-7B widths: 1536
+# tokens compile, 1920 do not)
 _RING_BYTES = 8 * 1024 * 1024
 _VMEM_LIMIT = 32 * 1024 * 1024
+_ROWS_BYTES = 48 * 1024 * 1024
 
 
 def supported(q_shape, pool_shape) -> bool:
-    """Whether the Pallas path handles this case (else XLA composite)."""
+    """Whether the Pallas path handles this case (else XLA composite): the
+    heads in whole groups over the pool's, and a step whose packed rows and
+    output fit in VMEM (1536 tokens at 32 heads)."""
     t, h, d = q_shape
     kv, pd = pool_shape[2], pool_shape[3]
-    return h % kv == 0 and d == pd
+    return (h % kv == 0 and d == pd
+            and 2 * _vmem_bytes((t, h * d), jnp.float32) <= _ROWS_BYTES)
 
 
 def num_tiles(rows: int, tokens: int) -> int:
@@ -115,15 +139,29 @@ def _ring_depth(visit_bytes: int):
     return slots, slots - 1
 
 
-def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
-            q_ref, *rest, bs, g, scale, quantized, slots, ahead):
+def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
+            tbl_ref, q_ref, *rest, bs, g, scale, quantized, slots, ahead):
     n_pool = 4 if quantized else 2           # k, v (+ their scale tiles)
     pools, o_ref = rest[:n_pool], rest[n_pool]
     bufs = rest[n_pool + 1:2 * n_pool + 1]
-    sem, cur, m_scr, l_scr, acc_scr = rest[2 * n_pool + 1:]
+    sem, cur, q_scr, m_scr, l_scr, acc_scr = rest[2 * n_pool + 1:]
     t = pl.program_id(0)
-    row, qp0, qc, nblk = row_ref[t], qp0_ref[t], qc_ref[t], nblk_ref[t]
+    row, tok0, qp0 = row_ref[t], tok0_ref[t], qp0_ref[t]
+    qc, nblk = qc_ref[t], nblk_ref[t]
     pairs = pair0_ref[pl.num_programs(0)]    # P: the call's live pairs
+    heads, d = q_scr.shape[0] * g, q_scr.shape[2]
+
+    def valid_tokens(visit):
+        # visit(packed row, [head h's (kv head, row of the tile's operand)])
+        # for each valid token of the tile: token i of head h is row
+        # (h % G) * TQ + i of kv head h // G, the G heads of a group stacked
+        # along sublanes, and columns h*D : (h+1)*D of packed row tok0 + i
+        for i in range(TQ):
+            @pl.when(i < qc)
+            def _():
+                visit(pl.ds(tok0 + i, 1),
+                      [(h // g, pl.ds(h % g * TQ + i, 1))
+                       for h in range(heads)])
 
     def copies(r, j, slot):
         # block j of row r: one DMA per pool operand, HBM -> the slot's
@@ -145,6 +183,8 @@ def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
 
     @pl.when(t == 0)
     def _():
+        # a row no tile owns (step padding, past cu[R]) reads zeros
+        o_ref[...] = jnp.zeros_like(o_ref)
         cur[0] = 0
         cur[1] = 0
         for f in range(ahead):
@@ -164,7 +204,7 @@ def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
 
         for c in copies(row, j, slot):
             c.wait()
-        q = q_ref[0].astype(jnp.float32)                       # [KV, TG, D]
+        q = q_scr[...]                                         # [KV, TG, D]
         kf = bufs[0][slot].astype(jnp.float32)                 # [BS, KV, D]
         vf = bufs[1][slot].astype(jnp.float32)
         k = jnp.swapaxes(kf, 0, 1)                             # [KV, BS, D]
@@ -179,7 +219,7 @@ def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale        # [KV, TG, BS]
         kvpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        qlocal = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // g
+        qlocal = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % TQ
         live = (kvpos <= qp0 + qlocal) & (qlocal < qc)
         s = jnp.where(live, s, _NEG)
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -201,13 +241,29 @@ def _kernel(row_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref, tbl_ref,
     # padding tile (the tail of the grid) is past the stream's end
     @pl.when(t < live_ref[0])
     def _():
+        def load(tok, rows):
+            q_tok = q_ref[tok, :]                              # [1, H*D]
+            for h, (kv, r) in enumerate(rows):
+                q_scr[kv, r, :] = q_tok[:, h * d:(h + 1) * d]
+
+        # the tile's rows of the packed queries; a row past its valid count
+        # keeps what an earlier tile left there, masked like any padding
+        valid_tokens(load)
         m_scr[...] = jnp.full_like(m_scr, _NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         jax.lax.fori_loop(0, nblk, body, 0)
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)   # fully-masked padding lanes
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        acc_scr[...] = acc_scr[...] / l_safe
+
+        def store(tok, rows):
+            o_ref[tok, :] = jnp.concatenate(
+                [acc_scr[kv, r, :] for kv, r in rows], axis=1)
+
+        # only the valid rows: the next tile's tokens are this one's
+        # neighbours in the packed output
+        valid_tokens(store)
 
 
 def _tile_metadata(cu, ctx, nt, bs, mb):
@@ -237,21 +293,6 @@ def _tile_metadata(cu, ctx, nt, bs, mb):
     pair0 = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(nblk, dtype=jnp.int32)])
     return tile_cu, row_of, tok0, qcount, qpos0, nblk, pair0
-
-
-def _unpack_index(cu, tile_cu, tokens, nt):
-    """For each packed token, its row of the kernel's output tiles laid out
-    flat ``[nt * TQ + 1]``: a row of a LIVE tile, or for the tokens past
-    ``cu[R]`` (step padding) the appended zero row ``nt * TQ``. Never a row
-    of a padding tile, which the kernel does not write."""
-    R = cu.shape[0] - 1
-    tok = jnp.arange(tokens, dtype=jnp.int32)
-    trow = jnp.clip(
-        jnp.searchsorted(cu, tok, side="right").astype(jnp.int32) - 1,
-        0, R - 1)
-    tlocal = tok - cu[trow]
-    src = (tile_cu[trow] + tlocal // TQ) * TQ + tlocal % TQ
-    return jnp.where(tok < cu[R], src, nt * TQ)
 
 
 def _tiles_of(q_lens):
@@ -284,19 +325,22 @@ def live_tile_blocks(q_lens, context_lens, block_size) -> int:
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                            cu_q_lens, scale=None, k_scale=None,
                            v_scale=None):
-    """q [T, H, D] packed over rows; pools [NB, BS, KV, D];
+    """q [T, H*D] packed over rows, a token's heads side by side as
+    ``q_proj`` leaves them (or its view [T, H, D]); pools [NB, BS, KV, D];
     block_tables [R, MB] int32; context_lens [R] visible tokens per row
     AFTER this step's write; cu_q_lens [R+1] ragged row segmentation of
-    the packed token axis. Returns [T, H, D].
+    the packed token axis. Returns q's shape, zeros for the tokens past
+    ``cu[R]``.
 
     k_scale/v_scale [NB, BS, KV] f32 (int8 pool): per-token-slot
     per-kv-head dequant scales riding the block table — each kv block's
     scale tile is DMA'd by the same table entry as the block itself and
     the dequant happens on the VMEM tile, so HBM reads stay at int8
     bytes."""
-    T, H, D = q.shape
-    NB, BS, KV, _ = k_pool.shape
+    NB, BS, KV, D = k_pool.shape
     R, MB = block_tables.shape
+    T = q.shape[0]
+    H = q.size // (T * D)
     G = H // KV
     TG = TQ * G
     if scale is None:
@@ -307,16 +351,10 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     tile_cu, row_of, tok0, qcount, qpos0, nblk, pair0 = _tile_metadata(
         cu, context_lens.astype(jnp.int32), NT, BS, MB)
 
-    # pack q into tiles: [T, H, D] -> [NT, KV, TQ*G, D] (zero-padded)
-    slot = jnp.arange(TQ, dtype=jnp.int32)
-    tok_idx = jnp.where(slot[None, :] < qcount[:, None],
-                        tok0[:, None] + slot[None, :], T)
-    q_pad = jnp.concatenate([q, jnp.zeros((1, H, D), q.dtype)])
-    q_tiles = (q_pad[tok_idx.reshape(-1)]
-               .reshape(NT, TQ, KV, G, D)
-               .transpose(0, 2, 1, 3, 4)
-               .reshape(NT, KV, TG, D))
-
+    # float32: a row at a dynamic offset is one sublane of a 32-bit tile,
+    # and Mosaic addresses no narrower one; the body computed in float32
+    # all along
+    rows = q.reshape(T, H * D).astype(jnp.float32)
     quantized = k_scale is not None
     operands = [k_pool, v_pool]
     blocks = [((BS, KV, D), k_pool.dtype), ((BS, KV, D), v_pool.dtype)]
@@ -330,45 +368,37 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
             for s in (k_scale, v_scale)]
         blocks += [((KV, bsp), jnp.float32)] * 2
     slots, ahead = _ring_depth(sum(_vmem_bytes(*b) for b in blocks))
-    # a padding tile re-uses the last live tile's q and output blocks
-    tile_spec = pl.BlockSpec(
-        (1, KV, TG, D),
-        lambda t, row, qp0, qc, nblk, pair0, live, tbl: (
-            jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), 0, 0, 0))
+    # the packed rows, whole, in VMEM for the call: XLA's allocation, which
+    # the fusion before the call writes and `o_proj`'s reads where they are
+    rows_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=8,
         grid=(NT,),
         # the pools stay in HBM: the kernel DMAs the blocks it walks
-        in_specs=[tile_spec] + [pl.BlockSpec(memory_space=pl.ANY)
-                                for _ in operands],
-        out_specs=tile_spec,
+        in_specs=[rows_spec] + [
+            pl.BlockSpec(memory_space=pl.ANY) for _ in operands],
+        out_specs=rows_spec,
         scratch_shapes=[pltpu.VMEM((slots,) + shape, dtype)
                         for shape, dtype in blocks] + [
             pltpu.SemaphoreType.DMA((len(operands), slots)),
             pltpu.SMEM((2,), jnp.int32),      # the fetch cursor (tile, j)
+            pltpu.VMEM((KV, TG, D), jnp.float32),     # the tile's queries
             pltpu.VMEM((KV, TG, 1), jnp.float32),
             pltpu.VMEM((KV, TG, 1), jnp.float32),
             pltpu.VMEM((KV, TG, D), jnp.float32)],
     )
-    out_dtype = q.dtype
     out = pl.pallas_call(
         functools.partial(_kernel, bs=BS, g=G, scale=float(scale),
                           quantized=quantized, slots=slots, ahead=ahead),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((NT, KV, TG, D), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((T, H * D), jnp.float32),
         # the ring and its cursor carry from one grid step to the next
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="ragged_paged_attention",
         interpret=_interpret(),
-    )(row_of, qpos0, qcount, nblk, pair0, tile_cu[R:],
+    )(row_of, tok0, qpos0, qcount, nblk, pair0, tile_cu[R:],
       jnp.clip(block_tables.astype(jnp.int32), 0, NB - 1),
-      q_tiles, *operands)
-
-    # unpack tiles back to the packed token axis
-    out_flat = (out.reshape(NT, KV, TQ, G, D)
-                .transpose(0, 2, 1, 3, 4)
-                .reshape(NT * TQ, H, D))
-    out_flat = jnp.concatenate([out_flat, jnp.zeros((1, H, D), out.dtype)])
-    return out_flat[_unpack_index(cu, tile_cu, T, NT)]
+      rows, *operands)
+    return out.astype(q.dtype).reshape(q.shape)

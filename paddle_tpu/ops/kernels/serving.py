@@ -167,7 +167,8 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, block_tables,
     """ONE kernel for a ragged mix of prefill chunks and decode rows
     over the paged KV pool (Ragged Paged Attention, arXiv:2604.15464).
 
-    q[T,H,D] packed query tokens segmented by cu_q_lens[R+1]; pools
+    q[T,H*D] packed query tokens (or the view [T,H,D]; the result has q's
+    shape) segmented by cu_q_lens[R+1]; pools
     [NB,BS,KV,D]; block_tables[R,MB]; context_lens[R] counts the tokens
     visible per row AFTER this step's chunk was written (write-then-
     attend order). Decode rows contribute q_len 1, prefill chunks their
@@ -178,6 +179,8 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, block_tables,
     with TP fallbacks recording their frozen reason."""
     from ... import flags
     from .pallas import ragged_paged_attention as rpa
+    rows = q                # [T, H*D] stays 2-D all the way to the kernel
+    q = q.reshape(q.shape[0], -1, k_pool.shape[3])
     if rpa.supported(q.shape, k_pool.shape):
         from .pallas import tp_attention as tpa
         ctx = tpa.current_tp_context()
@@ -192,14 +195,14 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, block_tables,
                     cu_q_lens, mesh, head_axis, batch_axis, scale,
                     k_scale=k_scale, v_scale=v_scale)
                 if out is not None:
-                    return out
+                    return out.reshape(rows.shape)
         elif flags.get_flag("use_pallas_kernels"):
             return rpa.ragged_paged_attention(
-                q, k_pool, v_pool, block_tables, context_lens, cu_q_lens,
+                rows, k_pool, v_pool, block_tables, context_lens, cu_q_lens,
                 scale, k_scale=k_scale, v_scale=v_scale)
     return _ragged_composite(q, k_pool, v_pool, block_tables, context_lens,
                              cu_q_lens, scale, k_scale=k_scale,
-                             v_scale=v_scale)
+                             v_scale=v_scale).reshape(rows.shape)
 
 
 def _token_rows(tokens, cu_q_lens, slots, start_pos, padding_slot):

@@ -134,6 +134,10 @@ RAGGED_SHAPES = {
     # memory; the budget's geometry and the half-width one
     "mistral-7b-cell": (32, 8, 512, 64, 4096, 512),
     "mistral-7b-cell-half": (32, 8, 256, 64, 4096, 512),
+    # ISSUE 40: the packed rows and the packed output are resident in VMEM
+    # beside the kernel's own limit, so they bound a step's tokens. The
+    # most `supported()` takes at these widths: 24 + 24 MiB
+    "mistral-7b-most-tokens": (32, 8, 1536, 64, 4096, 512),
     # chipbench's serve-reason-batch: AI21-Jamba2-3B's attention layers, 20
     # query heads on 1 KV head pooled in float32, 256 rows
     "jamba2-3b-cell": (20, 1, 512, 256, 8448, 32),
@@ -206,9 +210,9 @@ def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):
     assert _custom_call_names(text) == {"ragged_paged_attention"}
 
 
-def _step_texts(one_chip, make_model, rows, blocks, width):
+def _step_texts(one_chip, make_model, rows, blocks, width, budget=512):
     """The engine's step program of ``make_model()`` in both geometries of
-    a 512-token budget (slots -> compiled text), lowered from shapes:
+    a ``budget``-token budget (slots -> compiled text), lowered from shapes:
     ``rows`` rows, pools of ``blocks`` blocks of 64, tables ``width`` wide.
     The parameters stay the zeros `LazyGuard` puts in host memory, never
     initialized. Returns (texts, parameters and buffers, pool arrays)."""
@@ -217,8 +221,8 @@ def _step_texts(one_chip, make_model, rows, blocks, width):
     from paddle_tpu.models.generation import PagedKVCache, layer_states
     from paddle_tpu.models.serving import _StepProgram, _geometries
 
-    geometries = _geometries(512, rows, 0)
-    assert geometries == (256, 512)
+    geometries = _geometries(budget, rows, 0)
+    assert geometries == (budget // 2, budget)
     with paddle.LazyGuard():
         model = make_model()
     model.eval()
@@ -262,7 +266,17 @@ def _aliased(text):
     return {int(m) for m in re.findall(r"\((\d+), \{\}", alias)}
 
 
-def _serving_step_text(one_chip):
+def _pool_parameters(text):
+    """The numbers of the compiled entry computation's parameters that are
+    the step's pools (the program's second argument). Not the pools' place
+    among the arguments: an argument the program never reads has no
+    parameter, and a step reads the rope tables of its first layer only."""
+    entry = text[text.index("\nENTRY "):]
+    names = re.findall(r"[(,] ?(\w+)\.\d+: ", entry[:entry.index("\n", 1)])
+    return {i for i, name in enumerate(names) if name.startswith("pools_")}
+
+
+def _serving_step_text(one_chip, layers=8, budget=512):
     """The engine's step program at chipbench's `mistral-7b-v0.3-serve`:
     Mistral-7B-v0.3 widths, 8 layers, 64 rows and 16 bf16 pools of 4096
     blocks (4 GB of parameters in host memory)."""
@@ -271,12 +285,12 @@ def _serving_step_text(one_chip):
     def make():
         return LlamaForCausalLM(LlamaConfig(
             vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-            num_hidden_layers=8, num_attention_heads=32,
+            num_hidden_layers=layers, num_attention_heads=32,
             num_key_value_heads=8, max_position_embeddings=32768,
             rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16"))
 
-    return _compiled("serving_step", lambda: _step_texts(
-        one_chip, make, rows=64, blocks=4096, width=512))
+    return _compiled(("serving_step", layers, budget), lambda: _step_texts(
+        one_chip, make, rows=64, blocks=4096, width=512, budget=budget))
 
 
 @pytest.mark.parametrize("slots", [512, 256], ids=["budget", "half"])
@@ -286,13 +300,29 @@ def test_serving_step_owns_its_pools(mosaic, one_chip, slots):
     # the per-op path copied a 537 MB pool for each of 16 writes. ISSUE 32:
     # the same holds for the half-width program of a step that packs at
     # most 256 tokens
-    texts, n_state, n_pools = _serving_step_text(one_chip)
+    texts, _, n_pools = _serving_step_text(one_chip)
     text = texts[slots]
     assert n_pools == 16
-    assert _aliased(text) == set(range(n_state, n_state + n_pools))
+    assert len(_pool_parameters(text)) == n_pools
+    assert _aliased(text) == _pool_parameters(text)
     assert not re.search(r"= bf16\[4096,64,8,128\]\S* copy\(", text)
     assert _custom_call_names(text) == {"ragged_paged_attention"}
     assert text.count(CUSTOM_CALL) >= 8          # one call a layer
+
+
+@pytest.mark.parametrize("slots", [1536, 768], ids=["budget", "half"])
+def test_serving_step_at_the_most_tokens_the_kernel_takes(mosaic, one_chip,
+                                                          slots):
+    # ISSUE 40: the packed rows and the packed output are whole in VMEM for
+    # the call, XLA's allocation beside the kernel's own limit, and in the
+    # step program XLA holds the output, that limit and 2.5 MiB to 64 MiB.
+    # `supported()` takes the kernel up to 1536 tokens at these widths (a
+    # step of 1920 does not compile), and the composite past them
+    assert rpa.supported((1536, 32, D), (4096, 64, 8, D))
+    assert not rpa.supported((1537, 32, D), (4096, 64, 8, D))
+    texts, _, _ = _serving_step_text(one_chip, layers=2, budget=1536)
+    assert _custom_call_names(texts[slots]) == {"ragged_paged_attention"}
+    assert texts[slots].count(CUSTOM_CALL) == 2
 
 
 def _jamba_step_text(one_chip):
@@ -315,10 +345,11 @@ def test_jamba_step_owns_its_pools_and_row_state(mosaic, one_chip, slots):
     # convolution's row walk (state tiles copied in and out of HBM by the
     # kernel) and the ragged kernel at 20 query heads on 1 KV head, whose
     # pool is float32 because a bfloat16 one cannot be copied by the block
-    texts, n_state, n_pools = _jamba_step_text(one_chip)
+    texts, _, n_pools = _jamba_step_text(one_chip)
     text = texts[slots]
     assert n_pools == 2 + 2 + 26 + 26
-    assert _aliased(text) == set(range(n_state, n_state + n_pools))
+    assert len(_pool_parameters(text)) == n_pools
+    assert _aliased(text) == _pool_parameters(text)
     for shape in (r"f32\[257,16,40,128\]", r"bf16\[257,3,40,128\]",
                   r"f32\[8448,64,1,128\]"):
         assert not re.search(rf"= {shape}\S* copy\(", text), shape
@@ -326,6 +357,99 @@ def test_jamba_step_owns_its_pools_and_row_state(mosaic, one_chip, slots):
         "ragged_paged_attention", "ragged_selective_scan",
         "ragged_causal_conv"}
     assert text.count(CUSTOM_CALL) == 2 + 26 + 26
+
+
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%(\S+) = (\w+)\[([\d,]*)\](\{[^}]*\})? ([\w\-]+)\((.*)$")
+# what stands between a weight in the program's state and a copy of it: a
+# view, or XLA's own prefetch of the array into VMEM
+_SEES_THROUGH = {"bitcast", "copy-start", "copy-done", "slice-start",
+                 "slice-done", "get-tuple-element", "custom-call"}
+
+
+def _entry_instructions(text):
+    """name -> (dtype, dims, layout, opcode, the rest of its line) for the
+    instructions of the compiled text's entry computation."""
+    entry = text[text.index("\nENTRY "):]
+    found = {}
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(3).split(",") if d)
+            found[m.group(1)] = (m.group(2), dims, m.group(4) or "",
+                                 m.group(5), m.group(6))
+    return found
+
+
+def _reads_state(name, found):
+    """Whether instruction ``name`` is a parameter of the program's state
+    seen through views and prefetches."""
+    while name in found and found[name][3] in _SEES_THROUGH:
+        operand = re.search(r"%([^\s,)]+)", found[name][4])
+        if operand is None:
+            return False
+        name = operand.group(1)
+    return name.startswith("state_arrays")
+
+
+def _op_name(rest):
+    m = re.search(r'op_name="([^"]*)"', rest)
+    return m.group(1) if m else ""
+
+
+STEP_PROGRAMS = {
+    # the step program's texts by slot count; query heads x head_dim
+    "mistral": (lambda c: _serving_step_text(c)[0], 4096),
+    "jamba": (lambda c: _jamba_step_text(c)[0], 2560),
+}
+
+
+@pytest.mark.parametrize("slots", [512, 256], ids=["budget", "half"])
+@pytest.mark.parametrize("program", sorted(STEP_PROGRAMS))
+class TestQueriesStayRows:
+    """ISSUE 40: from `q_proj` to `o_proj` the queries are [T, H*D] rows and
+    nothing reorders their axes. A consumer that wants another order (a
+    [T, H, D] view for rope's tables, a tile pack's transposition) has XLA
+    write `q_proj`'s product tokens-minor and lay the WEIGHT out again every
+    step to get there: 33.5 MB read and written a layer. Properties of the
+    compiled text, so a later change that brings a transposition back fails
+    here, on the CPU."""
+
+    def test_no_relayout_around_the_ragged_kernel(self, mosaic, one_chip,
+                                                  program, slots):
+        text_of, _ = STEP_PROGRAMS[program]
+        for name, (dtype, dims, _, opcode, rest) in _entry_instructions(
+                text_of(one_chip)[slots]).items():
+            if opcode in ("copy", "transpose") \
+                    and "op_ragged_paged_attention" in _op_name(rest):
+                size = int(np.prod(dims)) * _BYTES[dtype]
+                assert size <= 2 ** 20, (name, dtype, dims)
+
+    def test_no_copy_of_the_q_and_k_weights(self, mosaic, one_chip, program,
+                                            slots):
+        text_of, _ = STEP_PROGRAMS[program]
+        found = _entry_instructions(text_of(one_chip)[slots])
+        for name, (_, dims, _, opcode, rest) in found.items():
+            scope = _op_name(rest)
+            if opcode == "copy" and ("/q_proj/" in scope
+                                     or "/k_proj/" in scope):
+                operand = re.search(r"%([^\s,)]+)", rest).group(1)
+                assert not _reads_state(operand, found), (name, dims, scope)
+
+    def test_q_proj_writes_rows(self, mosaic, one_chip, program, slots):
+        text_of, width = STEP_PROGRAMS[program]
+        products = [
+            (name, dims, layout)
+            for name, (_, dims, layout, opcode, rest) in _entry_instructions(
+                text_of(one_chip)[slots]).items()
+            if opcode in ("fusion", "convolution")
+            and _op_name(rest).endswith("self_attn/q_proj/jit(op_linear)"
+                                        "/dot_general")]
+        assert products
+        for name, dims, layout in products:
+            assert dims == (slots, width), (name, dims)
+            assert layout.startswith("{1,0"), (name, layout)
 
 
 def _fused_adamw_text(one_chip):

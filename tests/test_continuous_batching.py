@@ -173,6 +173,31 @@ class TestRaggedScheduling:
         assert results[a] == _greedy_reference(model, short_p, 12)
         assert results[b] == _greedy_reference(model, long_p, 6)
 
+    @pytest.mark.parametrize("thetas", [(10000.0, 10000.0), (10000.0, 50.0)],
+                             ids=["one_theta", "a_theta_a_layer"])
+    def test_each_layers_rope_tables_turn_its_own_rows(self, thetas):
+        # the step gathers the tokens' rope rows once for the layers whose
+        # tables are the same, and again for a layer with tables of its own
+        # (local beside global layers): generate() ropes layer by layer
+        from paddle_tpu.models.llama import LlamaRotaryEmbedding
+        paddle.seed(3)
+        m = LlamaForCausalLM(LlamaConfig(
+            vocab_size=128, hidden_size=64, intermediate_size=160,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, max_position_embeddings=128))
+        m.eval()
+        for layer, theta in zip(m.llama.layers, thetas):
+            layer.self_attn.rotary = LlamaRotaryEmbedding(16, 128, theta)
+        rng = np.random.RandomState(4)
+        prompts = [rng.randint(0, 128, n).tolist() for n in (23, 6, 11)]
+        eng = ContinuousBatchingEngine(
+            m, max_batch=3, num_blocks=32, block_size=16, temperature=0.0,
+            prefill_chunk=8, token_budget=12)
+        rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
+        results = eng.run()
+        for rid, p in zip(rids, prompts):
+            assert results[rid] == _greedy_reference(m, p, 8)
+
     def test_one_executable_across_steps(self, model):
         # fixed row count and two slot counts (half the token budget, and
         # the budget) = static step shapes: one trace and one executable

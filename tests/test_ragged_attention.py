@@ -430,23 +430,6 @@ class TestStream:
         assert [p for _, p in started] == [
             (t, j) for t in range(n_live) for j in range(nblk[t])]
 
-    @pytest.mark.parametrize("case", sorted(_STREAM_CASES))
-    def test_unpack_never_reads_a_padding_tile(self, case):
-        # the rows of the kernel's output past the live tiles are never
-        # written: every token reads a live tile or the appended zero row
-        qlens, _, tokens, _ = _STREAM_CASES[case]
-        cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
-        nt = rpa.num_tiles(len(qlens), tokens)
-        tile_cu = np.concatenate(
-            [[0], np.cumsum([-(-n // rpa.TQ) for n in qlens])])
-        src = np.asarray(rpa._unpack_index(
-            jnp.asarray(cu), jnp.asarray(tile_cu, jnp.int32), tokens, nt))
-        live_rows = rpa.live_tiles(qlens) * rpa.TQ
-        assert live_rows <= nt * rpa.TQ
-        assert ((src < live_rows) | (src == nt * rpa.TQ)).all()
-        assert (src[:cu[-1]] < live_rows).all()
-        assert (src[cu[-1]:] == nt * rpa.TQ).all()
-
     def test_depth_follows_the_visits_bytes(self):
         # one rule for every pool: 8 KV heads in bf16, Jamba's one float32
         # head, 32 heads (a visit of 1 MiB), the int8 pool with its scales
@@ -468,6 +451,171 @@ class TestStream:
         # slots too large for the budget still leave a ring of three
         assert rpa._ring_depth(rpa._RING_BYTES) == (3, 2)
         assert rpa._ring_depth(rpa._RING_BYTES // 5) == (5, 4)
+
+
+# name -> (q_lens, context_lens): what the kernel's own tiling of the packed
+# rows must get right. Six rows over 21 packed tokens, not a multiple of TQ,
+# in every case: one compiled kernel a pool serves them all
+_ROWS_TOKENS = 21
+_ROWS_CASES = {
+    "decode_only": ([1] * 6, [20, 1, 33, 16, 17, 40]),
+    # 13 tokens: a full tile and one of 5 valid rows, whose other 3 rows
+    # are the next rows' tokens in the packed array
+    "chunk_ends_mid_tile": ([1, 13, 1, 1, 0, 0], [30, 13, 9, 21, 0, 0]),
+    "row_with_no_token": ([1, 0, 5, 0, 1, 0], [12, 0, 25, 7, 3, 0]),
+    # the last tile starts at token 18 of 21: its rows run past the array
+    "last_tile_overhangs_the_array": ([1, 11, 1, 5, 3, 0],
+                                      [18, 27, 5, 5, 44, 0]),
+    "step_padding": ([1, 3, 1, 0, 0, 0], [9, 19, 35, 0, 0, 0]),
+}
+# the cells' heads: Mistral's 32 on 8 over a bf16 and an int8 pool, Jamba's
+# 20 on 1 over a float32 pool; bf16 queries, head_dim 128
+_ROWS_POOLS = {"32on8-bf16": (32, 8, jnp.bfloat16),
+               "32on8-int8": (32, 8, jnp.int8),
+               "20on1-f32": (20, 1, jnp.float32)}
+_rows_kernel = jax.jit(rpa.ragged_paged_attention)
+_rows_composite = jax.jit(_ragged_composite)
+
+
+def _rows_layout(case, pool):
+    qlens, ctxs = _ROWS_CASES[case]
+    heads, kv, pool_dtype = _ROWS_POOLS[pool]
+    rng = np.random.RandomState(sorted(_ROWS_CASES).index(case))
+    q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, _ROWS_TOKENS, kv=kv,
+                                      h=heads, d=128, mb=3)
+    q = q.astype(jnp.bfloat16)
+    scales = {}
+    if pool_dtype == jnp.int8:
+        kp, vp, ks, vs = _quantize_pools(kp, vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+        ref_pools = (kp.astype(jnp.float32) * ks[..., None],
+                     vp.astype(jnp.float32) * vs[..., None])
+    else:
+        kp, vp = kp.astype(pool_dtype), vp.astype(pool_dtype)
+        ref_pools = (kp, vp)
+    return (q, kp, vp, tbl, ctx, cu), scales, ref_pools
+
+
+class TestRows:
+    """ISSUE 40: the kernel reads the packed [T, H*D] rows and writes its
+    valid rows itself; no pack, no unpack, no transposition around it."""
+
+    @pytest.mark.parametrize("pool", sorted(_ROWS_POOLS))
+    @pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+    def test_matches_reference_and_composite(self, case, pool):
+        (q, kp, vp, tbl, ctx, cu), scales, ref_pools = _rows_layout(case,
+                                                                    pool)
+        ref = _reference(q, *ref_pools, tbl, ctx, cu, bs=16)
+        got = _rows_kernel(q, kp, vp, tbl, ctx, cu, **scales)
+        assert got.dtype == q.dtype and got.shape == q.shape
+        composite = _rows_composite(q, kp, vp, tbl, ctx, cu, **scales)
+        valid = int(cu[-1])
+        for other in (ref, np.asarray(composite, np.float32)):
+            np.testing.assert_allclose(np.asarray(got, np.float32)[:valid],
+                                       other[:valid], atol=5e-2, rtol=5e-2)
+        # the rows as `q_proj` leaves them are the same call
+        rows = _rows_kernel(q.reshape(q.shape[0], -1), kp, vp, tbl, ctx, cu,
+                            **scales)
+        assert rows.shape == (q.shape[0], q.shape[1] * q.shape[2])
+        np.testing.assert_array_equal(
+            np.asarray(rows, np.float32).reshape(q.shape),
+            np.asarray(got, np.float32))
+
+    @pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+    def test_float32_matches_reference_closely(self, case):
+        qlens, ctxs = _ROWS_CASES[case]
+        rng = np.random.RandomState(7)
+        q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, _ROWS_TOKENS,
+                                          mb=3)
+        ref = _reference(q, kp, vp, tbl, ctx, cu, bs=16)
+        got = np.asarray(_rows_kernel(q, kp, vp, tbl, ctx, cu))
+        np.testing.assert_allclose(got[:cu[-1]], ref[:cu[-1]], atol=2e-5,
+                                   rtol=2e-5)
+
+    @pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+    def test_a_tile_stores_its_valid_rows_only(self, case):
+        # the tokens past cu[R] belong to no tile and read zeros, whatever
+        # q holds there and however the last live tile overhangs them; and
+        # a row's tokens come out the same to the bit whether or not the
+        # rows around it are in the step, partly valid tiles and all
+        qlens, ctxs = _ROWS_CASES[case]
+        rng = np.random.RandomState(11)
+        q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, _ROWS_TOKENS,
+                                          mb=3, dtype=jnp.bfloat16)
+        got = np.asarray(_rows_kernel(q, kp, vp, tbl, ctx, cu), np.float32)
+        assert np.abs(got[int(cu[-1]):]).max(initial=0.0) == 0.0
+        qlens = np.diff(np.asarray(cu))
+        for r in np.flatnonzero(qlens):
+            alone = np.where(np.arange(len(qlens)) == r, qlens, 0)
+            cu_r = np.concatenate([[0], np.cumsum(alone)]).astype(np.int32)
+            lo, n = int(cu[r]), int(qlens[r])
+            # row r's tokens moved to the front of an otherwise empty step
+            q_r = jnp.concatenate([q[lo:lo + n], q[:q.shape[0] - n]])
+            out = np.asarray(_rows_kernel(
+                q_r, kp, vp, tbl, ctx, jnp.asarray(cu_r)), np.float32)
+            np.testing.assert_array_equal(out[:n], got[lo:lo + n])
+            assert np.abs(out[n:]).max(initial=0.0) == 0.0
+
+    # the packed rows and the packed output are whole in VMEM, XLA's
+    # allocation beside the kernel's own limit, so the step's tokens are
+    # bounded: 48 MiB of rows (tests/test_chip_compile.py compiles the
+    # bound)
+    @pytest.mark.parametrize("tokens, heads, kv, fits", [
+        (512, 32, 8, True),        # the Mistral cells' budget
+        (512, 32, 32, True),       # chip_smoke
+        (512, 20, 1, True),        # the Jamba cell's budget
+        (1024, 32, 8, True),
+        (1536, 32, 8, True),
+        (1537, 32, 8, False),
+        (2048, 32, 8, False),
+        (2048, 20, 1, True),
+        (2464, 20, 1, False),
+    ])
+    def test_supported_holds_the_rows_to_vmem(self, tokens, heads, kv, fits):
+        assert rpa.supported((tokens, heads, 128),
+                             (4096, 64, kv, 128)) == fits
+
+    def test_a_step_over_the_vmem_takes_the_composite(self, monkeypatch):
+        # a token budget whose rows do not fit is served like any other
+        # unsupported shape, and does not fail in Mosaic at warm-up
+        from paddle_tpu import flags
+        rng = np.random.RandomState(13)
+        qlens, ctxs = _ROWS_CASES["chunk_ends_mid_tile"]
+        args = _layout(rng, qlens, ctxs, 19, mb=3)
+        ref = _reference(*args, bs=16)
+        monkeypatch.setattr(rpa, "_ROWS_BYTES", 16 * 1024)
+        assert not rpa.supported(args[0].shape, args[1].shape)
+        monkeypatch.setattr(
+            rpa, "ragged_paged_attention",
+            lambda *a, **k: pytest.fail("the kernel was asked"))
+        rows = args[0].reshape(19, -1)
+        prev = flags.get_flag("use_pallas_kernels")
+        flags.set_flags({"use_pallas_kernels": True})
+        try:
+            got = call_op("ragged_paged_attention", Tensor(rows),
+                          *map(Tensor, args[1:])).numpy()
+        finally:
+            flags.set_flags({"use_pallas_kernels": prev})
+        assert got.shape == rows.shape
+        valid = int(args[5][-1])
+        np.testing.assert_allclose(got.reshape(args[0].shape)[:valid],
+                                   ref[:valid], atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_rope_rows_is_rope_on_the_rows(self, dtype):
+        from paddle_tpu.ops.kernels.nn import rope, rope_rows
+        rng = np.random.RandomState(5)
+        x = jnp.asarray(rng.randn(11, 4, 32), dtype)
+        angle = rng.uniform(0, 6.28, (64, 16)).astype(np.float32)
+        angle = np.concatenate([angle, angle], axis=1)
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        pos = jnp.asarray(rng.randint(0, 64, 11), jnp.int32)
+        want = rope(x[None], cos=cos, sin=sin, position_ids=pos[None])[0]
+        got = rope_rows(x.reshape(11, -1), cos[pos], sin[pos]).reshape(x.shape)
+        assert got.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 @pytest.mark.skipif(jax.device_count() < 8,
