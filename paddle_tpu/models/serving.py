@@ -110,6 +110,11 @@ _M_STEP_SLOTS = _M.counter(
     "serving.step_slots",
     "token slots of the geometries the steps ran (step_tokens over this "
     "is the share of the model's rows that carried a token)")
+_M_TOKEN_BLOCKS = _M.counter(
+    "serving.attention.token_blocks",
+    "(tile, kv block) pairs of one layer's ragged attention call whose tile "
+    "holds one token, the one-token body's visits (a step's "
+    "kv_token_blocks)")
 _M_OVERLAPPED = _M.counter(
     "serving.pipeline.overlapped",
     "steps launched while the step before them was still in flight (over "
@@ -1325,6 +1330,8 @@ class ContinuousBatchingEngine:
             # tile x table-column pairs of the whole grid
             kv_live_tiles = _rpa.live_tiles(qlen)
             kv_tile_blocks = _rpa.live_tile_blocks(qlen, lens, bs)
+            # of those, the visits of tiles of one token (decode rows)
+            kv_token_blocks = _rpa.live_token_blocks(qlen, lens, bs)
             kv_table_blocks = (_rpa.num_tiles(R, T)
                                * self.cache.block_tables.shape[1])
 
@@ -1383,6 +1390,7 @@ class ContinuousBatchingEngine:
             _M_STEPS.inc()
             _M_STEP_TOKENS.inc(t)
             _M_STEP_SLOTS.inc(T)
+            _M_TOKEN_BLOCKS.inc(kv_token_blocks)
         # the phase's own edges: dispatch began, its last async launch
         # returned (none with FLAGS_tracing off: the ledger row then counts
         # its calls only)
@@ -1400,6 +1408,7 @@ class ContinuousBatchingEngine:
                        "launches": launches,
                        "kv_live_tiles": kv_live_tiles,
                        "kv_tile_blocks": kv_tile_blocks,
+                       "kv_token_blocks": kv_token_blocks,
                        "kv_table_blocks": kv_table_blocks,
                        **step_attrs})
         return _Launched(nxt, post, drafts, dequant_blocks, t0_ns, td_ns,

@@ -96,6 +96,7 @@ METRIC_NAMES = frozenset({
     "serving.prefix_cache.shared_tokens", "serving.prefix_cache.evictions",
     "serving.cow_copies", "serving.ttft_seconds", "serving.tpot_seconds",
     "serving.queue_wait_seconds", "serving.rejected", "serving.step.traces",
+    "serving.attention.token_blocks",
     # int8 paged KV pool + speculative decoding (models/serving.py,
     # ops/kernels/serving.py)
     "serving.kv.bytes_per_token", "serving.kv.dequant_blocks",

@@ -63,6 +63,21 @@ after them. So both the arithmetic and the copies scale with
 ``sum_tiles(nblk) ~ sum(q_len_r * context_len_r) / (TQ * BS)``, not with
 the padded ``NT x MB`` rectangle of tiles and table columns.
 
+A tile of ONE valid token (a decode row; a chunk's last tile when one token
+is left for it) runs a body of its own: its packed row, reshaped,
+is the ``[KV, Gp, D]`` operand, head ``h`` in row ``h % G`` of kv head
+``h // G``, ``Gp = G`` rounded up to 8 sublanes, with ``(m, l, acc)`` in
+scratch of that height; its mask is the token's causal horizon and rows
+``< G``. At Jamba's 20 heads on one KV head that is 24 rows where the full
+body computes 160, of which a decode row fills 20. At so few rows a visit is
+its chain's latency (product, max, exp, product), so the one-token body
+walks its blocks two a step: both waited for, both computed in one stretch,
+the second's products overlapping the first's softmax; the second's fetch
+ahead goes to the first's slot once it is computed. The values are the full
+body's for the same row, bit for bit. Where ``Gp`` is no less than
+``TQ * G`` (G = 1) only the full body is compiled (``_token_rows``); the
+scalar-prefetched valid count picks the body a tile runs.
+
 The live tiles are the first ``tile_cu[R]`` of the grid and the stream
 ends with the last of them: a padding tile's grid step starts no copy,
 loads no row, computes nothing and stores nothing.
@@ -89,6 +104,7 @@ from .flash_attention import _interpret  # shared interpret override
 _NEG = -1e30
 
 TQ = 8  # query tokens per tile (f32 sublane)
+_SUBLANES = 8  # float32 rows a vreg holds
 
 # what the rings of one call may hold in VMEM; the kernel's VMEM limit, for
 # the rings, the tile's operand, (m, l, acc) and the float32 copies of one
@@ -139,17 +155,28 @@ def _ring_depth(visit_bytes: int):
     return slots, slots - 1
 
 
+def _token_rows(g) -> int:
+    """``Gp``: the query rows of the one-token body, a tile's ``G`` heads a
+    KV head rounded up to whole float32 sublanes; 0 where that is no fewer
+    than the full body's ``TQ * G`` (G = 1), which then serves every tile."""
+    gp = g + -g % _SUBLANES
+    return gp if gp < TQ * g else 0
+
+
 def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
             tbl_ref, q_ref, *rest, bs, g, scale, quantized, slots, ahead):
     n_pool = 4 if quantized else 2           # k, v (+ their scale tiles)
     pools, o_ref = rest[:n_pool], rest[n_pool]
     bufs = rest[n_pool + 1:2 * n_pool + 1]
-    sem, cur, q_scr, m_scr, l_scr, acc_scr = rest[2 * n_pool + 1:]
+    sem, cur, *scratch = rest[2 * n_pool + 1:]
+    # (q, m, l, acc) of the full body, then of the one-token body if any
+    full, token = scratch[:4], scratch[4:]
     t = pl.program_id(0)
     row, tok0, qp0 = row_ref[t], tok0_ref[t], qp0_ref[t]
-    qc, nblk = qc_ref[t], nblk_ref[t]
+    qc, nblk, pair0 = qc_ref[t], nblk_ref[t], pair0_ref[t]
     pairs = pair0_ref[pl.num_programs(0)]    # P: the call's live pairs
-    heads, d = q_scr.shape[0] * g, q_scr.shape[2]
+    kvh, d = full[0].shape[0], full[0].shape[2]
+    heads = kvh * g
 
     def valid_tokens(visit):
         # visit(packed row, [head h's (kv head, row of the tile's operand)])
@@ -192,18 +219,21 @@ def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
             def _():
                 fetch(f)
 
-    def body(j, carry):
-        f = pair0_ref[t] + j
-        slot = f % slots
-
+    def fetch_ahead(f):
         # the slot of pair f + AHEAD held a pair before f, consumed by now:
-        # fill it while this visit waits for and works on its own
+        # fill it while visit f waits for and works on its own
         @pl.when(f + ahead < pairs)
         def _():
             fetch(f + ahead)
 
+    def wait(j, slot):
         for c in copies(row, j, slot):
             c.wait()
+
+    def attend(j, slot, scr, live_of, guard):
+        # the tile's queries against block j, in its slot: online-softmax
+        # state (m, l, acc) in VMEM across the tile's visits
+        q_scr, m_scr, l_scr, acc_scr = scr
         q = q_scr[...]                                         # [KV, TG, D]
         kf = bufs[0][slot].astype(jnp.float32)                 # [BS, KV, D]
         vf = bufs[1][slot].astype(jnp.float32)
@@ -219,13 +249,13 @@ def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale        # [KV, TG, BS]
         kvpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        qlocal = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % TQ
-        live = (kvpos <= qp0 + qlocal) & (qlocal < qc)
+        live = live_of(kvpos, jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
         s = jnp.where(live, s, _NEG)
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(live, p, 0.0)   # exp(-1e30 - -1e30) = 1 guard
+        if guard:
+            p = jnp.where(live, p, 0.0)   # exp(-1e30 - -1e30) = 1 guard
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
         pv = jax.lax.dot_general(
@@ -234,13 +264,72 @@ def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
         l_scr[...] = l_new
-        return carry
 
-    # a live tile: its LIVE blocks only, the causal horizon of its last
-    # token bounds every kv position any of its tokens may see (nblk). A
-    # padding tile (the tail of the grid) is past the stream's end
-    @pl.when(t < live_ref[0])
-    def _():
+    def full_live(kvpos, sub):
+        qlocal = sub % TQ
+        return (kvpos <= qp0 + qlocal) & (qlocal < qc)
+
+    def full_visits():
+        def visit(j, carry):
+            f = pair0 + j
+            slot = f % slots
+            fetch_ahead(f)
+            wait(j, slot)
+            attend(j, slot, full, full_live, guard=True)
+            return carry
+
+        jax.lax.fori_loop(0, nblk, visit, 0)
+
+    def token_live(kvpos, sub):
+        # the one token at qp0 sees every position up to its own, and
+        # position 0 is in the first block: a valid row's running max is
+        # a score from its first visit on, so a masked exp is 0 without
+        # the guard. Rows G.. of the operand are padding
+        return (kvpos <= qp0) & (sub < g)
+
+    def token_visits():
+        # two visits a step: both blocks waited for, then both computed
+        # in one stretch, so the second's products overlap the first's
+        # softmax (at Gp rows a visit is its chain's latency). The second
+        # visit's fetch ahead goes to the first's slot, free once it is
+        # computed
+        def two(i, carry):
+            j = 2 * i
+            f = pair0 + j
+            slot, slot1 = f % slots, (f + 1) % slots
+            fetch_ahead(f)
+            wait(j, slot)
+            wait(j + 1, slot1)
+            attend(j, slot, token, token_live, guard=False)
+            attend(j + 1, slot1, token, token_live, guard=False)
+            fetch_ahead(f + 1)
+            return carry
+
+        jax.lax.fori_loop(0, nblk // 2, two, 0)
+
+        @pl.when(nblk % 2 == 1)
+        def _():
+            f = pair0 + nblk - 1
+            slot = f % slots
+            fetch_ahead(f)
+            wait(nblk - 1, slot)
+            attend(nblk - 1, slot, token, token_live, guard=False)
+
+    def tile(scr, load, visits, store):
+        # a live tile: its LIVE blocks only, the causal horizon of its last
+        # token bounds every kv position any of its tokens may see (nblk)
+        q_scr, m_scr, l_scr, acc_scr = scr
+        load(q_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        visits()
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)   # fully-masked padding lanes
+        acc_scr[...] = acc_scr[...] / l_safe
+        store(acc_scr)
+
+    def full_load(q_scr):
         def load(tok, rows):
             q_tok = q_ref[tok, :]                              # [1, H*D]
             for h, (kv, r) in enumerate(rows):
@@ -249,14 +338,8 @@ def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
         # the tile's rows of the packed queries; a row past its valid count
         # keeps what an earlier tile left there, masked like any padding
         valid_tokens(load)
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        jax.lax.fori_loop(0, nblk, body, 0)
-        l = l_scr[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)   # fully-masked padding lanes
-        acc_scr[...] = acc_scr[...] / l_safe
 
+    def full_store(acc_scr):
         def store(tok, rows):
             o_ref[tok, :] = jnp.concatenate(
                 [acc_scr[kv, r, :] for kv, r in rows], axis=1)
@@ -264,6 +347,29 @@ def _kernel(row_ref, tok0_ref, qp0_ref, qc_ref, nblk_ref, pair0_ref, live_ref,
         # only the valid rows: the next tile's tokens are this one's
         # neighbours in the packed output
         valid_tokens(store)
+
+    def token_load(q_scr):
+        # head h to row h % G of kv head h // G: the packed row is the
+        # [KV, G, D] operand, its padding rows untouched
+        q_scr[:, :g, :] = q_ref[pl.ds(tok0, 1), :].reshape(kvh, g, d)
+
+    def token_store(acc_scr):
+        o_ref[pl.ds(tok0, 1), :] = acc_scr[:, :g, :].reshape(1, heads * d)
+
+    # a padding tile (the tail of the grid) is past the stream's end
+    live = t < live_ref[0]
+    if token:
+        # a tile of one valid token (a decode row, a chunk's last tile of
+        # one) runs the body at Gp rows, not at TQ * G
+        @pl.when(live & (qc == 1))
+        def _():
+            tile(token, token_load, token_visits, token_store)
+
+        live = live & (qc != 1)
+
+    @pl.when(live)
+    def _():
+        tile(full, full_load, full_visits, full_store)
 
 
 def _tile_metadata(cu, ctx, nt, bs, mb):
@@ -322,6 +428,17 @@ def live_tile_blocks(q_lens, context_lens, block_size) -> int:
     return int(((end + block_size - 1) // block_size).sum())
 
 
+def live_token_blocks(q_lens, context_lens, block_size) -> int:
+    """The pairs of ``live_tile_blocks`` whose tile holds one valid token (a
+    decode row; a chunk's last tile when one token is left for it): the
+    visits of the one-token body, where the kernel has one
+    (``_token_rows``). Such a tile is its row's last, so its horizon is the
+    row's context."""
+    qlen = np.asarray(q_lens, np.int64)
+    ctx = np.asarray(context_lens, np.int64)
+    return int(((ctx[qlen % TQ == 1] + block_size - 1) // block_size).sum())
+
+
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                            cu_q_lens, scale=None, k_scale=None,
                            v_scale=None):
@@ -368,6 +485,13 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
             for s in (k_scale, v_scale)]
         blocks += [((KV, bsp), jnp.float32)] * 2
     slots, ahead = _ring_depth(sum(_vmem_bytes(*b) for b in blocks))
+    # the tile's queries and online-softmax state (m, l, acc): the full
+    # body's [KV, TQ*G, .], and the one-token body's [KV, Gp, .] where G
+    # heads a KV head leave it shorter
+    gp = _token_rows(G)
+    state = [pltpu.VMEM(shape, jnp.float32)
+             for n in ((TG, gp) if gp else (TG,))
+             for shape in ((KV, n, D), (KV, n, 1), (KV, n, 1), (KV, n, D))]
     # the packed rows, whole, in VMEM for the call: XLA's allocation, which
     # the fusion before the call writes and `o_proj`'s reads where they are
     rows_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
@@ -382,10 +506,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                         for shape, dtype in blocks] + [
             pltpu.SemaphoreType.DMA((len(operands), slots)),
             pltpu.SMEM((2,), jnp.int32),      # the fetch cursor (tile, j)
-            pltpu.VMEM((KV, TG, D), jnp.float32),     # the tile's queries
-            pltpu.VMEM((KV, TG, 1), jnp.float32),
-            pltpu.VMEM((KV, TG, 1), jnp.float32),
-            pltpu.VMEM((KV, TG, D), jnp.float32)],
+            *state],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bs=BS, g=G, scale=float(scale),

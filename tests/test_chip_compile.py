@@ -189,6 +189,42 @@ def test_ragged_paged_attention(mosaic, one_chip, shapes, pool_dtype):
     assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype, shapes)
 
 
+def _ragged_bodies(shapes, pool_dtype):
+    """The query rows of each body the kernel keeps state for at ``shapes``:
+    its scratch after the fetch cursor, (q, m, l, acc) a body."""
+    args, scales = _ragged_args(shapes, pool_dtype, lambda spec: None)
+    jaxpr = jax.make_jaxpr(
+        lambda *a, **k: rpa.ragged_paged_attention(*a, **k))(*args, **scales)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    n = call.params["grid_mapping"].num_scratch_operands
+    scratch = [v.aval for v in call.params["jaxpr"].invars[-n:]]
+    cursor = next(i for i, a in enumerate(scratch)
+                  if a.shape == (2,) and a.dtype == jnp.int32)
+    return [a.shape[1] for a in scratch[cursor + 1::4]]
+
+
+@pytest.mark.parametrize("shapes, pool_dtype, rows", [
+    ("jamba2-3b-cell", jnp.float32, [160, 24]),
+    ("jamba2-3b-cell-half", jnp.float32, [160, 24]),
+    ("mistral-7b-cell", jnp.bfloat16, [32, 8]),
+    ("mistral-7b-cell", jnp.int8, [32, 8]),
+    ("llama2-7b", jnp.bfloat16, [8]),
+], ids=["jamba-f32", "jamba-half-f32", "mistral-bf16", "mistral-int8",
+        "llama2-mha-bf16"])
+def test_ragged_one_token_body(mosaic, one_chip, shapes, pool_dtype, rows):
+    # a tile of one valid token runs a body on G heads a KV head
+    # rounded up to 8 rows (24 at Jamba's 20 on 1, 8 at Mistral's 4 on 1),
+    # beside the full body's TQ * G; Mosaic takes both, the load and store
+    # of the packed row as one reshape, and the visits two at a time. At
+    # G = 1 the full body is 8 rows already and is the only one compiled.
+    # The step programs of both cells compile this kernel in both
+    # geometries (test_serving_step_owns_its_pools,
+    # test_jamba_step_owns_its_pools_and_row_state)
+    assert CUSTOM_CALL in _ragged_text(one_chip, pool_dtype, shapes)
+    assert _ragged_bodies(shapes, pool_dtype) == rows
+
+
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 def test_sharded_ragged_on_the_hybrid_mesh(mosaic, topo, pool_dtype):

@@ -680,6 +680,9 @@ class TestStepPhases:
         # these in the parent and are min(AHEAD, pairs) a call now
         assert [s.attrs["kv_live_tiles"] for s in steps] == [
             1, 1 + 3, 1 + 2]
+        # of those pairs, the one-token tiles' (A's decode rows;
+        # no chunk here leaves a tile of one token)
+        assert [s.attrs["kv_token_blocks"] for s in steps] == [0, 2, 2]
         # 3 rows + ceil(slots / 8) tiles, each against every table column:
         # the table follows the geometry the step ran (ISSUE 32), 12 slots
         # for step 1's 6 tokens, the budget's 25 for 25 and 17 tokens
@@ -687,6 +690,35 @@ class TestStepPhases:
         width = eng.cache.block_tables.shape[1]
         assert [s.attrs["kv_table_blocks"] for s in steps] == [
             (3 + 2) * width, (3 + 4) * width, (3 + 4) * width]
+
+    def test_step_span_counts_one_token_tiles(self, model):
+        # a served mix of decode rows and chunks, one of which
+        # leaves a tile of one token. Block size 4, tiles of 8 tokens:
+        #   step 1  A prefills 6 tokens: no one-token tile
+        #   step 2  A decodes at context 7 (2 blocks); B's first chunk of 24
+        #   step 3  A decodes at 8 (2); B's last 9 tokens, context 33: tiles
+        #           of 8 and of 1, the last ending at 33 (9 blocks)
+        #   step 4  A decodes at 9 (3), B at 34 (9)
+        from paddle_tpu.observability import tracing
+        tracing.clear()
+        rng = np.random.RandomState(7)
+        eng = ContinuousBatchingEngine(model, max_batch=3, num_blocks=64,
+                                       block_size=4, temperature=0.0,
+                                       token_budget=25, prefill_chunk=24)
+        blocks0 = _metric("serving.attention.token_blocks")
+        eng.add_request(rng.randint(0, 128, 6).tolist(), max_new_tokens=8)
+        eng.step()
+        eng.add_request(rng.randint(0, 128, 33).tolist(), max_new_tokens=8)
+        for _ in range(3):
+            eng.step()
+        steps = [s for s in tracing.finished_spans("serving.step")
+                 if s.name == "serving.step"]
+        assert [s.attrs["kv_token_blocks"] for s in steps] == [
+            0, 2, 2 + 9, 3 + 9]
+        assert [s.attrs["kv_tile_blocks"] for s in steps] == [
+            2, 2 + 2 + 4 + 6, 2 + 8 + 9, 3 + 9]
+        assert _metric("serving.attention.token_blocks") - blocks0 == sum(
+            s.attrs["kv_token_blocks"] for s in steps)
 
     def test_idle_step_records_admit_only(self, model):
         from paddle_tpu.observability import tracing

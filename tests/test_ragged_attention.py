@@ -244,6 +244,9 @@ _LOOP_CASES = {
     "empty_rows_between": ([1, 0, 0, 9, 0, 1], [20, 0, 7, 9, 0, 33]),
     # speculative verify rows: q_len = K + 1 = 3 inside one tile
     "verify_row": ([3, 1, 3, 3], [35, 16, 3, 48]),
+    # chunks of 9 and 17 tokens: a last tile of one token, the one-token
+    # body's like a decode row's, beside the full body's tiles
+    "chunk_last_tile_one_token": ([9, 1, 17], [9, 40, 33]),
 }
 
 
@@ -292,6 +295,21 @@ class TestLiveBlockLoop:
             assert rpa.live_tile_blocks(qlens, ctxs, bs) == int(
                 np.asarray(nblk).sum())
 
+    @pytest.mark.parametrize("case", sorted(_LOOP_CASES))
+    def test_host_token_count_is_the_one_token_tiles_trip_count(self, case):
+        # the engine's kv_token_blocks (numpy) against the trip
+        # counts of the tiles whose valid count is 1, the one-token body's
+        qlens, ctxs = _LOOP_CASES[case]
+        cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
+        nt = rpa.num_tiles(len(qlens), 48)
+        for bs in (4, 16, 64):
+            *_, qcount, _, nblk, _ = rpa._tile_metadata(
+                jnp.asarray(cu), jnp.asarray(ctxs, jnp.int32), nt, bs, 512)
+            one = np.asarray(qcount) == 1
+            assert rpa.live_token_blocks(qlens, ctxs, bs) == int(
+                np.asarray(nblk)[one].sum())
+            assert int(one.sum()) == sum(n % rpa.TQ == 1 for n in qlens)
+
 
 # name -> (q_lens, context_lens, packed tokens, `_layout` keywords): what
 # the stream over a call's live (tile, block) pairs must get right where
@@ -321,6 +339,12 @@ _STREAM_CASES = {
     # Jamba's attention layers: 20 query heads on 1 KV head
     "mqa_20_on_1": ([1, 9, 1, 0, 1], [40, 9, 70, 0, 16], 16,
                     {"h": 20, "kv": 1, "mb": 5}),
+    # decode rows of 1 to 6 blocks, odd and even, and chunks whose last
+    # tile holds one token: the one-token body walks its blocks two a step
+    "chunk_last_tile_one_token": ([1, 9, 1, 17, 1], [40, 9, 96, 33, 17], 32,
+                                  {}),
+    # one query head a KV head: the full body is already one token's rows
+    "one_head_a_kv_head": ([1, 9, 1], [40, 9, 70], 16, {"h": 2, "kv": 2}),
 }
 
 
@@ -376,9 +400,10 @@ class TestStream:
         slots, ahead = depth
         qlens, ctxs, tokens, kw = _STREAM_CASES[case]
         bs, mb = kw.get("bs", 16), kw.get("mb", 6)
+        token_body = rpa._token_rows(kw.get("h", 4) // kw.get("kv", 2)) > 0
         cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
         nt = rpa.num_tiles(len(qlens), tokens)
-        tile_cu, row_of, _, _, _, nblk, pair0 = (
+        tile_cu, row_of, _, qcount, _, nblk, pair0 = (
             np.asarray(a) for a in rpa._tile_metadata(
                 jnp.asarray(cu), jnp.asarray(ctxs, jnp.int32), nt, bs, mb))
         n_live, pairs = int(tile_cu[-1]), int(pair0[nt])
@@ -413,16 +438,28 @@ class TestStream:
                 assert len(started) == min(ahead, pairs)
             if t >= n_live:
                 continue        # a padding tile: no copy, no wait
-            for j in range(nblk[t]):
-                f = int(pair0[t]) + j
-                if f + ahead < pairs:
-                    fetch(f + ahead)
-                # the visit waits for the copy the cursor started for it
-                assert holds[f % slots] == ((t, j), False)
-                waited.append((f, (t, j)))
-                in_flight -= 1
-                assert in_flight <= ahead
-                holds[f % slots] = ((t, j), True)
+            # a step of the tile's loop: one visit, or two for a tile of
+            # one token (both waited for, then both computed, then the
+            # second's fetch ahead, into the first's slot)
+            step = 2 if token_body and qcount[t] == 1 else 1
+            for j0 in range(0, nblk[t], step):
+                js = range(j0, min(j0 + step, nblk[t]))
+                f0 = int(pair0[t]) + j0
+                if f0 + ahead < pairs:
+                    fetch(f0 + ahead)
+                for j in js:
+                    f = int(pair0[t]) + j
+                    # the visit waits for the copy the cursor started for it
+                    assert holds[f % slots] == ((t, j), False)
+                    waited.append((f, (t, j)))
+                    in_flight -= 1
+                    assert in_flight <= ahead
+                for j in js:
+                    holds[(int(pair0[t]) + j) % slots] = ((t, j), True)
+                for j in js[1:]:
+                    f = int(pair0[t]) + j
+                    if f + ahead < pairs:
+                        fetch(f + ahead)
         # every copy started is waited for exactly once, by its own visit,
         # and each live pair was fetched, in the grid's order
         assert started == waited and in_flight == 0
@@ -467,12 +504,20 @@ _ROWS_CASES = {
     "last_tile_overhangs_the_array": ([1, 11, 1, 5, 3, 0],
                                       [18, 27, 5, 5, 44, 0]),
     "step_padding": ([1, 3, 1, 0, 0, 0], [9, 19, 35, 0, 0, 0]),
+    # a 17-token chunk at context 40: tiles of 8, 8 and 1 token, the last
+    # one's row read and written by the one-token body
+    "chunk_last_tile_one_token": ([1, 17, 1, 0, 1, 1],
+                                  [12, 40, 9, 0, 33, 16]),
 }
 # the cells' heads: Mistral's 32 on 8 over a bf16 and an int8 pool, Jamba's
-# 20 on 1 over a float32 pool; bf16 queries, head_dim 128
+# 20 on 1 over a float32 pool; bf16 queries, head_dim 128. And G = 1 (no
+# one-token body), G = 4 on one KV head, G = 20 over an int8 pool
 _ROWS_POOLS = {"32on8-bf16": (32, 8, jnp.bfloat16),
                "32on8-int8": (32, 8, jnp.int8),
-               "20on1-f32": (20, 1, jnp.float32)}
+               "20on1-f32": (20, 1, jnp.float32),
+               "8on8-bf16": (8, 8, jnp.bfloat16),
+               "4on1-f32": (4, 1, jnp.float32),
+               "20on1-int8": (20, 1, jnp.int8)}
 _rows_kernel = jax.jit(rpa.ragged_paged_attention)
 _rows_composite = jax.jit(_ragged_composite)
 
@@ -623,19 +668,28 @@ class TestRows:
 class TestShardedRagged:
     def test_matches_unsharded_reference(self):
         rng = np.random.RandomState(7)
-        qlens, ctxs = [1, 12, 10, 1], [20, 12, 37, 49]
-        q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, 32, kv=4, h=8)
         mesh = jax.make_mesh((4,), ("mp",))
-        out = tpa.sharded_ragged_paged_attention(q, kp, vp, tbl, ctx, cu,
-                                                 mesh, "mp")
-        assert out is not None
-        ref = rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, cu)
-        assert out.dtype == ref.dtype
-        np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
-                                   np.asarray(ref)[:cu[-1]],
-                                   atol=2e-5, rtol=2e-5)
-        # heads really ride the mp axis
-        assert out.sharding.spec[1] == "mp"
+        # decode rows and chunks at 2 heads on 1 KV head a shard; chunks
+        # whose last tile holds one token; Jamba's 20 on 1 in each shard
+        for qlens, ctxs, h in (([1, 12, 10, 1], [20, 12, 37, 49], 8),
+                               ([1, 9, 17, 1], [20, 9, 40, 49], 8),
+                               ([1, 9, 1, 1], [20, 9, 37, 49], 80)):
+            q, kp, vp, tbl, ctx, cu = _layout(rng, qlens, ctxs, 32, kv=4,
+                                              h=h)
+            out = tpa.sharded_ragged_paged_attention(q, kp, vp, tbl, ctx,
+                                                     cu, mesh, "mp")
+            assert out is not None
+            ref = rpa.ragged_paged_attention(q, kp, vp, tbl, ctx, cu)
+            assert out.dtype == ref.dtype
+            np.testing.assert_allclose(np.asarray(out)[:cu[-1]],
+                                       np.asarray(ref)[:cu[-1]],
+                                       atol=2e-5, rtol=2e-5)
+            np.testing.assert_allclose(
+                np.asarray(out)[:cu[-1]],
+                _reference(q, kp, vp, tbl, ctx, cu, bs=16)[:cu[-1]],
+                atol=2e-5, rtol=2e-5)
+            # heads really ride the mp axis
+            assert out.sharding.spec[1] == "mp"
 
     def test_int8_sharded_matches_unsharded_quantized(self):
         # scale tiles shard with the pool's kv-head axis: the sharded
