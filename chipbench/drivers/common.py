@@ -140,8 +140,12 @@ class Slice:
         self.t0: Optional[float] = None
         self.t1: Optional[float] = None
 
+    def due(self, now: float) -> bool:
+        """The next ``tick(now)`` starts the profiler."""
+        return self.on and self.t0 is None and now >= self.begin_at
+
     def tick(self, now: float) -> None:
-        if self.on and self.t0 is None and now >= self.begin_at:
+        if self.due(now):
             shutil.rmtree(self.dir, ignore_errors=True)
             trace.start(self.dir)
             self.t0 = time.perf_counter()

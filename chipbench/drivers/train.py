@@ -1,15 +1,21 @@
 """Training cells: a closed loop of ``TrainStep`` steps, each on a fresh
 batch of seeded random token ids fetched from a host-side generator.
 
-A step is fetched, run and waited for (``block_until_ready`` of the updated
-parameters) before the next begins, so the host's clock around a step is the
-step. Random tokens cannot be learned: the loss stays near ln(vocab), and
-the check is agreement with the reference and finite losses, not a falling
-loss.
+In the window the host keeps ``dispatch_ahead_s`` seconds of steps launched
+ahead of the one it waits for, as a training loop that reads its losses late
+does: a host that stands still for less than that leaves the chip fed. The
+runtime may queue fewer: a launch behind as many as it holds waits for room
+(each step records ``queued``, the steps in flight at its launch). When
+the window's time is up nothing more is launched, every launched step is
+waited for, and the clock is read after that wait: the rate is all of those
+steps over all of that time. Random tokens cannot be learned: the loss stays
+near ln(vocab), and the check is agreement with the reference and finite
+losses, not a falling loss.
 """
 
 from __future__ import annotations
 
+import collections
 import math
 import time
 from statistics import median
@@ -56,11 +62,14 @@ def run(ctx: Context) -> Result:
         with jax.profiler.TraceAnnotation("chipbench.fetch"):
             return Tensor(jnp.asarray(next(batches)))
 
-    def step(ids):
+    def launch(ids):
         with jax.profiler.TraceAnnotation("chipbench.train_step"):
-            loss = train((ids,), (ids,))
-            jax.block_until_ready([p._data for p in params])
-        return loss._data
+            return train((ids,), (ids,))._data
+
+    def step(ids):
+        loss = launch(ids)
+        jax.block_until_ready([p._data for p in params])
+        return loss
 
     # the reference first: the first step donates the weights it reads
     t = time.perf_counter()
@@ -74,26 +83,53 @@ def run(ctx: Context) -> Result:
     t = time.perf_counter()
     first_loss = float(step(first))
     for _ in range(spec["warmup_steps"] - 1):
+        t_step = time.perf_counter()
         step(fetch())
+    # the last warm-up step is a compiled one: it sets how many steps are
+    # launched ahead in the window
+    ahead = max(1, math.ceil(cell["dispatch_ahead_s"]
+                             / (time.perf_counter() - t_step)))
     parts["compile_and_warmup"] = time.perf_counter() - t
 
     compiles = CompileCounter()
     t_window = time.perf_counter()
     setup_s = t_window - ctx.t_process
-    tracer = Slice(ctx, t_window, cell["trace_slice_s"])
+    # the slice is due ``dispatch_ahead_s`` early: the steps in flight are
+    # waited for first, so that it still holds ``trace_slice_s`` of launches
+    tracer = Slice(ctx, t_window,
+                   cell["trace_slice_s"] + cell["dispatch_ahead_s"])
     steps: List[Dict] = []
     losses = []
+    waiting = collections.deque()    # indices of launched, unwaited steps
+
+    def wait_one():
+        i = waiting.popleft()
+        with jax.profiler.TraceAnnotation("chipbench.wait"):
+            jax.block_until_ready(losses[i])
+        steps[i]["t_done"] = time.perf_counter()
+
     compiles.armed = True
     heart = Heartbeat().start(ctx.heartbeat)
     while True:
         now = time.perf_counter()
         if now >= t_window + ctx.seconds:
             break
+        if tracer.due(now):
+            # the slice begins with nothing in flight, so that the device
+            # runs in it the steps launched in it
+            while waiting:
+                wait_one()
         tracer.tick(now)
         t_begin = time.perf_counter()
-        ids = fetch()
-        losses.append(step(ids))
-        steps.append({"t_begin": t_begin, "t_end": time.perf_counter()})
+        queued = len(waiting)
+        losses.append(launch(fetch()))
+        steps.append({"t_begin": t_begin, "queued": queued})
+        waiting.append(len(steps) - 1)
+        if len(waiting) > ahead:
+            wait_one()
+        steps[-1]["t_end"] = time.perf_counter()
+    while waiting:
+        wait_one()
     t_done = time.perf_counter()
     stops = heart.stop()
     compiles.armed = False
@@ -110,10 +146,12 @@ def run(ctx: Context) -> Result:
              "losses_finite": finite, "ln_vocab": math.log(cfg.vocab_size)}
     ctx.emit("check", **check)
     ctx.emit("setup", setup_s=setup_s, parts=parts)
-    step_ms = [(s["t_end"] - s["t_begin"]) * 1e3 for s in steps]
+    done = [s["t_done"] for s in steps]
+    step_ms = [(b - a) * 1e3 for a, b in zip(done, done[1:])]
     flops = model_math.train_flops_per_token(sizes, toks)
     notes = {"compiles_in_window": compiles.count, "steps_in_window": len(steps),
              "tokens_in_window": tokens, "window_s": t_done - t_window,
+             "steps_ahead": ahead,
              "step_ms_p50": median(step_ms) if step_ms else None,
              "first_loss": first_loss, "last_loss": losses[-1] if losses else None,
              "model_flops_per_token": flops,
@@ -131,7 +169,7 @@ def run(ctx: Context) -> Result:
         end_to_end={"train_tokens_per_s": (rate, UNITS["train_tokens_per_s"]),
                     "setup_s": (setup_s, UNITS["setup_s"])},
         steps=steps, traced_steps=traced,
-        reduced=tracer.reduce("chipbench.train_step"), config=sizes,
+        reduced=tracer.reduce(None), config=sizes,
         cell=cell, device_kind=ctx.device_kind,
         memory_peak_bytes=memory_peak_bytes(cell["chips"]),
         compared={"loss_rel_diff": [rel, cell["check"]["loss_rel_tol"]],

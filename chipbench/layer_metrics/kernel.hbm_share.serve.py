@@ -7,7 +7,7 @@ events."""
 LAYER = "kernels (ops/kernels/pallas)"
 UNIT = "%"
 SOURCE = "device_trace"
-MOVES = "itl_p95_ms"
+MOVES = "serve_tokens_per_s"
 DRIVER = "serve"
 
 # the Pallas call's name in the trace: today the kernel function's, and the

@@ -1,5 +1,6 @@
-"""Host clock per training step, batch fetch to ``block_until_ready`` of
-the updated parameters; median over the window's steps."""
+"""Host clock per training step: from one step's loss found ready to the
+next's, median over the window's steps. With steps launched ahead of the
+one waited for, that is the period at which the device finishes steps."""
 
 LAYER = "train step (jit/api.py TrainStep)"
 UNIT = "ms"
@@ -10,5 +11,6 @@ DRIVER = "train"
 
 def compute(run):
     import statistics
-    ms = [(s["t_end"] - s["t_begin"]) * 1e3 for s in run.steps]
+    done = [s["t_done"] for s in run.steps]
+    ms = [(b - a) * 1e3 for a, b in zip(done, done[1:])]
     return statistics.median(ms) if ms else None

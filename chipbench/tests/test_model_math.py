@@ -36,7 +36,7 @@ def test_a_list_of_layer_types_read_as_far_as_the_layers_run():
 
 def reader():
     return cli.layer_metric_files(
-        "serve", ["itl_p95_ms"])["kernel.hbm_share.serve"]
+        "serve", ["serve_tokens_per_s"])["kernel.hbm_share.serve"]
 
 
 def run_of(config):

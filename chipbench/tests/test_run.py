@@ -84,3 +84,69 @@ def test_closed_loop_in_a_rehearsal():
     # three clients: never more than three rows in a step
     assert max(s["rows"] for s in result.steps) <= 3
     assert result.end_to_end["serve_tokens_per_s"][0] > 0
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_training_launches_ahead_and_waits_for_every_step(trace):
+    """Steps are launched ahead of the one waited for, and the window counts
+    only steps that were waited for: each has a ``t_done``, in order, the
+    last one before the rate's clock was read. A traced slice begins with
+    nothing in flight, so its steps are the ones launched in it."""
+    import time
+    from chipbench import run as cli
+    from chipbench.drivers import train
+    from chipbench.drivers.common import Context
+    cell = cli.load_json("workloads", "train-pretrain-4k.json")
+    config = cli.load_json("configs", cell["config"] + ".json")
+    cell["trace_slice_s"] = 1.0
+    ctx = Context(cell_name="ahead", cell=cell, config=config, seed=7,
+                  seconds=2.0, trace=trace, rehearse=True,
+                  t_process=time.perf_counter(),
+                  scratch=os.path.join(ROOT, ".chipbench_tmp"),
+                  device_kind="cpu")
+    result = train.run(ctx)
+    assert result.correct and result.attempted == len(result.steps) > 2
+    done = [s["t_done"] for s in result.steps]
+    assert done == sorted(done)
+    assert all(s["t_begin"] <= s["t_end"] and s["t_begin"] <= s["t_done"]
+               for s in result.steps)
+    tokens = result.end_to_end["train_tokens_per_s"][0]
+    assert tokens > 0
+    if trace:
+        first = result.traced_steps[0]
+        earlier = [s for s in result.steps if s["t_begin"] < first["t_begin"]]
+        assert len(result.traced_steps) > 1 and all(
+            s["t_done"] <= first["t_begin"] for s in earlier)
+        # what ``dispatch.host_ms.train`` reads: the slice's first launches
+        # find nothing queued before them
+        assert [s["queued"] for s in result.traced_steps[:2]] == [0, 1]
+
+
+def test_the_slice_is_due_once():
+    from chipbench.drivers.common import Context, Slice
+    ctx = Context(cell_name="due", cell={}, config={}, seed=0, seconds=10.0,
+                  trace=True, rehearse=True, t_process=0.0,
+                  scratch=os.path.join(ROOT, ".chipbench_tmp"),
+                  device_kind="cpu")
+    tracer = Slice(ctx, window_start=100.0, length_s=3.0)
+    assert not tracer.due(106.9) and tracer.due(107.0)
+    tracer.t0 = 107.0                   # as ``tick`` leaves it once started
+    assert not tracer.due(108.0)
+    ctx.trace = False
+    assert not Slice(ctx, 100.0, 3.0).due(109.0)
+
+
+@pytest.mark.parametrize("cell", [
+    "serve-chat-steady", "serve-decode-batch", "serve-reason-batch"])
+def test_the_ragged_kernels_roofline_is_read_in_every_serving_cell(cell):
+    """``MOVES`` decides the cells a reader is read in: the ragged kernel's
+    share of its roofline moves ``serve_tokens_per_s``, which every serving
+    cell reports, and ``BENCHMARK.json`` lists it for each of them."""
+    from chipbench import run as cli
+    reported = cli.load_json("workloads", cell + ".json")["end_to_end"]
+    assert "kernel.hbm_share.serve" in cli.layer_metric_files(
+        "serve", reported)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    entry = listed["kernel.hbm_share.serve"]
+    assert cell in entry["workloads"] and entry["moves"] in reported
