@@ -248,8 +248,8 @@ def _run_vjp_create_graph(node: "GradNode", ct_tensors):
 # -- structure-cached fused backward ------------------------------------------
 #
 # The per-node walk pays one PJRT launch per GradNode plus an eager
-# `cur + g` add per cotangent accumulation (BENCH_r05: ~18.9us/op eager
-# with tape vs ~0.3us/op inside a compiled step). A training iteration's
+# `cur + g` add per cotangent accumulation (on the CPU, BENCH_r05:
+# ~18.9us/op eager with tape vs ~0.3us/op compiled). A training iteration's
 # tape has a STABLE structure, so the whole reverse walk is compiled once
 # per structure into ONE XLA executable taking every node primal plus the
 # seed cotangents and returning every leaf grad. First sight of a

@@ -3,7 +3,7 @@
 The directory is part of every cache key, so it must not move between
 runs: either the environment names it (``JAX_COMPILATION_CACHE_DIR``, which
 JAX reads by itself) or it is ``<checkout>/.jax_cache``, derived from this
-file's own location. Entry points (``chip_smoke.py``, ``bench.py``) call
+file's own location. Entry points (``chip_smoke.py``) call
 :func:`enable_compile_cache` before their first compile.
 """
 

@@ -44,8 +44,8 @@ from ..observability import metrics as _metrics_mod
 from ..observability import perf as _perf_mod
 
 # -- always-on observability (observability/): one counter inc per dispatch
-# plus a flag-gated flight-recorder ring write; both stay inside the 1us/op
-# instrumentation budget (bench.py observability_overhead micro).
+# plus a flag-gated flight-recorder ring write, under a 1us/op budget that
+# no chip reading has checked (PERF.md).
 
 _M_DISPATCH = _metrics_mod.registry().counter(
     "dispatch.count", "eager op dispatches (incl. dunder fast path)")

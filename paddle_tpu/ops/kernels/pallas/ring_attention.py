@@ -36,8 +36,8 @@ Per-block math has TWO implementations, selected by shard shape
   - `_ring_local` (small/unaligned shards, CPU tests): plain XLA einsum +
     logsumexp blocks, differentiated by AD.
 Parity for values and all three grads: tests/test_pallas_and_pp.py
-(TestRingAttention); block-level perf vs the XLA composite: bench.py
-`ring_block_attention` micro-bench.
+(TestRingAttention); block-level speed against the XLA composite: not
+measured (no benchmark cell runs this kernel; PERF.md).
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ commute out of the dot entirely:
 so the weight is read from HBM as int8 (half the bf16 bytes) and the
 convert fuses into the MXU feed; the scale lands on the tiny [m, n]
 output. Measured on v5e at decode shapes (m32 k8192 n28672), DEVICE
-clock (benchmarks/device_time.py): 315us vs 625us for the bf16 matmul
+clock (a profile's busy time, round 3): 315us vs 625us for the bf16 matmul
 — the expected ~2x of a memory-bound op at half the weight bytes.
 (Round 3's host-clock "0.98x" reading was launch-latency noise; see
 PARITY.md methodology.) A hand Pallas tile kernel was tried and

@@ -3,7 +3,7 @@
 PRs 7–9 made ONE engine fast and unkillable; this package makes the
 SERVICE survive. A :class:`ReplicaRouter` spreads an open-loop arrival
 stream over N engine replicas behind a uniform :class:`ReplicaHandle`
-transport (thread-hosted for tests/benches, subprocess-hosted for real
+transport (thread-hosted for tests, subprocess-hosted for real
 isolation + SIGKILL chaos), session-affine on the prompt's prefix-block
 digest chain so shared system prompts land where their KV is warm.
 

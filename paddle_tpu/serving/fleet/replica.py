@@ -6,8 +6,8 @@ The router speaks one small verb set — ``submit`` / ``pop_finished`` /
 engine directly. Two transports implement it:
 
 * :class:`ThreadReplicaHandle` hosts the engine on a worker thread in
-  this process. Cheap enough that tests and ``bench.py serving_fleet``
-  run real multi-replica fleets on CPU; ``kill()`` stops the worker at
+  this process. Cheap enough that tests run real multi-replica fleets
+  on CPU; ``kill()`` stops the worker at
   a step boundary WITHOUT flushing the journal, so the unflushed tail
   is lost exactly as a SIGKILL would lose it (and ``pop_finished``
   returns nothing from a killed incarnation — a dead process delivers

@@ -12,8 +12,8 @@ generation is preloaded into freshly-allocated pool blocks and
 registered *evictable* — warm capacity the allocator may reclaim, so
 preloading never steals admission headroom. Recovered requests and new
 traffic sharing those prompt heads then prefill from warm blocks
-instead of recomputing them (measured as warm-vs-cold TTFT by
-``bench.py serving_recovery``).
+instead of recomputing them (warm-vs-cold TTFT on the chip: not
+measured; PERF.md).
 
 A geometry/dtype mismatch (different block size, kv heads, head dim, or
 model fingerprint) refuses the preload rather than serving another

@@ -133,54 +133,9 @@ _SLOW_TESTS = {
     # 5s resnet50 bottleneck-block smoke; resnet18 stays tier-1
     ("test_vision", "test_resnet50_bottleneck"),
     # 5s end-to-end shed-then-client-retry; the retry-after hint unit
-    # tests stay, and the fleet bench micro asserts sheds + hint
+    # tests stay, and test_tracing's fleet.shed call-site case asserts
+    # the sheds, their count and their hint
     ("test_serving_fleet", "test_shed_then_retry"),
-}
-
-# Class-qualified entries (same audit, PR 7 refresh; PR 18 refresh):
-# the WALL-CLOCK bench-micro smokes are the slowest and least
-# time-box-appropriate tier-1 members — each guards a timing RATIO the
-# bench artifact already records every round (BENCH_rXX), and each
-# feature's machinery keeps its own dedicated tier-1 file
-# (test_resilience 27 tests, test_step_capture 39, test_observability
-# 35). The newest micro's smoke (TestServingFleetMicro, which carries
-# the PR 18 incident-overhead acceptance gates) stays tier-1 until the
-# next audit.
-_SLOW_CLASS_TESTS = {
-    # 24s checkpoint-overlap wall-clock gate (has its own busy-host retry)
-    ("test_bench_robustness", "TestCheckpointOverlapMicro",
-     "test_micro_runs_and_meets_gate"),
-    # 13s captured-vs-eager wall-clock micro
-    ("test_bench_robustness", "TestStepCaptureMicro",
-     "test_micro_runs_and_reports"),
-    # 6s metrics-overhead wall-clock micro
-    ("test_bench_robustness", "TestObservabilityMicro",
-     "test_micro_runs_and_reports"),
-    # 37s K-block-vs-single-step wall-clock gate (busy-host retry
-    # inside); the multi-step machinery keeps tier-1 coverage in
-    # test_multi_step (34 fast tests)
-    ("test_bench_robustness", "TestMultiStepMicro",
-     "test_micro_runs_and_meets_gate"),
-    # ~80s full-grid fused-vs-chain wall-clock gate (busy-host retry
-    # inside); the megakernel keeps tier-1 coverage in
-    # test_fused_optimizer (64 fast tests)
-    ("test_bench_robustness", "TestFusedOptimizerMicro",
-     "test_micro_runs_and_meets_gate"),
-    # PR 18 audit: ~11-20s detector-tax wall-clock gate (flaked under
-    # host load even with its retry); the anomaly machinery keeps
-    # tier-1 coverage in test_anomaly (29 fast tests)
-    ("test_bench_robustness", "TestAnomalyOverheadMicro",
-     "test_micro_runs_and_meets_gate"),
-    # PR 18 audit: ~7s ragged-batching wall-clock micro; continuous
-    # batching keeps tier-1 coverage in test_continuous_batching (21)
-    ("test_bench_robustness", "TestServingRaggedMicro",
-     "test_micro_runs_and_reports"),
-    # PR 20: ~40s four-regime (kv_dtype x spec) wall-clock micro with a
-    # >=1.3x speculative-decode gate; the int8-pool and spec machinery
-    # keep tier-1 coverage in test_continuous_batching (TestQuantizedKV
-    # + TestSpeculativeDecode) and test_ragged_attention
-    ("test_bench_robustness", "TestServingRegimesMicro",
-     "test_matrix_runs_and_meets_gates"),
 }
 
 
@@ -191,10 +146,6 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.heavy)
         if (item.module.__name__ in _SLOW_MODULES
                 or (item.module.__name__, item.originalname) in _SLOW_TESTS):
-            item.add_marker(pytest.mark.slow)
-        if (item.module.__name__,
-                getattr(item.cls, "__name__", None),
-                item.originalname) in _SLOW_CLASS_TESTS:
             item.add_marker(pytest.mark.slow)
     # Schedule the suite's long pole LAST: test_v5p_aot's module-scoped
     # ~2 min XLA:TPU AOT compile is the single longest stretch with no

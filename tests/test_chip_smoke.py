@@ -114,9 +114,3 @@ def test_tpu_place_raises_without_a_tpu(ask):
     ("cpu", None), ("TPU v5 lite", (197e12, 819e9))])
 def test_peaks_by_device_kind(kind, peak):
     assert perf.DEVICE_PEAKS.get(kind) == peak
-
-
-def test_bench_peak_raises_for_unknown_kind():
-    import bench
-    with pytest.raises(ValueError, match="no peak"):
-        bench._peak_flops(jax.devices()[0])

@@ -14,6 +14,7 @@ read-bound plan short-circuit.
 import hashlib
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -162,6 +163,38 @@ class TestPoisoning:
         got = np.asarray(w(X, Y))
         assert np.array_equal(got, np.asarray(jfn(X, Y)))
         assert len(_corrupt_events()) > n0
+
+    def test_non_json_plan_is_a_miss_with_flight_event(self, tmp_path):
+        es.attach(str(tmp_path))
+        assert es.store().put("plan", ("k",), b"\xff not json")
+        n0 = len(_corrupt_events())
+        assert es.store().get_json("plan", ("k",)) is None
+        assert len(_corrupt_events()) == n0 + 1
+
+    def test_failed_write_is_false_with_flight_event(self, tmp_path,
+                                                     monkeypatch):
+        es.attach(str(tmp_path))
+
+        def full_disk(path, write):
+            raise OSError("no space left on device")
+
+        monkeypatch.setattr(es, "fsync_write", full_disk)
+        assert es.store().put_json("plan", ("k",), {"a": 1}) is False
+        assert es.store().written == 0
+        assert fr.recorder().entries()[-1][3] == "jit.cache.write_failed"
+
+    def test_foreign_debris_is_swept_only_after_its_grace(self, tmp_path):
+        es.attach(str(tmp_path))
+        old = tmp_path / "plan" / "aa-0ther0ld"
+        young = tmp_path / "plan" / "bb-0theryng"
+        for d in (old, young):
+            d.mkdir(parents=True)
+        t = time.time() - es._DEBRIS_GRACE_S - 60.0
+        os.utime(old, (t, t))
+        assert es.store().put_json("plan", ("k",), {"a": 1})
+        assert not old.exists()           # a dead writer's leftovers
+        assert young.exists()             # may still be mid-commit
+        assert es.store().state()["kinds"] == {"plan": 1}
 
     def test_jaxlib_version_bump_invalidates_everything(
             self, tmp_path, monkeypatch):

@@ -13,6 +13,7 @@ The end-to-end hang/failover attributions live with the chaos fixtures
 in test_serving_resilience.py / test_serving_fleet.py.
 """
 
+import importlib
 import json
 import os
 import queue
@@ -146,6 +147,14 @@ class TestCaptureStacks:
         t.join(5.0)
         assert cls == "data_wait"
 
+    def test_fallback_capture_sees_this_thread(self):
+        (rec,) = debug._capture_fallback()
+        assert rec["current"] is True
+        assert rec["thread_id"] == threading.get_ident()
+        assert rec["class"] in STACK_CLASSES
+        assert ("test_fallback_capture_sees_this_thread"
+                in [name for _, _, name in rec["frames"]])
+
     def test_current_thread_flagged_and_sorted_last(self):
         stacks = capture_stacks()
         assert stacks, "no threads captured"
@@ -201,6 +210,25 @@ class TestIncidentRecorder:
         rec = IncidentRecorder(str(tmp_path))
         path = rec.record("debug.manual")
         assert not os.path.exists(os.path.join(path, "journal.json"))
+
+    @pytest.mark.parametrize("part, module, fn", [
+        ("trace.json", "tracing", "dump_trace"),
+        ("perf.json", "perf", "perfz_snapshot"),
+    ], ids=["trace", "perf"])
+    def test_bundle_commits_without_a_broken_garnish(
+            self, tmp_path, no_rate_limit, monkeypatch, part, module, fn):
+        mod = importlib.import_module(f"paddle_tpu.observability.{module}")
+
+        def broken(*a, **k):
+            raise RuntimeError("garnish broke")
+
+        monkeypatch.setattr(mod, fn, broken)
+        path = IncidentRecorder(str(tmp_path)).record("debug.manual",
+                                                      step=3)
+        assert path is not None and read_committed_marker(path)
+        assert not os.path.exists(os.path.join(path, part))
+        for kept in ("incident.json", "stacks.json", "metrics.json"):
+            assert os.path.exists(os.path.join(path, kept)), kept
 
     def test_unknown_kind_raises(self, tmp_path):
         with pytest.raises(ValueError, match="INCIDENT_KINDS"):

@@ -216,6 +216,15 @@ class TestFlightRecorderRing:
             paddle.set_flags(saved)
         assert rec.capacity == old_cap
 
+    def test_clear_empties_the_ring_and_restarts_numbering(self):
+        rec = FlightRecorder(4)
+        for i in range(6):
+            rec.record(f"op{i}", ())
+        rec.clear()
+        assert rec.entries() == [] and rec.total_recorded == 0
+        rec.record("again", ())
+        assert [(e[0], e[3]) for e in rec.entries()] == [(0, "again")]
+
 
 class TestFlightRecorderCrashDump:
     def test_injected_op_failure_reproduces_last_dispatches(self):
@@ -267,6 +276,36 @@ class TestFlightRecorderCrashDump:
             paddle.set_flags(saved)
         assert os.path.exists(path)
         assert "op=crash_op2" in open(path).read()
+
+    def test_excepthook_outlives_its_own_dumps(self, monkeypatch):
+        from paddle_tpu.observability import incident, tracing
+
+        def broken(*a):
+            raise RuntimeError("dump broke")
+
+        chained = []
+        monkeypatch.setattr(fr_mod, "_crash_dump", broken)
+        monkeypatch.setattr(tracing, "_crash_dump", broken)
+        monkeypatch.setattr(incident, "_crash_incident", broken)
+        monkeypatch.setattr(fr_mod, "_prev_excepthook",
+                            lambda *a: chained.append(a[0]))
+        fr_mod._excepthook(ValueError, ValueError("x"), None)
+        assert chained == [ValueError]    # the traceback still prints
+
+    @pytest.mark.parametrize("exc", [KeyboardInterrupt, SystemExit])
+    def test_deliberate_exits_skip_the_dumps(self, exc, monkeypatch):
+        from paddle_tpu.observability import incident, tracing
+        called = []
+        monkeypatch.setattr(fr_mod, "_crash_dump",
+                            lambda: called.append("flight"))
+        monkeypatch.setattr(tracing, "_crash_dump",
+                            lambda: called.append("spans"))
+        monkeypatch.setattr(incident, "_crash_incident",
+                            lambda *a: called.append("bundle"))
+        monkeypatch.setattr(fr_mod, "_prev_excepthook",
+                            lambda *a: called.append("traceback"))
+        fr_mod._excepthook(exc, exc(), None)
+        assert called == ["traceback"]
 
     def test_excepthook_installed_and_chains(self):
         import sys

@@ -6,13 +6,10 @@ the perf-regression sentinel (fires on a planted slowdown, quiet on
 noise), the step-time decomposition (components sum to the step wall;
 wired through hapi train_batch and the ResilientTrainer fallback), the
 labeled fleet merge under ``replica=``, the /perfz + /statusz + CLI
-contract, the histogram/delta edge cases the plane leans on, and the
-``bench.py --compare`` regression gate round trip.
+contract, and the histogram/delta edge cases the plane leans on.
 """
 
 import json
-import os
-import sys
 import urllib.request
 
 import numpy as np
@@ -22,8 +19,6 @@ import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.observability import perf
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _counter_value(name):
@@ -299,6 +294,14 @@ class TestStepDecomposition:
         s = perf.step_summary()
         assert 0.02 <= s["data_wait"]["sum"] <= s["total"]["sum"]
 
+    def test_step_seq_counts_with_the_plane_off(self):
+        assert paddle.get_flags(["FLAGS_perf_attribution"])[
+            "FLAGS_perf_attribution"] is False
+        s0 = perf.step_seq()
+        perf.record_step(0.01)
+        perf.record_step(0.01)
+        assert perf.step_seq() == s0 + 2
+
     def test_step_beat_unconditional(self):
         assert paddle.get_flags(["FLAGS_perf_attribution"])[
             "FLAGS_perf_attribution"] is False
@@ -407,6 +410,21 @@ class TestPerfzSurfaces:
         assert "cli_op" in out
         assert "AOT projection [test_plan]" in out
 
+    def test_perfz_text_shows_the_step_decomposition(self, perf_on):
+        perf.record_step(0.01, host_s=0.004, device_s=0.003)
+        text = perf.format_perfz()
+        assert "Step decomposition (seconds):" in text
+        for part in ("data_wait", "host_dispatch", "device", "other",
+                     "total"):
+            assert any(line.split()[:2] == [part, "count=1"]
+                       for line in text.splitlines()), part
+
+    def test_perfz_text_without_executables_says_how(self, perf_on):
+        text = perf.format_perfz()
+        assert text.splitlines()[0].startswith("perf_attribution=on ")
+        assert "(no executables registered" in text
+        assert "Step decomposition" not in text
+
     def test_profiler_summary_appends_table(self, perf_on, capsys):
         import paddle_tpu.profiler as profiler
         led = perf.ledger()
@@ -468,79 +486,3 @@ class TestHistogramEdgeCases:
         h.observe(0.4)
         d3 = reg.delta_update(state, ("edge.",))
         assert d3["edge.h_seconds"]["c"] == 1
-
-
-class TestBenchCompare:
-    def _round(self, n, metrics):
-        cfgs = [{"metric": k, "value": v, "unit": "x", "vs_baseline": 1.0}
-                for k, v in metrics.items() if k != "headline"]
-        return {"n": n, "cmd": "bench", "rc": 0, "tail": "",
-                "parsed": {"metric": "headline",
-                           "value": metrics.get("headline", 1.0),
-                           "unit": "mfu_fraction",
-                           "detail": {"configs": cfgs}}}
-
-    def _write_rounds(self, tmp_path, rounds):
-        paths = []
-        for i, m in enumerate(rounds, start=1):
-            p = tmp_path / f"BENCH_r{i:02d}.json"
-            p.write_text(json.dumps(self._round(i, m)))
-            paths.append(str(p))
-        return paths
-
-    def test_clean_tree_passes_against_itself(self, tmp_path, capsys):
-        import bench
-        m = {"headline": 0.6, "step_us": 100.0, "opt_speedup": 4.0}
-        paths = self._write_rounds(tmp_path, [m, m])
-        assert bench.bench_compare(paths[0]) == 0   # candidate = newest
-        assert "no regression" in capsys.readouterr().out
-
-    def test_planted_slowdown_fails_with_table(self, tmp_path, capsys):
-        import bench
-        base = {"headline": 0.6, "step_us": 100.0, "opt_speedup": 4.0}
-        bad = {"headline": 0.6, "step_us": 200.0, "opt_speedup": 4.0}
-        paths = self._write_rounds(tmp_path, [base, bad])
-        assert bench.bench_compare(paths[0], paths[1]) == 1
-        out = capsys.readouterr().out
-        assert "REGRESSED" in out and "step_us" in out
-        assert "opt_speedup" in out      # the per-micro table is printed
-
-    def test_direction_awareness(self, tmp_path):
-        import bench
-        # _us shrinking and speedup growing are both improvements
-        base = {"headline": 0.6, "step_us": 100.0, "opt_speedup": 4.0}
-        better = {"headline": 0.9, "step_us": 50.0, "opt_speedup": 9.0}
-        paths = self._write_rounds(tmp_path, [base, better])
-        assert bench.bench_compare(paths[0], paths[1]) == 0
-        # speedup COLLAPSING is a regression
-        worse = {"headline": 0.6, "step_us": 100.0, "opt_speedup": 1.0}
-        paths = self._write_rounds(tmp_path, [base, worse])
-        assert bench.bench_compare(paths[0], paths[1]) == 1
-
-    def test_noise_band_widens_with_history(self, tmp_path):
-        import bench
-        # step_us historically swings 40% round to round: a 25% move
-        # sits inside 3 x median band and must NOT gate
-        hist = [{"step_us": 100.0}, {"step_us": 140.0},
-                {"step_us": 100.0}, {"step_us": 140.0},
-                {"step_us": 125.0}]
-        paths = self._write_rounds(tmp_path, hist)
-        assert bench.bench_compare(paths[-2], paths[-1]) == 0
-
-    def test_zero_valued_metrics_not_gated(self, tmp_path, capsys):
-        import bench
-        paths = self._write_rounds(
-            tmp_path, [{"headline": 0.0, "step_us": 100.0},
-                       {"headline": 0.0, "step_us": 100.0}])
-        assert bench.bench_compare(paths[0], paths[1]) == 0
-        assert "not gated" in capsys.readouterr().out
-
-    def test_cli_entry(self, tmp_path, capsys, monkeypatch):
-        import bench
-        m = {"headline": 0.6, "step_us": 100.0}
-        paths = self._write_rounds(tmp_path, [m, m])
-        monkeypatch.setattr(sys, "argv",
-                            ["bench.py", "--compare", paths[0]])
-        with pytest.raises(SystemExit) as ei:
-            bench.main()
-        assert ei.value.code == 0

@@ -154,6 +154,25 @@ class TestAsyncCheckpointer:
         np.testing.assert_array_equal(np.asarray(opt._states[0]["m"]), m0)
         assert opt._step_count == 1
 
+    def test_close_drains_the_inflight_write(self, tmp_path):
+        net, opt, step_fn, state_fn, apply_fn, _ = _tiny_job()
+        step_fn(0)
+        ck = AsyncCheckpointer(str(tmp_path))
+        path = ck.save(state_fn(), 3)        # the write runs behind us
+        ck.close()
+        assert ck._pending is None and ck.last_error is None
+        assert ck.latest() == path
+
+    def test_restore_latest_without_a_generation_is_a_noop(self,
+                                                           tmp_path):
+        net, opt, step_fn, state_fn, apply_fn, _ = _tiny_job()
+        ck = AsyncCheckpointer(str(tmp_path))
+        state = state_fn()
+        assert ck.restore_latest(state) == (state, None)
+        step_fn(0)
+        ck.save(state_fn(), 5, block=True)
+        assert ck.restore_latest(state_fn())[1] == 5
+
     def test_restore_into_fresh_process_state(self, tmp_path):
         """A relaunched rank restores BEFORE its first step: optimizer
         per-param states are still None and must be reconstructed from

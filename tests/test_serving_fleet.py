@@ -37,6 +37,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.serving import ContinuousBatchingEngine, QueueFull
 from paddle_tpu.observability import exporter as telemetry
+from paddle_tpu.observability import tracing
 from paddle_tpu.observability.metrics import (METRIC_NAMES, Histogram,
                                               registry)
 from paddle_tpu.serving.fleet import (FleetShed, ReplicaRouter,
@@ -46,7 +47,8 @@ from paddle_tpu.serving.fleet import (FleetShed, ReplicaRouter,
                                       ThreadReplicaHandle)
 from paddle_tpu.serving.fleet.router import (_affinity_digest,
                                              _rendezvous_order)
-from paddle_tpu.serving.resilience import (ResilientServingEngine,
+from paddle_tpu.serving.resilience import (RequestJournal,
+                                           ResilientServingEngine,
                                            ServingAction)
 
 _TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -645,6 +647,63 @@ class TestFleetRouter:
             _assert_byte_identical(router, model)
         finally:
             router.close()
+
+    def test_pop_output_delivers_once(self, model, tmp_path):
+        router, _ = _mk_fleet(model, tmp_path)
+        try:
+            p = _prompts(1, rng_seed=4)[0]
+            gid = router.submit(p, max_new_tokens=4)
+            assert router.pop_output(gid + 1) is None   # unknown: no wait
+            out = router.pop_output(gid, timeout=120.0)  # polls until done
+            assert out == _reference(model, {gid: (p, 4)})[gid]
+            assert gid not in router.outputs
+            assert router.pop_output(gid) is None        # delivered once
+            assert router.pop_output(gid, timeout=0.05) is None
+        finally:
+            router.close()
+
+    def test_failover_delivers_a_finished_stream_from_the_journal(
+            self, model, tmp_path):
+        """A replica that finished a request and died before the router
+        collected it: its journal holds the whole stream, and the router
+        delivers that rather than generating the request again."""
+        tracing.clear()
+        router, reps = _mk_fleet(model, tmp_path)
+        try:
+            p = _prompts(1, rng_seed=9)[0]
+            gid = router.submit(p, max_new_tokens=3)
+            victim = next(r for r in reps
+                          if r.name == router._outstanding[gid].replica)
+            journal = RequestJournal(os.path.join(victim.root, "journal"))
+            deadline = time.time() + 120.0
+            while time.time() < deadline:
+                rec = journal.load().requests.get(gid)
+                if rec is not None and rec.finished:
+                    break
+                time.sleep(0.02)
+            victim.kill()                  # before the router polled
+            router.drain_all(timeout_s=120.0)
+            assert router.rerouted_requests == 0
+            assert router.dropped_requests == 0
+            _assert_byte_identical(router, model)
+        finally:
+            router.close()
+        (fo,) = [s for s in tracing._ring().entries()
+                 if s.name == "fleet.failover"]
+        assert fo.attrs == {"gid": gid, "replica": victim.name,
+                            "disposition": "delivered_from_journal"}
+
+    def test_thread_handle_refuses_work_after_drain(self, model,
+                                                    tmp_path):
+        h = ThreadReplicaHandle("d", lambda: model, str(tmp_path / "d"),
+                                **ENG)
+        h.start()
+        try:
+            h.drain()
+            with pytest.raises(ReplicaUnavailable):
+                h.submit(0, [1, 2, 3], 2)
+        finally:
+            h.stop()
 
     def test_thread_drain_raises_on_wedged_worker(self, model,
                                                   tmp_path):

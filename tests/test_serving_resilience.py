@@ -97,6 +97,19 @@ class TestRequestJournal:
         assert st.requests[0].finished
         assert st.segments == 3
 
+    def test_state_splits_finished_from_unfinished(self, tmp_path):
+        j = RequestJournal(str(tmp_path))
+        j.append({"t": "config", "seed": 1, "sampling": {}, "eos": None})
+        for rid in (0, 1, 2):
+            j.append({"t": "admit", "rid": rid, "prompt": [1, 2 + rid],
+                      "max_new_tokens": 4})
+        j.append({"t": "tokens", "rid": 1, "from": 0, "toks": [5]})
+        j.append({"t": "finish", "rid": 1})
+        j.flush()
+        st = RequestJournal(str(tmp_path)).load()
+        assert [r.rid for r in st.finished] == [1]
+        assert sorted(r.rid for r in st.unfinished) == [0, 2]
+
     def test_empty_flush_writes_no_segment(self, tmp_path):
         j = RequestJournal(str(tmp_path))
         j.flush()
@@ -233,6 +246,11 @@ class TestJournalCompaction:
                 j.append({"t": "finish", "rid": rid})
             j.flush()
         return j
+
+    def test_compact_before_any_segment_is_a_noop(self, tmp_path):
+        j = RequestJournal(str(tmp_path))
+        assert j.compact(drop_rids={0}) == 0
+        assert os.listdir(tmp_path) == []
 
     def test_compact_drops_only_retired_finished(self, tmp_path):
         j = self._fill(str(tmp_path))
@@ -656,6 +674,35 @@ class TestDrain:
         assert e2.outputs == ref
         e2.close()
 
+    def test_mid_decode_drain_replays_from_watermarks(self, model,
+                                                      tmp_path):
+        """A drain after decoding began: the relaunch resumes every
+        request from its committed watermark, regenerates only the tail,
+        preloads the drain's prefix snapshot, and matches an
+        uninterrupted run."""
+        prompts = _requests(3, head_blocks=1, rng_seed=5)
+        ref = _reference(model, tmp_path, prompts, max_new=8)
+        e1 = ResilientServingEngine(model, str(tmp_path / "d"),
+                                    journal_flush_every=1, **ENG)
+        for p in prompts:
+            e1.add_request(p, max_new_tokens=8)
+        for _ in range(20):
+            e1.step()
+            if sum(len(r.out_tokens)
+                   for r in e1.engine.results.values()) >= 3:
+                break
+        e1.drain(deadline_s=0.0)
+        e1.close()
+        e2 = ResilientServingEngine(model, str(tmp_path / "d"), **ENG)
+        committed = (sum(e2._watermark.values())
+                     + sum(len(t) for t in e2.outputs.values()))
+        assert e2.replayed_requests > 0 and committed > 0
+        assert e2.warm_blocks > 0             # the drain's snapshot
+        assert e2.run() == ServingAction.COMPLETED
+        assert e2.outputs == ref
+        assert sum(len(t) for t in e2.outputs.values()) > committed
+        e2.close()
+
     def test_generous_deadline_finishes_in_flight(self, model, tmp_path):
         e1 = ResilientServingEngine(model, str(tmp_path / "d"), **ENG)
         for p in _requests(2):
@@ -1037,6 +1084,26 @@ class TestWarmStart:
         assert (len(eng.cache._free) + eng._pc.evictable
                 == eng._total_blocks)
         e2.close()
+
+    def test_preload_into_a_full_pool_loads_nothing(self, model,
+                                                     tmp_path):
+        """A pool with no more free blocks than rows gives a snapshot
+        none of them: admissions come first."""
+        e1 = ResilientServingEngine(model, str(tmp_path / "w"),
+                                    **dict(ENG, temperature=0.0))
+        for p in _requests(2, head_blocks=2, rng_seed=3):
+            e1.add_request(p, max_new_tokens=3)
+        e1.run()
+        assert e1.snapshot() is not None
+        e1.close()
+        eng = ContinuousBatchingEngine(
+            model, **dict(ENG, temperature=0.0,
+                          num_blocks=ENG["max_batch"] + 1))
+        free = list(eng.cache._free)
+        assert len(free) <= eng.max_batch
+        assert load_prefix_cache(eng, e1.warm_root) == 0
+        assert eng.cache._free == free
+        assert registry().get("serving.resilience.warm_blocks").value == 0
 
 
 # ------------------------------------------------ step-hang watchdog (fast)

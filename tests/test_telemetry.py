@@ -441,6 +441,40 @@ class TestExporter:
         finally:
             srv.shutdown()
 
+    def test_statusz_shows_engine_phase_and_exec_cache(self, tmp_path):
+        from paddle_tpu.jit import exec_store
+        srv, port = self._server()
+        eng = _FakeEngine()
+        try:
+            srv.attach_engine(eng)
+            exec_store.attach(str(tmp_path))
+            assert exec_store.store().put_json("plan", ("k",), {"a": 1})
+            code, body, _ = _get(port, "/statusz")
+        finally:
+            exec_store.detach()
+            srv.shutdown()
+        assert code == 200
+        assert "engine: phase=not_ready" in body
+        assert f"dir: {tmp_path}" in body
+        assert "entries: 1  (plan=1)" in body
+        assert "written: 1" in body
+
+    def test_a_failing_page_is_a_500_and_the_server_lives(self,
+                                                          monkeypatch):
+        srv, port = self._server()
+
+        def broken():
+            raise RuntimeError("page broke")
+
+        monkeypatch.setattr(srv, "_trace_body", broken)
+        try:
+            code, body, _ = _get(port, "/trace")
+            assert code == 500 and "RuntimeError: page broke" in body
+            code, _, _ = _get(port, "/healthz")
+            assert code == 200
+        finally:
+            srv.shutdown()
+
     def test_endpoints_survive_dead_weakrefs(self):
         # satellite (PR18): the exporter observes the serving stack via
         # weakrefs only — after the router and engine are garbage

@@ -9,7 +9,8 @@ id), the profiler merge, the ``python -m paddle_tpu.observability``
 CLI, and trace continuity across a thread-hosted fleet — one trace_id
 from ``fleet.submit`` through admission, queue/prefill/decode phase
 segments and the finish edge, surviving a kill-failover with the
-original id.
+original id; and each span name that only one call site records, read
+back from the ring after driving that site.
 
 The slow-marked tranche runs REAL subprocess replicas: the ``tc``
 submit-frame field must re-establish the router's trace in the child,
@@ -31,11 +32,14 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.observability import flight_recorder, tracing
+from paddle_tpu.models.serving import ContinuousBatchingEngine
+from paddle_tpu.observability import flight_recorder, incident, tracing
 from paddle_tpu.observability.metrics import METRIC_NAMES, registry
-from paddle_tpu.serving.fleet import (ReplicaRouter,
+from paddle_tpu.serving.fleet import (FleetShed, ReplicaRouter,
                                       SubprocessReplicaHandle,
                                       ThreadReplicaHandle)
+from paddle_tpu.serving.resilience import (ResilientServingEngine,
+                                           ServingAction)
 
 _TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
 
@@ -64,10 +68,13 @@ def _prompts(n=4, rng_seed=3, bs=16):
             for i in range(n)]
 
 
-def _mk_fleet(model, tmp_path, n=2, **router_kw):
+def _mk_fleet(model, tmp_path, n=2, max_queue=None, eng=None,
+              **router_kw):
     reps = [ThreadReplicaHandle(f"rep{i}", lambda: model,
                                 str(tmp_path / f"rep{i}"),
-                                journal_flush_every=1, **ENG)
+                                max_queue=max_queue,
+                                journal_flush_every=1,
+                                **{**ENG, **(eng or {})})
             for i in range(n)]
     router = ReplicaRouter(reps, block_size=ENG["block_size"],
                            **router_kw)
@@ -80,6 +87,18 @@ def _recorded(name=None):
     """Completed ring entries, optionally filtered by span name."""
     ents = tracing._ring().entries()
     return ents if name is None else [s for s in ents if s.name == name]
+
+
+def _named(name):
+    """The attributes of every ring entry called ``name`` and of every
+    span event of that name, in ring order."""
+    out = []
+    for sp in tracing._ring().entries():
+        if sp.name == name:
+            out.append(sp.attrs or {})
+        out.extend(attrs or {} for _, ev, attrs in (sp.events or ())
+                   if ev == name)
+    return out
 
 
 # ---------------------------------------------------------- span core (fast)
@@ -135,6 +154,10 @@ class TestSpanCore:
                 sp.event("serving.first_token")
         (rec,) = _recorded("serving.step")
         assert len(rec.events) == tracing._EVENTS_MAX
+
+    def test_tracing_defaults_on(self):
+        assert tracing._F_TRACING.default is True
+        assert paddle.get_flags(["FLAGS_tracing"])["FLAGS_tracing"] is True
 
     def test_counters_registered_and_incremented(self):
         assert "tracing.spans" in METRIC_NAMES
@@ -517,6 +540,190 @@ class TestFleetTraceContinuity:
             assert handoffs and len(admits) >= 2
         else:
             assert fo.attrs["disposition"] == "delivered_from_journal"
+
+
+# ------------------------------------------- one test a call site (fast)
+
+def _overload(model, tmp_path):
+    """Eight 200-token requests offered with a zero deadline to two
+    one-row replicas of one queue slot each: the replicas refuse, the
+    router sheds. Returns (router, admitted count, the sheds'
+    retry-after)."""
+    router, _ = _mk_fleet(model, tmp_path, max_queue=1,
+                          eng=dict(max_batch=1, num_blocks=32))
+    admitted, hints = 0, []
+    try:
+        for p in _prompts(8, rng_seed=5):
+            try:
+                router.submit(p, max_new_tokens=200, deadline_s=0.0)
+                admitted += 1
+            except FleetShed as e:
+                hints.append(e.retry_after_s)
+    finally:
+        router.close()
+    return router, admitted, hints
+
+
+def _rolling_drain(model, tmp_path):
+    """A rolling drain of a two-replica fleet with requests in flight;
+    returns the replicas' names in drain order."""
+    router, reps = _mk_fleet(model, tmp_path)
+    try:
+        for p in _prompts(4, rng_seed=21):
+            router.submit(p, max_new_tokens=4)
+        router.rolling_drain(ready_timeout_s=120.0)
+        router.drain_all(timeout_s=120.0)
+        assert router.dropped_requests == 0
+    finally:
+        router.close()
+    return [r.name for r in reps]
+
+
+class TestCallSiteRecords:
+    """The names below have one call site each and no reader but these
+    tests: each drives its site and reads the record, with its
+    attributes, back from the ring."""
+
+    def test_anomaly_verdict(self):
+        from paddle_tpu.distributed.resilience import (AnomalyAction,
+                                                       AnomalyDetector)
+        tracing.clear()
+        det = AnomalyDetector(nonfinite_streak=2, warmup_steps=0)
+        assert det.observe(0, 1.0) == AnomalyAction.OK
+        assert det.observe(1, float("nan")) == AnomalyAction.SKIP
+        assert det.observe(2, float("nan")) == AnomalyAction.REWIND
+        # OK verdicts are the hot path and record nothing
+        assert _named("anomaly.verdict") == [
+            {"step": 1, "action": AnomalyAction.SKIP, "streak": 1},
+            {"step": 2, "action": AnomalyAction.REWIND, "streak": 2}]
+
+    def test_checkpoint_snapshot(self, tmp_path):
+        from paddle_tpu.distributed.resilience import AsyncCheckpointer
+        tracing.clear()
+        ck = AsyncCheckpointer(str(tmp_path))
+        state = {"w": paddle.to_tensor(np.arange(6, dtype=np.float32))}
+        ck.save(state, 7, block=True)
+        assert ck.last_error is None
+        (snap,) = _recorded("checkpoint.snapshot")
+        assert snap.kind == "span" and snap.attrs == {"step": 7}
+        # the writer thread's commit joins the snapshot's trace
+        (commit,) = _recorded("checkpoint.commit")
+        assert commit.trace_id == snap.trace_id
+        assert commit.parent_id == snap.span_id
+
+    def test_fleet_queue_full(self, model, tmp_path):
+        tracing.clear()
+        router, admitted, hints = _overload(model, tmp_path)
+        full = _named("fleet.queue_full")
+        # a shed comes only after every replica refused the request
+        assert {a["replica"] for a in full} == {"rep0", "rep1"}
+        assert len(full) >= 2 * len(hints) > 0
+
+    def test_fleet_shed(self, model, tmp_path):
+        tracing.clear()
+        router, admitted, hints = _overload(model, tmp_path)
+        assert hints and admitted
+        assert all(h is not None and h > 0.0 for h in hints)
+        # every offered request was admitted or shed, and counted so
+        assert router.sheds == len(hints) == 8 - admitted
+        sheds = _named("fleet.shed")
+        assert [a["retry_after_s"] for a in sheds] == [
+            round(h, 4) for h in hints]
+        assert all(a["slo_est"] is None for a in sheds)   # deadline sheds
+
+    def test_fleet_drain(self, model, tmp_path):
+        tracing.clear()
+        names = _rolling_drain(model, tmp_path)
+        assert [a["replica"] for a in _named("fleet.drain")] == names
+
+    def test_fleet_restart(self, model, tmp_path):
+        tracing.clear()
+        names = _rolling_drain(model, tmp_path)
+        # each replica restarts after its own drain, before the next's
+        steps = [(s.name, s.attrs["replica"]) for s in _recorded()
+                 if s.name in ("fleet.drain", "fleet.restart")]
+        assert steps == [(kind, n) for n in names
+                         for kind in ("fleet.drain", "fleet.restart")]
+
+    def test_observability_incident(self, tmp_path):
+        saved = paddle.get_flags(["FLAGS_incident_rate_limit_s"])
+        paddle.set_flags({"FLAGS_incident_rate_limit_s": 0.0})
+        tracing.clear()
+        try:
+            path = incident.record_incident(
+                "debug.manual", root=str(tmp_path), step=5)
+        finally:
+            paddle.set_flags(saved)
+            with incident._RECORDER._lock:
+                incident._RECORDER._recent.clear()
+        assert path is not None and path.startswith(str(tmp_path))
+        (sp,) = _recorded("observability.incident")
+        assert sp.kind == "span"
+        assert sp.attrs == {"kind": "debug.manual", "step": 5}
+
+    def test_serving_preempt(self, model):
+        tracing.clear()
+        eng = ContinuousBatchingEngine(model, max_batch=2, num_blocks=4,
+                                       block_size=16, temperature=0.0,
+                                       preempt_after=4)
+        rids = [eng.add_request(p, max_new_tokens=24)
+                for p in ([3, 4, 5], [9, 8, 7])]
+        eng.run()
+        assert eng.preempt_count >= 1
+        pre = _recorded("serving.preempt")
+        assert len(pre) == eng.preempt_count
+        assert {s.attrs["rid"] for s in pre} <= set(rids)
+        assert sum(s.attrs["preemptions"] == 1 for s in pre) >= 1
+
+    def test_serving_step_hang(self, model, tmp_path):
+        tracing.clear()
+        eng = ResilientServingEngine(model, str(tmp_path / "h"),
+                                     step_timeout_s=0.3, **ENG)
+        try:
+            eng.add_request([3, 1, 4, 1, 5], max_new_tokens=4)
+            eng.step()
+            deadline = time.time() + 5.0
+            while (eng.poll() != ServingAction.RESTART
+                   and time.time() < deadline):
+                time.sleep(0.05)          # the "wedged" step
+            assert eng.poll() == ServingAction.RESTART
+        finally:
+            eng.close()
+        (hang,) = _recorded("serving.step_hang")
+        assert hang.kind == "instant"
+        assert hang.attrs["stalled_s"] >= 0.3
+
+    def test_step_capture_capture(self):
+        from paddle_tpu import nn
+        from paddle_tpu.jit.step_capture import capture_counters
+        saved = paddle.get_flags(["FLAGS_step_capture"])
+        paddle.set_flags({"FLAGS_step_capture": True})
+        try:
+            paddle.seed(0)
+            net = nn.Linear(4, 2)
+            opt = paddle.optimizer.SGD(learning_rate=0.1,
+                                       parameters=net.parameters())
+
+            def step(x):
+                loss = (net(x) ** 2).mean()
+                loss.backward()
+                opt.step()
+                opt.clear_grad()
+                return loss
+
+            fn = paddle.jit_step(step)
+            x = paddle.to_tensor(np.ones((3, 4), np.float32))
+            tracing.clear()
+            c0 = capture_counters["captures"]
+            for _ in range(5):
+                fn(x)
+        finally:
+            paddle.set_flags(saved)
+        assert capture_counters["captures"] == c0 + 1
+        (cap,) = _recorded("step_capture.capture")
+        assert cap.kind == "span" and cap.attrs is None
+        replays = _recorded("step_capture.replay")
+        assert replays and all(r.t0_ns >= cap.t1_ns for r in replays)
 
 
 # ------------------------------------------------- subprocess chaos (slow)

@@ -8,8 +8,9 @@ and the SPMD collective schedule asserted. Reference analog: the static
 auto-parallel Engine planning whole-cluster programs
 (python/paddle/distributed/auto_parallel/static/engine.py:991).
 
-The full 32-layer 8B plan runs in bench.py (llama3_8b_v5p64_aot entry);
-tests here compile a depth-reduced geometry to keep CI under ~3 min.
+Tests here compile a depth-reduced geometry to keep CI under ~3 min;
+the full 32-layer 8B plan is ``aot.plan_llama3_8b_v5p64()`` with
+``layers=None``.
 """
 import numpy as np
 import pytest
